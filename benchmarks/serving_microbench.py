@@ -35,6 +35,7 @@ from repro.configs import tiny_lm
 from repro.core import build_topology
 from repro.core.gossip import DelayedStackedChannel, fleet_node_gaps
 from repro.core.planes import PlaneLayout
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 from repro.models.layers import TPContext
 from repro.serve import (
@@ -100,7 +101,7 @@ def _bench_throughput(out: dict) -> list[tuple]:
     cfg = tiny_lm(n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, d_ff=128,
                   vocab_size=256)
     rt = T.RuntimeConfig(dtype="float32", remat=False)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = T.init_params(jax.random.key(0), cfg, tp=1)
     params2 = jax.tree.map(lambda x: x * 1.01, params)
     lay = PlaneLayout.build(params)

@@ -278,7 +278,7 @@ def run(json_path: str = "BENCH_gossip.json") -> None:
         get_config("qwen3-0.6b"),
         name="embed-heavy-dense",
         n_layers=2, d_model=256, n_heads=4, n_kv_heads=4, d_ff=512,
-        vocab_size=100352, qk_norm=False,
+        vocab_size=100352, qk_norm=False, head_dim=0, tie_embeddings=False,
     )
     scenarios = {
         "moe_concentrated": _scenario(

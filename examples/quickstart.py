@@ -25,6 +25,7 @@ from repro.configs import tiny_lm
 from repro.core.optimizers import make_optimizer
 from repro.core.schedules import ScheduleConfig
 from repro.data.synthetic import SyntheticLM, SyntheticLMConfig
+from repro.launch.mesh import make_mesh
 from repro.models.transformer import RuntimeConfig
 from repro.train.step import TrainConfig, build_train_step
 from repro.train.train_state import init_train_state
@@ -32,7 +33,7 @@ from repro.train.train_state import init_train_state
 N_NODES, TP, STEPS, SEQ = 8, 1, 60, 64
 cfg = tiny_lm(n_layers=2, d_model=128, n_heads=4, n_kv_heads=2, d_ff=512,
               vocab_size=1024)
-mesh = jax.make_mesh((N_NODES, TP), ("data", "model"))
+mesh = make_mesh((N_NODES, TP), ("data", "model"))
 
 for algo in ("dmsgd", "decentlam"):
     tcfg = TrainConfig(

@@ -16,12 +16,13 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import tiny_lm
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 from repro.train import serve as serve_mod
 
 cfg = tiny_lm(n_layers=2, d_model=128, n_heads=4, n_kv_heads=2, d_ff=512,
               vocab_size=1024)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 rt = T.RuntimeConfig(dtype="float32", remat=False)
 
 B, PROMPT, GEN = 8, 24, 16

@@ -6,6 +6,4 @@ zoo), ``kernels`` (Pallas TPU kernels), ``train`` (distributed runtime),
 ``data``, ``launch``, ``configs``.
 """
 
-from . import compat  # noqa: F401  — applies jax version-compat config
-
 __version__ = "1.0.0"

@@ -1,7 +1,13 @@
-"""qwen3-0.6b [dense]: qk_norm, GQA [hf:Qwen/Qwen3-8B; hf].
+"""qwen3-0.6b [dense]: qk_norm, GQA, tied embeddings.
 
-28L d_model=1024 16H (kv=8) d_ff=3072 vocab=151936.  Full attention =>
-long_500k skipped.
+Source: Qwen/Qwen3-0.6B ``config.json`` (huggingface.co/Qwen/Qwen3-0.6B):
+28 layers, hidden 1024, 16 query heads, 8 KV heads, ``head_dim=128`` (so
+the attention width 16 x 128 = 2048 is twice the hidden size), intermediate
+3072, vocab 151936, ``tie_word_embeddings=true``, ``rope_theta=1e6``, RMSNorm
+with per-head q/k norms.  Full attention => long_500k skipped.
+
+``SMOKE`` is the same family cut for the CPU tests: it sets its own
+``head_dim`` (d_model / n_heads) and keeps the tied head.
 """
 import dataclasses
 from .base import ModelConfig
@@ -15,12 +21,14 @@ CONFIG = ModelConfig(
     n_kv_heads=8,
     d_ff=3072,
     vocab_size=151936,
+    head_dim=128,
     qk_norm=True,
     rope_theta=1000000.0,
+    tie_embeddings=True,
     long_context_ok=False,
 )
 
 SMOKE = dataclasses.replace(
     CONFIG, n_layers=3, d_model=64, n_heads=4, n_kv_heads=2, d_ff=128,
-    vocab_size=256,
+    vocab_size=256, head_dim=16,
 )

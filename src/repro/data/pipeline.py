@@ -24,9 +24,16 @@ def prefetch_to_device(
     *,
     depth: int = 2,
 ) -> Iterator[Any]:
-    """Yields device-placed batches for steps [0, n_steps)."""
+    """Yields device-placed batches for steps [0, n_steps).
+
+    ``batch_fn`` returns host (numpy) arrays; the only transfer is the
+    sharded ``device_put``.  An exception in the producer thread is
+    re-raised here, in the consumer, so a failing input pipeline fails
+    the run instead of ending it early.
+    """
     q: queue.Queue = queue.Queue(maxsize=depth)
     stop = object()
+    failure: list[BaseException] = []
 
     def produce():
         try:
@@ -36,6 +43,8 @@ def prefetch_to_device(
                     lambda x, sh: jax.device_put(x, sh), host, shardings
                 )
                 q.put(dev)
+        except BaseException as e:  # noqa: BLE001 — handed to the consumer
+            failure.append(e)
         finally:
             q.put(stop)
 
@@ -44,5 +53,8 @@ def prefetch_to_device(
     while True:
         item = q.get()
         if item is stop:
+            t.join()
+            if failure:
+                raise failure[0]
             return
         yield item

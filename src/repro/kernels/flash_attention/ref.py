@@ -29,8 +29,11 @@ def reference_attention(
     kf = jnp.repeat(k, g, axis=2)
     vf = jnp.repeat(v, g, axis=2)
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
+    # f32 matmuls run in bf16 passes on a TPU unless asked for full precision
+    highest = jax.lax.Precision.HIGHEST
     s = jnp.einsum(
-        "bqhd,bkhd->bhqk", q.astype(jnp.float32), kf.astype(jnp.float32)
+        "bqhd,bkhd->bhqk", q.astype(jnp.float32), kf.astype(jnp.float32),
+        precision=highest,
     ) * scale
     q_pos = q_offset + jnp.arange(Sq)
     k_pos = jnp.arange(k.shape[1])
@@ -41,5 +44,7 @@ def reference_attention(
         mask &= q_pos[:, None] - k_pos[None, :] < window
     s = jnp.where(mask[None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", p, vf.astype(jnp.float32))
+    out = jnp.einsum(
+        "bhqk,bkhd->bqhd", p, vf.astype(jnp.float32), precision=highest
+    )
     return out.astype(q.dtype)

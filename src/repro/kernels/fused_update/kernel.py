@@ -19,7 +19,6 @@ construction; ``interpret=True`` runs the identical math on CPU for tests.
 from __future__ import annotations
 
 import functools
-import inspect
 
 import jax
 import jax.numpy as jnp
@@ -30,8 +29,6 @@ from ...core.update_spec import MathCtx, post_math, pre_math
 
 LANES = 1024
 ROW_COLS = 128  # lane width of a row-scalar operand (one VMEM tile column)
-
-_SDS_HAS_VMA = "vma" in inspect.signature(jax.ShapeDtypeStruct.__init__).parameters
 
 
 def _stage_body(
@@ -51,16 +48,6 @@ def _stage_body(
     res = math(op, ctx, s, **vals)
     for n, oref in zip(names_out, outs):
         oref[...] = res[n].astype(oref.dtype)
-
-
-def _vma_of(x):
-    """Varying manual axes of ``x`` on jax versions that track them."""
-    if not hasattr(jax, "typeof"):
-        return frozenset()
-    try:
-        return jax.typeof(x).vma
-    except Exception:  # noqa: BLE001 — outside a trace / no vma support
-        return frozenset()
 
 
 def fused_stage_kernel(
@@ -96,31 +83,23 @@ def fused_stage_kernel(
     bs = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
     bs_row = pl.BlockSpec((block_rows, ROW_COLS), lambda i: (i, 0))
 
-    # inside a check_vma shard_map (newer jax) the outputs must declare their
+    # inside a check_vma shard_map the outputs must declare their
     # varying axes; they inherit the inputs' (elementwise kernel), and every
     # operand must be promoted to the same variance (scalars are replicated)
-    vma = frozenset()
-    for a in inputs.values():
-        vma = vma | _vma_of(a)
+    vma = frozenset().union(*(jax.typeof(a).vma for a in inputs.values()))
     if vma:
 
         def _promote(a):
-            missing = tuple(sorted(vma - _vma_of(a)))
-            return jax.lax.pvary(a, missing) if missing else a
+            missing = tuple(sorted(vma - jax.typeof(a).vma))
+            return jax.lax.pcast(a, missing, to="varying") if missing else a
 
         scalars = _promote(scalars)
         inputs = {n: _promote(a) for n, a in inputs.items()}
         row_scalars = {n: _promote(a) for n, a in row_scalars.items()}
 
-    if _SDS_HAS_VMA:
-        out_shape = [
-            jax.ShapeDtypeStruct(first.shape, dt, vma=vma)
-            for dt in out_dtypes.values()
-        ]
-    else:
-        out_shape = [
-            jax.ShapeDtypeStruct(first.shape, dt) for dt in out_dtypes.values()
-        ]
+    out_shape = [
+        jax.ShapeDtypeStruct(first.shape, dt, vma=vma) for dt in out_dtypes.values()
+    ]
 
     kern = functools.partial(
         _stage_body, kind=kind, op=op, ctx=ctx, names_in=names_in,

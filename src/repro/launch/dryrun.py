@@ -33,7 +33,6 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from .. import compat
 from ..configs import ARCHS, SHAPES, get_config, shape_applicable
 from ..configs.base import ModelConfig, ShapeSpec
 from ..core.optimizers import make_optimizer
@@ -170,7 +169,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, args) -> dict:
 
     ma = compiled.memory_analysis()
     print(f"  memory_analysis: {ma}")
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     print(
         "  cost_analysis (XLA, loop bodies once): flops=%.4g bytes=%.4g"
         % (ca.get("flops", 0.0), ca.get("bytes accessed", 0.0))

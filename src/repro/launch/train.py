@@ -19,13 +19,32 @@ Examples::
     # ~100M model (paper-scale demo; slow on CPU, sized for real chips)
     PYTHONPATH=src python -m repro.launch.train --simulate-nodes 8 \
         --preset 100m --steps 300
+
+``run(argv)`` is this whole command as a function: ``main()`` and
+``chip_smoke.py`` call it with an argument list and read its
+:class:`RunResult`.
 """
 
 import argparse
+import dataclasses
 import os
+import time
+from typing import Any
 
 
-def _parse():
+@dataclasses.dataclass
+class RunResult:
+    """What one ``run`` leaves behind, for callers that check it."""
+
+    state: Any  # final TrainState, on the mesh
+    losses: list  # fleet-mean loss of every step, in order
+    compile_s: float  # lower + compile of the step program
+    step_s: float  # steady seconds/step after the first (block_until_ready)
+    compiled: Any  # jax.stages.Compiled of the step
+    mesh: Any
+
+
+def parse_args(argv=None):
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--simulate-nodes", type=int, default=0,
                    help="simulate N devices on CPU (set before jax init)")
@@ -118,7 +137,7 @@ def _parse():
     p.add_argument("--track-consensus", dest="track_consensus",
                    action="store_true")
     p.add_argument("--dtype", default="float32")
-    return p.parse_args()
+    return p.parse_args(argv)
 
 
 def _parse_chaos(specs, seed):
@@ -159,55 +178,25 @@ def _parse_chaos(specs, seed):
     return ChaosSchedule(faults=tuple(faults), seed=seed)
 
 
-def main() -> None:
-    args = _parse()
-    if args.simulate_nodes:
-        total = args.simulate_nodes * args.tp
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={total}"
-        )
-
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
+def model_config(args):
+    """The ModelConfig the parsed command line trains."""
     from ..configs import get_config, tiny_lm
-    from ..core.optimizers import make_optimizer
-    from ..core.schedules import ScheduleConfig
-    from ..data.pipeline import prefetch_to_device
-    from ..data.synthetic import SyntheticLM, SyntheticLMConfig
-    from ..models.transformer import RuntimeConfig
-    from ..train.checkpoint import (
-        check_plane_manifest,
-        elastic_reshape,
-        restore_checkpoint,
-        save_checkpoint,
-    )
-    from ..train.step import TrainConfig, build_train_step
-    from ..train.train_state import (
-        ensure_channel_state,
-        init_train_state,
-        model_plane_layout,
-        reconcile_plane_state,
-    )
-
-    n_devices = len(jax.devices())
-    tp = args.tp
-    n_nodes = n_devices // tp
-    assert n_nodes * tp == n_devices, (n_devices, tp)
-    mesh = jax.make_mesh((n_nodes, tp), ("data", "model"))
-    print(f"mesh: {n_nodes} nodes x {tp}-way TP ({n_devices} devices)")
 
     if args.arch:
-        cfg = get_config(args.arch, smoke=args.smoke)
-    elif args.preset == "100m":
-        cfg = tiny_lm("lm-100m", n_layers=12, d_model=768, n_heads=12,
-                      n_kv_heads=4, d_ff=3072, vocab_size=50304)
-    else:
-        cfg = tiny_lm()
+        return get_config(args.arch, smoke=args.smoke)
+    if args.preset == "100m":
+        return tiny_lm("lm-100m", n_layers=12, d_model=768, n_heads=12,
+                       n_kv_heads=4, d_ff=3072, vocab_size=50304)
+    return tiny_lm()
 
-    tcfg = TrainConfig(
+
+def train_config(args):
+    """The TrainConfig the parsed command line trains with."""
+    from ..core.schedules import ScheduleConfig
+    from ..models.transformer import RuntimeConfig
+    from ..train.step import TrainConfig
+
+    return TrainConfig(
         algorithm=args.algorithm,
         topology=args.topology,
         gossip_impl=args.gossip_impl,
@@ -232,6 +221,57 @@ def main() -> None:
         resilient=args.resilient,
         resilient_gap=args.resilient_gap,
     )
+
+
+def main() -> None:
+    run()
+
+
+def run(argv=None) -> RunResult:
+    """Train as the command line ``argv`` says (``sys.argv`` when None)."""
+    args = parse_args(argv)
+    if args.simulate_nodes:
+        total = args.simulate_nodes * args.tp
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + f" --xla_force_host_platform_device_count={total}"
+        )
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from .compile_cache import configure_compile_cache
+    from .mesh import make_mesh
+
+    configure_compile_cache()
+
+    from ..core.optimizers import make_optimizer
+    from ..data.pipeline import prefetch_to_device
+    from ..data.synthetic import SyntheticLM, SyntheticLMConfig
+    from ..train.checkpoint import (
+        check_plane_manifest,
+        elastic_reshape,
+        restore_checkpoint,
+        save_checkpoint,
+    )
+    from ..train.step import build_train_step
+    from ..train.train_state import (
+        ensure_channel_state,
+        init_train_state,
+        model_plane_layout,
+        reconcile_plane_state,
+    )
+
+    n_devices = len(jax.devices())
+    tp = args.tp
+    n_nodes = n_devices // tp
+    assert n_nodes * tp == n_devices, (n_devices, tp)
+    mesh = make_mesh((n_nodes, tp), ("data", "model"))
+    print(f"mesh: {n_nodes} nodes x {tp}-way TP ({n_devices} devices)")
+
+    cfg = model_config(args)
+    tcfg = train_config(args)
 
     def build(mesh, n_nodes):
         step_fn, sspecs, bspecs, channel = build_train_step(
@@ -291,8 +331,8 @@ def main() -> None:
     ))
 
     def batch_fn(k):
-        b = data.batch(start + k)
-        return {kk: jnp.asarray(v) for kk, v in b.items()}
+        # host numpy until the sharded device_put in the prefetch thread
+        return data.batch(start + k)
 
     serve = None
     if args.serve_while_training:
@@ -342,17 +382,24 @@ def main() -> None:
         applied_trust = monitor.trust.copy()
     skipped_steps = 0
 
-    import time
-
     t0 = time.time()
-    t_warm = None  # set after step 0 so measured_step_s excludes XLA compile
+    t_warm = None  # set after step 0 so the steady window excludes compile
+    compiled, compile_s = None, 0.0
+    losses = []
     it = prefetch_to_device(batch_fn, bshard, args.steps - start)
     for k, batch in enumerate(it):
         step = start + k
+        if k == 0:
+            # compile ahead of the first call (the jit reuses this
+            # executable), so compile time is reported on its own
+            t_c = time.perf_counter()
+            compiled = step_fn.lower(state, batch).compile()
+            compile_s = time.perf_counter() - t_c
         state, metrics = step_fn(state, batch)
+        losses.append(metrics["loss"])
         if k == 0:
             jax.block_until_ready(metrics["loss"])
-            t_warm = time.time()
+            t_warm = time.perf_counter()
         if args.max_skipped_steps and float(metrics["skipped_nonfinite"]) > 0:
             skipped_steps += 1
             if skipped_steps > args.max_skipped_steps:
@@ -391,8 +438,8 @@ def main() -> None:
             host = jax.device_get(state)
             new_n = max(1, n_nodes // 2)
             host = elastic_reshape(host, new_n)
-            mesh2 = jax.make_mesh((new_n, tp), ("data", "model"),
-                                  devices=jax.devices()[: new_n * tp])
+            mesh2 = make_mesh((new_n, tp), ("data", "model"),
+                              devices=jax.devices()[: new_n * tp])
             step_fn, opt, channel, bshard = build(mesh2, new_n)
             host = ensure_channel_state(host, channel, new_n, layout)
             state = jax.tree.map(jnp.asarray, host)
@@ -404,21 +451,26 @@ def main() -> None:
             n_nodes = new_n
             remaining = args.steps - step - 1
             it2 = prefetch_to_device(
-                lambda k2: {kk: jnp.asarray(v)
-                            for kk, v in data.batch(step + 1 + k2).items()},
-                bshard, remaining,
+                lambda k2: data.batch(step + 1 + k2), bshard, remaining,
             )
             for k2, batch2 in enumerate(it2):
                 state, metrics = step_fn(state, batch2)
+                losses.append(metrics["loss"])
                 s2 = step + 1 + k2
                 if s2 % args.log_every == 0 or s2 == args.steps - 1:
                     print(f"step {s2:5d} loss {float(metrics['loss']):.4f} "
                           f"(post-failure, {new_n} nodes)", flush=True)
             break
 
+    jax.block_until_ready(state)
     dt = time.time() - t0
-    print(f"done: {args.steps - start} steps in {dt:.1f}s "
-          f"({(args.steps - start) / dt:.2f} steps/s)")
+    n_steps = args.steps - start
+    step_s = (
+        (time.perf_counter() - t_warm) / (n_steps - 1)
+        if t_warm is not None and n_steps > 1 else float("nan")
+    )
+    print(f"done: {n_steps} steps in {dt:.1f}s (compile {compile_s:.2f}s, "
+          f"steady {step_s:.4f}s/step)")
     if serve is not None:
         # drain whatever the cooperative ticks left in flight (unless the
         # gate never cleared a single version — nothing to serve with)
@@ -431,14 +483,11 @@ def main() -> None:
               f"{ps['gap_threshold']}, final v{ps['current_version']})")
     if args.measure_json:
         import json
-        n_steps = args.steps - start
-        if t_warm is not None and n_steps > 1:
+        if n_steps > 1:
             # steady-state price: exclude step 0 (XLA compile dominates it)
-            measured = (time.time() - t_warm) / (n_steps - 1)
-            warm_steps = n_steps - 1
+            measured, warm_steps = step_s, n_steps - 1
         else:
-            measured = dt / max(1, n_steps)
-            warm_steps = n_steps
+            measured, warm_steps = dt / max(1, n_steps), n_steps
         with open(args.measure_json, "w") as f:
             json.dump({
                 "measured_step_s": measured,
@@ -453,6 +502,10 @@ def main() -> None:
                         metadata={"n_nodes": n_nodes,
                                   "algorithm": args.algorithm},
                         plane_layout=layout)
+    return RunResult(
+        state=state, losses=[float(x) for x in losses], compile_s=compile_s,
+        step_s=step_s, compiled=compiled, mesh=mesh,
+    )
 
 
 if __name__ == "__main__":

@@ -20,7 +20,6 @@ from typing import Any
 import jax
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from ..configs.base import ModelConfig
 from ..models import transformer as T
 from ..models.layers import TPContext
@@ -77,7 +76,7 @@ def build_prefill_step(
             target_len=scfg.target_len or batch["tokens"].shape[1],
         )
 
-    sm = shard_map(
+    sm = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(pspecs, bspec),
@@ -110,7 +109,7 @@ def build_decode_step(
         )
 
     t_spec = P(ba) if per_slot_t else P()
-    sm = shard_map(
+    sm = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(pspecs, tok_spec, cspecs, t_spec),

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import LEGACY_SHARD_MAP, shard_map
 from ..configs.base import ModelConfig
 from ..core.gossip import GossipChannel, build_channel, make_psum_mean
 from ..core.optimizers import OptimizerConfig, make_optimizer
@@ -187,6 +186,14 @@ def batch_specs(cfg: ModelConfig, node_axes) -> Tree:
     return s
 
 
+def _spec_axes(spec: P) -> set:
+    """Mesh axes a PartitionSpec shards over."""
+    axes = set()
+    for entry in spec:
+        axes.update(entry if isinstance(entry, tuple) else (entry,))
+    return axes - {None}
+
+
 def _consensus_metric(params: Tree, node_axes, n_nodes: int, model_axis) -> jax.Array:
     """(1/n) sum_i ||x_i - x_bar||^2 across nodes (telemetry; averaged over
     model shards so the scalar is replicated on every device)."""
@@ -283,40 +290,25 @@ def build_train_step(
             params, batch, cfg, tp_ctx, rt, collect_rows=tcfg.sparse_gossip
         )
 
-    # Legacy shard_map AD (pre-vma jax) differs from the modern tracker in
-    # two ways that matter inside the fully-manual region:
-    #   1. grads of model-axis-*replicated* params (norm scales) stay
-    #      partial per shard — the cross-shard psum must be added by hand;
-    #   2. psum transposes to psum (the old pmap convention), so the
-    #      replicated loss cotangent picks up one net factor of tp on every
-    #      backward path — divide it back out.
-    # Both are no-ops on modern jax (vma AD emits exactly this), and the
-    # distributed == stacked equivalence tests check the result leaf-exactly.
-    pspec_leaves = jax.tree.leaves(
-        T.param_specs(cfg, tp, model_axis), is_leaf=lambda s: isinstance(s, P)
-    )
+    def replicated_over_model(spec: P, x: jax.Array) -> jax.Array:
+        """``x`` typed as its out_spec claims on the model axis.
 
-    def _spec_axes(spec) -> set:
-        axes = set()
-        for entry in spec:
-            if entry is None:
-                continue
-            for a in entry if isinstance(entry, tuple) else (entry,):
-                axes.add(a)
-        return axes
-
-    def reduce_replicated_grads(grads):
-        if not LEGACY_SHARD_MAP or tp == 1:
-            return grads
-        inv_tp = 1.0 / tp
-        leaves, treedef = jax.tree.flatten(grads)
-        fixed = [
-            g * inv_tp
-            if model_axis in _spec_axes(s)
-            else jax.lax.psum(g, model_axis) * inv_tp
-            for g, s in zip(leaves, pspec_leaves)
-        ]
-        return jax.tree.unflatten(treedef, fixed)
+        A plane bucket packs replicated leaves beside model-sharded ones, so
+        after unpack the replicated params (and anything counted from the
+        bucket, like the sparse channel's volume telemetry) carry the
+        bucket's model-axis variance although every rank computed the same
+        values.  For such a leaf whose spec does not name the model axis,
+        the psum of model rank 0's copy (exact: the other terms are zeros)
+        proves the replication to the vma check.  At tp == 1 it is a psum
+        over one member, which compiles away.
+        """
+        if model_axis in _spec_axes(spec) or model_axis not in jax.typeof(x).vma:
+            return x
+        if tp > 1:
+            x = jnp.where(
+                jax.lax.axis_index(model_axis) == 0, x, jnp.zeros_like(x)
+            )
+        return jax.lax.psum(x, model_axis)
 
     def grads_of(params, batch):
         accum = tcfg.grad_accum
@@ -361,7 +353,6 @@ def build_train_step(
         lr = lr_fn(step_idx)
 
         grads, loss, metrics = grads_of(params, batch)
-        grads = reduce_replicated_grads(grads)
 
         # finite guard: when the local grad norm goes non-finite, zero the
         # grads BEFORE the update path (the gossip payload this round stays
@@ -369,15 +360,16 @@ def build_train_step(
         # optimizer state after it (momentum/EF frozen — a poisoned step
         # must not leak into the accumulators).  Params still take the
         # g=0 update, i.e. the node keeps gossiping.  The decision is
-        # per-node; at tp > 1 the psum makes every model shard agree so
-        # the replicated params cannot desync.
+        # per-node; the psum over the model axis (at any tp, free at
+        # tp == 1) makes every model shard agree, so the replicated params
+        # cannot desync and the guard is provably invariant over the model
+        # axis, as the replicated out_specs require.
         finite = None
         if tcfg.finite_guard:
             gsq = jnp.float32(0.0)
             for gg in jax.tree.leaves(grads):
                 gsq = gsq + jnp.sum(jnp.square(gg.astype(jnp.float32)))
-            if tp > 1:
-                gsq = jax.lax.psum(gsq, model_axis)
+            gsq = jax.lax.psum(gsq, model_axis)
             finite = jnp.isfinite(gsq)
             grads = jax.tree.map(
                 lambda gg: jnp.where(finite, gg, jnp.zeros_like(gg)), grads
@@ -481,6 +473,10 @@ def build_train_step(
             "opt": jax.tree.map(lambda x: x[None], new_opt),
             "channel": jax.tree.map(lambda x: x[None], comp_state),
         }
+        new_state = jax.tree.map(
+            replicated_over_model, sspecs, new_state,
+            is_leaf=lambda x: isinstance(x, P),
+        )
         return new_state, out_metrics
 
     sspecs = stacked_state_specs(
@@ -494,7 +490,7 @@ def build_train_step(
         mspecs["consensus_sq"] = P()
 
     all_axes = set(node_axes) | {model_axis}
-    step_sm = shard_map(
+    step_sm = jax.shard_map(
         step_fn,
         mesh=mesh,
         in_specs=(sspecs, bspecs),

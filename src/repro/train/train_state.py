@@ -67,12 +67,13 @@ def model_plane_layout(
 def _plane_pspec(layout: PlaneLayout) -> Tree:
     """Per-node PartitionSpec tree of a plane dict.
 
-    At tp == 1 each bucket is one unsharded ``(rows, LANES)`` buffer; a
-    sharded layout stacks the tp per-rank row blocks along the row axis,
+    A sharded layout stacks the tp per-rank row blocks along the row axis,
     so the buffer splits over the model axis and each mesh column sees
-    exactly its local bucket inside shard_map."""
-    m = layout.model_axis if layout.tp > 1 else None
-    return {key: P(m, None) for key in layout.segments}
+    exactly its local bucket inside shard_map.  At tp == 1 the split over
+    the size-1 axis moves nothing, but it still says what the step can
+    prove: a bucket packs model-sharded and replicated leaves together, so
+    it varies over the model axis."""
+    return {key: P(layout.model_axis, None) for key in layout.segments}
 
 
 def _prepend_axis(spec_tree: Tree, axes) -> Tree:

@@ -28,7 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import (
     ALGORITHMS,
     DelayedPpermuteChannel,
@@ -39,13 +38,14 @@ from repro.core import (
     make_psum_mean,
 )
 from repro.core.compression import get_compressor
+from repro.launch.mesh import make_mesh
 from repro.sim import SimSpec, simulate
 
 N, D, M = 8, 6, 10
 LR = 1e-2
 TOPO = "ring"
 
-mesh = jax.make_mesh((N,), ("data",))
+mesh = make_mesh((N,), ("data",))
 prob = make_linear_regression(n=N, m=M, d=D, noise=0.01, seed=3, heterogeneity=1.0)
 topo = build_topology(TOPO, N)
 mean = make_psum_mean(("data",), N)
@@ -135,7 +135,7 @@ def run_distributed(opt, gossip, chstate0, n_steps, on_step=None):
     sspecs = {"x": specs(x0), "opt": specs(s0), "ch": specs(ch0), "k": P()}
     dspecs = (P("data", None, None), P("data", None))
 
-    step_sm = jax.jit(shard_map(
+    step_sm = jax.jit(jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(sspecs, *dspecs),

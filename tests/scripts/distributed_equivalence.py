@@ -21,6 +21,7 @@ from repro.core import (
 )
 from repro.core.schedules import ScheduleConfig
 from repro.data.synthetic import SyntheticLM, SyntheticLMConfig
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 from repro.models.layers import TPContext
 from repro.train.step import TrainConfig, build_train_step
@@ -50,7 +51,7 @@ if MODE.startswith("planes"):
         cases = [("decentlam-sa", 2)]
     else:
         cases = [("decentlam", 0)]
-    mesh = jax.make_mesh((N, TP), ("data", "model"))
+    mesh = make_mesh((N, TP), ("data", "model"))
     cfg = tiny_lm(
         n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=256
     )
@@ -132,7 +133,7 @@ if MODE == "sparse":
     from repro.train.train_state import model_plane_layout
 
     N, TP, S = 8, 1, 32
-    mesh = jax.make_mesh((N, TP), ("data", "model"))
+    mesh = make_mesh((N, TP), ("data", "model"))
     cfg = get_config("granite-moe-1b-a400m", smoke=True)
     layout = model_plane_layout(cfg, TP)
     data = SyntheticLM(SyntheticLMConfig(
@@ -189,7 +190,7 @@ if MODE == "sparse":
     sys.exit(0)
 
 cfg = tiny_lm(n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=256)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 N, TP, S = 4, 2, 32
 
 kwargs = dict(

@@ -6,13 +6,14 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import tiny_lm
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 from repro.models.layers import TPContext
 from repro.train import serve as serve_mod
 
 cfg = tiny_lm(n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, d_ff=128,
               vocab_size=256)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 rt = T.RuntimeConfig(dtype="float32", remat=False)
 B, S = 8, 32
 

@@ -33,7 +33,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import (
     ALGORITHMS,
     DelayedPpermuteChannel,
@@ -46,6 +45,7 @@ from repro.core import (
 from repro.core.gossip import fleet_node_gaps
 from repro.core.planes import PlaneLayout
 from repro.launch.elastic import plan_recovery
+from repro.launch.mesh import make_mesh
 from repro.resilience import (
     ChaosChannel,
     ChaosSchedule,
@@ -85,7 +85,7 @@ def grad_fn(x, _s):
 def make_runner(n, data_rows):
     """A run_distributed over the first ``n`` devices and the given
     global data rows; returns (runner, mesh)."""
-    mesh = jax.make_mesh((n,), ("data",), devices=jax.devices()[:n])
+    mesh = make_mesh((n,), ("data",), devices=jax.devices()[:n])
     mean = make_psum_mean(("data",), n)
     rows = np.asarray(list(data_rows))
     A = prob.A[rows]
@@ -129,7 +129,7 @@ def make_runner(n, data_rows):
         sspecs = {"x": specs(x0), "opt": specs(s0), "ch": specs(ch0), "k": P()}
         dspecs = (P("data", None, None), P("data", None))
 
-        step_sm = jax.jit(shard_map(
+        step_sm = jax.jit(jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(sspecs, *dspecs),

@@ -31,7 +31,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import (
     ALGORITHMS,
     DelayedPpermuteChannel,
@@ -43,6 +42,7 @@ from repro.core import (
     make_psum_mean,
     make_stacked_mean,
 )
+from repro.launch.mesh import make_mesh
 from repro.sparse import (
     SparseDelayedPpermuteChannel,
     SparsePpermuteChannel,
@@ -53,7 +53,7 @@ from repro.sparse import (
 N, D, M = 8, 6, 10
 LR = 1e-2
 
-mesh = jax.make_mesh((N,), ("data",))
+mesh = make_mesh((N,), ("data",))
 prob = make_linear_regression(n=N, m=M, d=D, noise=0.01, seed=3, heterogeneity=1.0)
 topo = build_topology("ring", N)
 mean = make_psum_mean(("data",), N)
@@ -103,7 +103,7 @@ def run_mesh(opt, channel, n_steps, *, mask=None, x0=None):
     state = {"x": xs, "opt": s0, "ch": ch0, "k": jnp.int32(0)}
     sspecs = {"x": specs(xs), "opt": specs(s0), "ch": specs(ch0), "k": P()}
     dspecs = (P("data", None, None), P("data", None))
-    step_sm = jax.jit(shard_map(
+    step_sm = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(sspecs, *dspecs), out_specs=sspecs,
         axis_names={"data"},
     ))

@@ -3,7 +3,6 @@
 import jax
 import jax.numpy as jnp
 
-from repro.compat import cost_analysis, shard_map
 from repro.launch.costmodel import analyze_lowered
 
 
@@ -20,7 +19,7 @@ def test_scan_flops_match_unrolled_xla():
         out, _ = jax.lax.scan(lambda c, wi: (c @ wi, None), x, w, unroll=L)
         return out
 
-    xla = cost_analysis(jax.jit(unrolled).lower(x, w).compile())["flops"]
+    xla = jax.jit(unrolled).lower(x, w).compile().cost_analysis()["flops"]
     ours = analyze_lowered(rolled, (x, w), {}).flops
     # elementwise accounting adds O(d^2); dot flops are O(L d^3)
     assert abs(ours - xla) / xla < 0.02, (ours, xla)
@@ -90,7 +89,7 @@ def test_collective_bytes_with_axis_sizes():
     from jax.sharding import PartitionSpec as P
 
     x = jnp.zeros((1024,), jnp.float32)  # 4 KiB
-    sm = shard_map(f, mesh=jax.make_mesh((1,), ("data",)),
+    sm = jax.shard_map(f, mesh=jax.make_mesh((1,), ("data",)),
                    in_specs=P(), out_specs=P(), check_vma=False)
     costs = analyze_lowered(sm, (x,), mesh_axes)
     nbytes = 1024 * 4
@@ -110,7 +109,7 @@ def test_collectives_inside_scan_are_multiplied():
     from jax.sharding import PartitionSpec as P
 
     x = jnp.zeros((256,), jnp.float32)
-    sm = shard_map(f, mesh=jax.make_mesh((1,), ("data",)),
+    sm = jax.shard_map(f, mesh=jax.make_mesh((1,), ("data",)),
                    in_specs=P(), out_specs=P(), check_vma=False)
     costs = analyze_lowered(sm, (x,), {"data": 4})
     expect = 6 * 2 * (3 / 4) * 256 * 4

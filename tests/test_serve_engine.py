@@ -21,6 +21,7 @@ import pytest
 
 from repro.configs import tiny_lm
 from repro.core.planes import PlaneLayout
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 from repro.models.layers import TPContext
 from repro.serve import Request, ServeEngine, WeightPublisher, greedy_decode_loop
@@ -34,7 +35,7 @@ TL = MAX_PROMPT + MAX_NEW
 
 
 def _mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def _params(seed=0):

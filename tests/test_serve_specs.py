@@ -46,9 +46,11 @@ def test_serve_specs_replicated_fallback():
     )
 
     def batch_dim(spec):
-        return spec[1]  # cache leaves are (Lg, B, ...)
+        # cache leaves are (Lg, B, ...); PartitionSpec normalises a
+        # one-axis tuple ("data",) to "data", so compare normalised specs
+        return P(spec[1])
 
     for leaf in jax.tree.leaves(c8, is_leaf=lambda x: isinstance(x, P)):
-        assert batch_dim(leaf) == ("data",), leaf
+        assert batch_dim(leaf) == P(("data",)), leaf
     for leaf in jax.tree.leaves(c1, is_leaf=lambda x: isinstance(x, P)):
-        assert batch_dim(leaf) is None, leaf
+        assert batch_dim(leaf) == P(None), leaf
