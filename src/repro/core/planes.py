@@ -346,6 +346,7 @@ class PlaneLayout:
 
     # -- pack / unpack ------------------------------------------------------
 
+    @jax.named_scope("plane_pack")
     def pack(self, tree: Tree, *, dtype=None, leading: int = 0,
              impl: str | None = None) -> dict:
         """Pack ``tree`` (structure of the template) into plane buffers.
@@ -438,6 +439,7 @@ class PlaneLayout:
             cache[key] = idx
         return cache[key]
 
+    @jax.named_scope("plane_unpack")
     def unpack(self, planes: dict, *, like: Tree | None = None,
                dtype=None, leading: int = 0) -> Tree:
         """Slice the plane buffers back into the template structure.

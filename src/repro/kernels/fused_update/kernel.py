@@ -114,5 +114,7 @@ def fused_stage_kernel(
         out_specs=[bs] * len(names_out),
         out_shape=out_shape,
         interpret=interpret,
+        # a stable name for the kernel in traces and in the compiled HLO
+        name=f"fused_update_{kind}_{op}",
     )(scalars, *row_scalars.values(), *inputs.values())
     return dict(zip(names_out, outs))

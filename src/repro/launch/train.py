@@ -137,7 +137,78 @@ def parse_args(argv=None):
     p.add_argument("--track-consensus", dest="track_consensus",
                    action="store_true")
     p.add_argument("--dtype", default="float32")
+    p.add_argument("--profile-dir", dest="profile_dir", default=None,
+                   help="write a jax.profiler trace of --profile-steps here "
+                        "(TensorBoard, Perfetto)")
+    p.add_argument("--profile-steps", dest="profile_steps", type=_step_range,
+                   default=(1, 4), metavar="A:B",
+                   help="steps A to B-1 go into the --profile-dir trace")
     return p.parse_args(argv)
+
+
+def _step_range(text: str) -> tuple[int, int]:
+    """``A:B`` -> (A, B): the steps A to B - 1."""
+    try:
+        a, b = (int(x) for x in text.split(":"))
+    except ValueError:
+        raise argparse.ArgumentTypeError(f"expected A:B, got {text!r}") from None
+    if not 0 <= a < b:
+        raise argparse.ArgumentTypeError(f"expected 0 <= A < B, got {text!r}")
+    return a, b
+
+
+class StepProfiler:
+    """A ``jax.profiler`` trace of steps ``first`` to ``end - 1`` into
+    ``out_dir``, each step inside ``StepTraceAnnotation("train",
+    step_num=k)``.  The program's own spans land in the same trace: the
+    input pipeline's ``repro.input.*`` on the host, and the named scopes
+    (``model``, ``update_tail``, ...) in the device ops' metadata.  Without
+    ``out_dir`` it does nothing."""
+
+    def __init__(self, out_dir: str | None, steps: tuple[int, int]):
+        self.out_dir = out_dir
+        self.first, self.end_at = steps
+        self._span = None
+        self._on = False
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.stop()
+
+    def begin(self, step: int) -> None:
+        """Before step ``step`` is dispatched."""
+        if self.out_dir is None or not self.first <= step < self.end_at:
+            return
+        import jax
+
+        if not self._on:
+            jax.profiler.start_trace(self.out_dir)
+            self._on = True
+        self._span = jax.profiler.StepTraceAnnotation("train", step_num=step)
+        self._span.__enter__()
+
+    def end(self, step: int, state) -> None:
+        """After step ``step``'s host work; the trace stops after the last
+        traced step's device work."""
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
+        if self._on and step + 1 >= self.end_at:
+            self.stop(state)
+
+    def stop(self, state=None) -> None:
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
+        if self._on:
+            import jax
+
+            if state is not None:
+                jax.block_until_ready(state)
+            jax.profiler.stop_trace()
+            self._on = False
 
 
 def _parse_chaos(specs, seed):
@@ -386,81 +457,85 @@ def run(argv=None) -> RunResult:
     t_warm = None  # set after step 0 so the steady window excludes compile
     compiled, compile_s = None, 0.0
     losses = []
+    profiler = StepProfiler(args.profile_dir, args.profile_steps)
     it = prefetch_to_device(batch_fn, bshard, args.steps - start)
-    for k, batch in enumerate(it):
-        step = start + k
-        if k == 0:
-            # compile ahead of the first call (the jit reuses this
-            # executable), so compile time is reported on its own
-            t_c = time.perf_counter()
-            compiled = step_fn.lower(state, batch).compile()
-            compile_s = time.perf_counter() - t_c
-        state, metrics = step_fn(state, batch)
-        losses.append(metrics["loss"])
-        if k == 0:
-            jax.block_until_ready(metrics["loss"])
-            t_warm = time.perf_counter()
-        if args.max_skipped_steps and float(metrics["skipped_nonfinite"]) > 0:
-            skipped_steps += 1
-            if skipped_steps > args.max_skipped_steps:
-                raise RuntimeError(
-                    f"aborting at step {step}: the finite guard skipped the "
-                    f"optimizer update on {skipped_steps} steps, exceeding "
-                    f"--max-skipped-steps={args.max_skipped_steps} — the "
-                    "gradients are persistently non-finite (check lr/data/"
-                    "fault injection)"
+    with profiler:
+        for k, batch in enumerate(it):
+            step = start + k
+            if k == 0:
+                # compile ahead of the first call (the jit reuses this
+                # executable), so compile time is reported on its own
+                t_c = time.perf_counter()
+                compiled = step_fn.lower(state, batch).compile()
+                compile_s = time.perf_counter() - t_c
+            profiler.begin(step)
+            state, metrics = step_fn(state, batch)
+            losses.append(metrics["loss"])
+            if k == 0:
+                jax.block_until_ready(metrics["loss"])
+                t_warm = time.perf_counter()
+            if args.max_skipped_steps and float(metrics["skipped_nonfinite"]) > 0:
+                skipped_steps += 1
+                if skipped_steps > args.max_skipped_steps:
+                    raise RuntimeError(
+                        f"aborting at step {step}: the finite guard skipped the "
+                        f"optimizer update on {skipped_steps} steps, exceeding "
+                        f"--max-skipped-steps={args.max_skipped_steps} — the "
+                        "gradients are persistently non-finite (check lr/data/"
+                        "fault injection)"
+                    )
+            if monitor is not None and step % args.health_every == 0:
+                trust = monitor.observe(
+                    fleet_sender_gaps(channel, state["channel"])
                 )
-        if monitor is not None and step % args.health_every == 0:
-            trust = monitor.observe(
-                fleet_sender_gaps(channel, state["channel"])
-            )
-            if not np.array_equal(trust, applied_trust):
-                state = dict(state)
-                state["channel"] = with_trust(state["channel"], trust)
-                applied_trust = trust.copy()
-                print(f"health: {monitor.states()} (step {step})", flush=True)
-        if serve is not None:
-            serve(step, state)
-        if step % args.log_every == 0 or step == args.steps - 1:
-            msg = (f"step {step:5d} loss {float(metrics['loss']):.4f} "
-                   f"lr {float(metrics['lr']):.2e}")
-            if args.track_consensus:
-                msg += f" consensus {float(metrics['consensus_sq']):.3e}"
-            print(msg, flush=True)
-        if args.ckpt_dir and (step + 1) % args.ckpt_every == 0:
-            path = save_checkpoint(args.ckpt_dir, jax.device_get(state),
-                                   metadata={"n_nodes": n_nodes,
-                                             "algorithm": args.algorithm},
-                                   plane_layout=layout)
-            print(f"checkpointed -> {path}")
-        if args.failure_drill and step == (start + args.steps) // 2:
-            print("FAILURE DRILL: checkpoint, shrink to n/2, rebuild, resume")
-            host = jax.device_get(state)
-            new_n = max(1, n_nodes // 2)
-            host = elastic_reshape(host, new_n)
-            mesh2 = make_mesh((new_n, tp), ("data", "model"),
-                              devices=jax.devices()[: new_n * tp])
-            step_fn, opt, channel, bshard = build(mesh2, new_n)
-            host = ensure_channel_state(host, channel, new_n, layout)
-            state = jax.tree.map(jnp.asarray, host)
-            data = SyntheticLM(SyntheticLMConfig(
-                vocab_size=cfg.vocab_size, seq_len=args.seq_len,
-                per_node_batch=args.per_node_batch, n_nodes=new_n,
-                heterogeneity=args.heterogeneity,
-            ))
-            n_nodes = new_n
-            remaining = args.steps - step - 1
-            it2 = prefetch_to_device(
-                lambda k2: data.batch(step + 1 + k2), bshard, remaining,
-            )
-            for k2, batch2 in enumerate(it2):
-                state, metrics = step_fn(state, batch2)
-                losses.append(metrics["loss"])
-                s2 = step + 1 + k2
-                if s2 % args.log_every == 0 or s2 == args.steps - 1:
-                    print(f"step {s2:5d} loss {float(metrics['loss']):.4f} "
-                          f"(post-failure, {new_n} nodes)", flush=True)
-            break
+                if not np.array_equal(trust, applied_trust):
+                    state = dict(state)
+                    state["channel"] = with_trust(state["channel"], trust)
+                    applied_trust = trust.copy()
+                    print(f"health: {monitor.states()} (step {step})", flush=True)
+            if serve is not None:
+                serve(step, state)
+            if step % args.log_every == 0 or step == args.steps - 1:
+                msg = (f"step {step:5d} loss {float(metrics['loss']):.4f} "
+                       f"lr {float(metrics['lr']):.2e}")
+                if args.track_consensus:
+                    msg += f" consensus {float(metrics['consensus_sq']):.3e}"
+                print(msg, flush=True)
+            if args.ckpt_dir and (step + 1) % args.ckpt_every == 0:
+                path = save_checkpoint(args.ckpt_dir, jax.device_get(state),
+                                       metadata={"n_nodes": n_nodes,
+                                                 "algorithm": args.algorithm},
+                                       plane_layout=layout)
+                print(f"checkpointed -> {path}")
+            profiler.end(step, state)
+            if args.failure_drill and step == (start + args.steps) // 2:
+                print("FAILURE DRILL: checkpoint, shrink to n/2, rebuild, resume")
+                host = jax.device_get(state)
+                new_n = max(1, n_nodes // 2)
+                host = elastic_reshape(host, new_n)
+                mesh2 = make_mesh((new_n, tp), ("data", "model"),
+                                  devices=jax.devices()[: new_n * tp])
+                step_fn, opt, channel, bshard = build(mesh2, new_n)
+                host = ensure_channel_state(host, channel, new_n, layout)
+                state = jax.tree.map(jnp.asarray, host)
+                data = SyntheticLM(SyntheticLMConfig(
+                    vocab_size=cfg.vocab_size, seq_len=args.seq_len,
+                    per_node_batch=args.per_node_batch, n_nodes=new_n,
+                    heterogeneity=args.heterogeneity,
+                ))
+                n_nodes = new_n
+                remaining = args.steps - step - 1
+                it2 = prefetch_to_device(
+                    lambda k2: data.batch(step + 1 + k2), bshard, remaining,
+                )
+                for k2, batch2 in enumerate(it2):
+                    state, metrics = step_fn(state, batch2)
+                    losses.append(metrics["loss"])
+                    s2 = step + 1 + k2
+                    if s2 % args.log_every == 0 or s2 == args.steps - 1:
+                        print(f"step {s2:5d} loss {float(metrics['loss']):.4f} "
+                              f"(post-failure, {new_n} nodes)", flush=True)
+                break
 
     jax.block_until_ready(state)
     dt = time.time() - t0

@@ -157,6 +157,7 @@ def _block_attend(q, k, v, q_pos, k_pos, *, causal: bool, window: int, softcap: 
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
 
 
+@jax.named_scope("attn_core")
 def attention_core(
     q: jax.Array,
     k: jax.Array,
@@ -283,6 +284,7 @@ def attn_forward(
     return y
 
 
+@jax.named_scope("attn_core")
 def _masked_attention_traced_window(
     q, k, v, *, causal: bool, window, remat: bool, softcap: float, q_block: int = 512
 ):

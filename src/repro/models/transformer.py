@@ -442,16 +442,17 @@ def forward_loss(params, batch, cfg: ModelConfig, tp_ctx: TPContext, rt: Runtime
         collect_rows=collect_rows,
     )
     x = norm_apply(x, params["final_norm"], cfg.norm_type)
-    logits = lm_head_logits(x, _lm_head_w(params, cfg, tp_ctx, rt))
-    vp = cfg.vocab_padded(tp_ctx.size)
-    loss = softmax_xent_sharded(
-        logits.reshape(B * S, -1),
-        batch["targets"].reshape(-1),
-        tp_ctx,
-        vocab_size=cfg.vocab_size,
-        vocab_padded=vp,
-        mask=(batch["mask"].reshape(-1) if "mask" in batch else None),
-    )
+    with jax.named_scope("lm_head"):
+        logits = lm_head_logits(x, _lm_head_w(params, cfg, tp_ctx, rt))
+        vp = cfg.vocab_padded(tp_ctx.size)
+        loss = softmax_xent_sharded(
+            logits.reshape(B * S, -1),
+            batch["targets"].reshape(-1),
+            tp_ctx,
+            vocab_size=cfg.vocab_size,
+            vocab_padded=vp,
+            mask=(batch["mask"].reshape(-1) if "mask" in batch else None),
+        )
     total = loss + cfg.router_aux_weight * aux["moe_load_balance"] + 1e-3 * aux[
         "moe_router_z"
     ]

@@ -286,9 +286,12 @@ def build_train_step(
     mean = make_psum_mean(node_axes, n_nodes)
 
     def loss_fn(params, batch):
-        return T.forward_loss(
-            params, batch, cfg, tp_ctx, rt, collect_rows=tcfg.sparse_gossip
-        )
+        # the scope names the model's forward, its backward (as
+        # transpose(jvp(model))) and remat's recompute in the HLO metadata
+        with jax.named_scope("model"):
+            return T.forward_loss(
+                params, batch, cfg, tp_ctx, rt, collect_rows=tcfg.sparse_gossip
+            )
 
     def replicated_over_model(spec: P, x: jax.Array) -> jax.Array:
         """``x`` typed as its out_spec claims on the model axis.
@@ -353,7 +356,17 @@ def build_train_step(
         lr = lr_fn(step_idx)
 
         grads, loss, metrics = grads_of(params, batch)
+        return update_tail(
+            batch, params, opt_state, comp_state, step_idx, lr, grads, loss,
+            metrics,
+        )
 
+    @jax.named_scope("update_tail")
+    def update_tail(
+        batch, params, opt_state, comp_state, step_idx, lr, grads, loss, metrics
+    ):
+        """Everything after the gradient: finite guard, planes, update
+        kernel and gossip, metric reductions."""
         # finite guard: when the local grad norm goes non-finite, zero the
         # grads BEFORE the update path (the gossip payload this round stays
         # finite, so neighbors keep mixing clean iterates) and restore the

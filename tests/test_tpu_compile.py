@@ -14,9 +14,11 @@ compiles (a TPU executable written to it cannot be read back without the
 chip).
 """
 
+import contextlib
 import functools
 import importlib.util
 import os
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -61,16 +63,24 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture(autouse=True)
-def no_persistent_cache():
+@contextlib.contextmanager
+def _persistent_cache_off():
     from jax.experimental.compilation_cache import compilation_cache
 
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(autouse=True)
+def no_persistent_cache():
+    with _persistent_cache_off():
+        yield
 
 
 def _custom_calls(compiled) -> int:
@@ -109,37 +119,56 @@ def test_flash_attention_compiles_at_qwen3_heads(one_chip):
     assert _custom_calls(compiled) >= 1
 
 
-def test_one_node_train_step_fits_one_chip(topo, monkeypatch):
+@pytest.fixture(scope="module")
+def one_node_step(topo):
     """The chip smoke's step: qwen3-0.6b at published widths, DecentLaM on
     flat planes with the Pallas update, one node on one chip, at the
-    smoke's sequence length and batch.  The chip packs planes with
-    ``concat`` (the CPU takes ``gather``), so the compile does too."""
+    smoke's sequence length and batch, compiled once for the tests below.
+    The chip packs planes with ``concat`` (the CPU takes ``gather``), so the
+    compile does too."""
     smoke = _chip_smoke()
     args = train.parse_args(smoke.step_argv("pallas"))
     cfg, tcfg = train.model_config(args), train.train_config(args)
-    monkeypatch.setattr(
-        PlaneLayout, "pack", functools.partialmethod(PlaneLayout.pack, impl="concat")
-    )
-    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
-                axis_types=(AxisType.Auto,) * 2)
-    step, sspecs, bspecs, channel = build_train_step(cfg, tcfg, mesh)
-    state = abstract_train_state(
-        cfg, make_optimizer(tcfg.opt_config()), 1, 1, channel=channel,
-        plane_layout=model_plane_layout(cfg),
-    )
-    state = jax.tree.map(
-        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                          sharding=NamedSharding(mesh, s)),
-        state, sspecs,
-    )
-    batch = {
-        k: jax.ShapeDtypeStruct((args.per_node_batch, args.seq_len), jnp.int32,
-                                sharding=NamedSharding(mesh, bspecs[k]))
-        for k in ("tokens", "targets")
-    }
-    compiled = step.lower(state, batch).compile()
-    ma = compiled.memory_analysis()
+    with pytest.MonkeyPatch.context() as mp, _persistent_cache_off():
+        mp.setattr(PlaneLayout, "pack",
+                   functools.partialmethod(PlaneLayout.pack, impl="concat"))
+        mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                    axis_types=(AxisType.Auto,) * 2)
+        step, sspecs, bspecs, channel = build_train_step(cfg, tcfg, mesh)
+        state = abstract_train_state(
+            cfg, make_optimizer(tcfg.opt_config()), 1, 1, channel=channel,
+            plane_layout=model_plane_layout(cfg),
+        )
+        state = jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                              sharding=NamedSharding(mesh, s)),
+            state, sspecs,
+        )
+        batch = {
+            k: jax.ShapeDtypeStruct((args.per_node_batch, args.seq_len), jnp.int32,
+                                    sharding=NamedSharding(mesh, bspecs[k]))
+            for k in ("tokens", "targets")
+        }
+        return step.lower(state, batch).compile()
+
+
+def test_one_node_train_step_fits_one_chip(one_node_step):
+    ma = one_node_step.memory_analysis()
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
              + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     assert total <= HBM_BYTES, f"{total / 2**30:.2f} GiB > 16 GiB"
-    assert _custom_calls(compiled) > 0
+    assert _custom_calls(one_node_step) > 0
+
+
+def test_benchmark_finds_both_update_stages(one_node_step, monkeypatch):
+    """The benchmark's rule for the update kernel (``bench/harness.py``
+    ``Program.kernel_names``, which reads only the compiled step) finds
+    DecentLaM's two plane stages, by their ``fused_update_*`` names, and
+    nothing else."""
+    monkeypatch.syspath_prepend(os.path.join(ROOT, "bench"))
+    import harness
+
+    names = harness.Program.kernel_names(SimpleNamespace(compiled=one_node_step))
+    assert len(names) == _custom_calls(one_node_step) == 2
+    assert sorted(n.rsplit(".", 1)[0] for n in names) == [
+        "fused_update_post_decentlam_post", "fused_update_pre_grad_step"]
