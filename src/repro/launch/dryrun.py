@@ -73,7 +73,6 @@ def _runtime(args) -> T.RuntimeConfig:
         remat=args.remat,
         remat_policy=args.remat_policy,
         decode_grouped_gqa=args.decode_grouped_gqa,
-        q_block=args.q_block,
         mlstm_chunk=args.mlstm_chunk,
         ssm_chunk=args.ssm_chunk,
     )
@@ -252,7 +251,6 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, args) -> dict:
             "grad_accum": args.grad_accum,
             "remat": args.remat,
             "remat_policy": args.remat_policy,
-            "q_block": args.q_block,
             "decode_grouped_gqa": args.decode_grouped_gqa,
             "mlstm_chunk": args.mlstm_chunk,
             "ssm_chunk": args.ssm_chunk,
@@ -282,7 +280,6 @@ def main() -> None:
     p.add_argument("--decode-grouped-gqa", dest="decode_grouped_gqa",
                    action="store_true")
     p.add_argument("--ssm-chunk", dest="ssm_chunk", type=int, default=128)
-    p.add_argument("--q-block", dest="q_block", type=int, default=512)
     p.add_argument("--fused-update", dest="fused_update", action="store_true")
     p.add_argument("--gossip-serialize", dest="gossip_serialize",
                    action=argparse.BooleanOptionalAction, default=True)

@@ -6,8 +6,13 @@ Sharding scheme (DESIGN.md §4):
   (heads padded up to a multiple of tp; padded heads are masked so they
   neither contribute outputs nor receive gradients).  K/V are sharded over
   kv-heads when divisible, otherwise computed replicated (GQA kv-heads are
-  small).  Attention itself runs over q-blocks with a rematerialized
-  flash-style inner function so the S x S score matrix is never fully live.
+  small).  The jnp core loops over at most eight spans of q blocks, each
+  scored only against the keys it can see (the causal prefix, cut on the
+  left by a static window), so masked-out key blocks are never computed;
+  the blocks within a span run one after another.  In a rematerialised layer
+  each block is scored twice a step (forward and the layer's recompute);
+  only where one layer's probabilities are too large to save does each
+  block carry its own checkpoint, and a third scoring.
   The out-projection is row-sharded -> one psum.
 * **decode** — the KV cache is *sequence-sharded* over the model axis
   (split-K / flash-decoding): the new token's q is all-gathered (tiny), every
@@ -22,6 +27,7 @@ Sharding scheme (DESIGN.md §4):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -137,24 +143,77 @@ def _group_index(dims: AttnDims, tp_ctx: TPContext) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Core attention (q-block chunked, flash-style memory)
+# Core attention (q blocks over the key span each can see)
 # ---------------------------------------------------------------------------
 
+# A layer's saved probabilities (f32 softmax output and its cast to the value
+# dtype) under this many bytes are kept for the backward pass; above it each
+# q block is checkpointed and scored again there.
+PROBS_BUDGET_BYTES = 2**30
 
-def _block_attend(q, k, v, q_pos, k_pos, *, causal: bool, window: int, softcap: float):
-    """q: (B, bq, H, hd); k/v: (B, Sk, H, hd); positions give the mask."""
-    scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], jnp.float32))
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
+
+def default_q_block(Sq: int) -> int:
+    """Rows per q block for ``Sq`` queries: an eighth of them, rounded up to
+    whole tiles of 128 rows, and at most 512, so one block's scores are at
+    most 512 rows by ``Sk``.  At 2048 that is 256, which a TPU v5e ran faster
+    than 512: the causal core then scores 0.5625 of the score area, not
+    0.625."""
+    return min(128 * max(1, -(-Sq // 1024)), 512)
+
+
+def _block_spans(Sq: int, Sk: int, bq: int, causal: bool, window: int):
+    """``(q0, q1, lo, hi)`` per span of rows: rows ``[q0, q1)`` score keys
+    ``[lo, hi)``, the keys any of its rows can see.  The rows are cut into at
+    most eight spans of whole ``bq`` blocks, and neighbouring spans that see
+    the same keys are joined.  Causal: the key prefix up to the span's last
+    row; a static ``window > 0`` also drops the key blocks left of the first
+    key in any row's window."""
+    nb = -(-Sq // bq)
+    sb = bq * -(-nb // 8)
+    spans = []
+    for q0 in range(0, Sq, sb):
+        q1 = min(q0 + sb, Sq)
+        hi = min(q1, Sk) if causal else Sk
+        lo = max(q0 - window + 1, 0) // bq * bq if window > 0 else 0
+        if lo >= hi:  # no row sees a key: mask the full span, as a full row
+            lo, hi = 0, Sk
+        if spans and spans[-1][2:] == (lo, hi):
+            spans[-1] = (spans[-1][0], q1, lo, hi)
+        else:
+            spans.append((q0, q1, lo, hi))
+    return spans
+
+
+def score_block_share(Sq: int, Sk: int, bq: int, causal: bool, window: int = 0) -> float:
+    """Share of the ``Sq x Sk`` score area that the block core computes."""
+    area = sum((q1 - q0) * (hi - lo) for q0, q1, lo, hi in
+               _block_spans(Sq, Sk, bq, causal, window))
+    return area / (Sq * Sk)
+
+
+def _score_block(qb, kb, vb, *, q0, lo, causal, window, softcap):
+    """Rows ``q0 + [0, bq)`` of q against keys ``lo + [0, bk)``; a ``window``
+    > 0 masks keys at or past that distance.  ``q0`` is an int, or traced
+    where the blocks of a span are mapped: the mask is then always built."""
+    scale = 1.0 / jnp.sqrt(jnp.asarray(qb.shape[-1], jnp.float32))
+    s = jnp.einsum("bqhd,bkhd->bhqk", qb, kb).astype(jnp.float32) * scale
     if softcap > 0.0:
         s = softcap * jnp.tanh(s / softcap)
-    mask = jnp.ones((q_pos.shape[-1], k_pos.shape[-1]), bool)
-    if causal:
-        mask &= k_pos[None, :] <= q_pos[:, None]
-    if window > 0:
-        mask &= q_pos[:, None] - k_pos[None, :] < window
-    s = jnp.where(mask[None, None], s, NEG_INF)
+    bq, bk = qb.shape[1], kb.shape[1]
+    q_pos = q0 + jnp.arange(bq)[:, None]
+    k_pos = lo + jnp.arange(bk)[None, :]
+    static = isinstance(q0, int)
+    mask = None
+    # masks only where some row of the block cannot see some key of its span
+    if causal and (not static or lo + bk - 1 > q0):
+        mask = k_pos <= q_pos
+    if window > 0 and (not static or q0 + bq - 1 - lo >= window):
+        m = q_pos - k_pos < window
+        mask = m if mask is None else mask & m
+    if mask is not None:
+        s = jnp.where(mask[None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
+    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(vb.dtype), vb)
 
 
 @jax.named_scope("attn_core")
@@ -166,12 +225,23 @@ def attention_core(
     causal: bool,
     window: int = 0,
     softcap: float = 0.0,
-    q_block: int = 512,
+    q_block: int | None = None,
     impl: str = "jnp",
     remat: bool = True,
 ) -> jax.Array:
     """q: (B, Sq, H, hd); k/v: (B, Sk, Hkv_grouped-to-H, hd) — kv already
-    expanded to H heads.  Returns (B, Sq, H, hd)."""
+    expanded to H heads.  Returns (B, Sq, H, hd).
+
+    The jnp core is a static loop over at most eight row spans, each scored
+    against the keys it can see (``_block_spans``), in q blocks of
+    ``default_q_block`` rows; the whole blocks of a span that holds more than
+    one run one after another under ``lax.map``.  The blocks' outputs are
+    joined by one ``concatenate``.  Scores and softmax are f32; ``p`` is cast
+    to the value dtype for the PV product.  ``remat`` says the enclosing
+    layer is rematerialised: its recompute then saves what the backward pass
+    needs, unless one layer's saved probabilities pass ``PROBS_BUDGET_BYTES``,
+    where each block carries its own checkpoint and is scored once more in
+    the backward pass."""
     if impl in ("pallas", "pallas_interpret"):
         from ..kernels.flash_attention import ops as fa_ops
 
@@ -179,27 +249,31 @@ def attention_core(
             q, k, v, causal=causal, window=window,
             interpret=(impl == "pallas_interpret"),
         )
-
     B, Sq, H, hd = q.shape
     Sk = k.shape[1]
-    bq = min(q_block, Sq)
-    nb = Sq // bq if Sq % bq == 0 else 0
-    if nb == 0:  # ragged fallback: single block
-        bq, nb = Sq, 1
-    k_pos = jnp.arange(Sk)
+    bq = min(q_block or default_q_block(Sq), Sq)
+    probs_bytes = (B * H * Sq * Sk * score_block_share(Sq, Sk, bq, causal, window)
+                   * (4 + jnp.dtype(v.dtype).itemsize))
+    checkpoint = remat and probs_bytes > PROBS_BUDGET_BYTES
 
-    def block(qb_and_pos):
-        qb, q_pos = qb_and_pos
-        return _block_attend(
-            qb, k, v, q_pos, k_pos, causal=causal, window=window, softcap=softcap
-        )
+    def attend(qb, kb, vb, q0, lo):
+        block = functools.partial(_score_block, q0=q0, lo=lo, causal=causal,
+                                  window=window, softcap=softcap)
+        return (jax.checkpoint(block) if checkpoint else block)(qb, kb, vb)
 
-    if remat:
-        block = jax.checkpoint(block)
-    qs = q.reshape(B, nb, bq, H, hd).swapaxes(0, 1)  # (nb, B, bq, H, hd)
-    pos = jnp.arange(Sq).reshape(nb, bq)
-    out = jax.lax.map(block, (qs, pos))  # (nb, B, bq, H, hd)
-    return out.swapaxes(0, 1).reshape(B, Sq, H, hd)
+    outs = []
+    for q0, q1, lo, hi in _block_spans(Sq, Sk, bq, causal, window):
+        kb, vb = k[:, lo:hi], v[:, lo:hi]
+        n = (q1 - q0) // bq
+        if n > 1:
+            qs = q[:, q0:q0 + n * bq].reshape(B, n, bq, H, hd).swapaxes(0, 1)
+            out = jax.lax.map(lambda a: attend(a[0], kb, vb, a[1], lo),
+                              (qs, q0 + bq * jnp.arange(n)))
+            outs.append(out.swapaxes(0, 1).reshape(B, n * bq, H, hd))
+            q0 += n * bq
+        for r in range(q0, q1, bq):  # a span of one block, and a ragged tail
+            outs.append(attend(q[:, r:min(r + bq, q1)], kb, vb, r, lo))
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
 
 
 def _expand_kv(k: jax.Array, dims: AttnDims, tp_ctx: TPContext) -> jax.Array:
@@ -221,7 +295,7 @@ def attn_forward(
     *,
     positions: jax.Array | None = None,
     causal: bool = True,
-    window: int | jax.Array = 0,
+    window: int = 0,
     attn_impl: str = "jnp",
     remat: bool = True,
     return_kv: bool = False,
@@ -230,8 +304,7 @@ def attn_forward(
 ):
     """x: (B, S, d) replicated over model axis -> (B, S, d) replicated.
 
-    ``window`` may be a traced scalar (per-layer windows inside a scanned
-    stack) — it is applied via masking, which is shape-independent.
+    ``window`` is static: 0 is full attention, > 0 a sliding window.
     ``kv_source`` switches to cross-attention: k/v computed from it.
     """
     B, S, d = x.shape
@@ -260,16 +333,10 @@ def attn_forward(
     kf = _expand_kv(k, dims, tp_ctx)
     vf = _expand_kv(v, dims, tp_ctx)
 
-    if isinstance(window, (int,)) and attn_impl != "jnp":
-        out = attention_core(
-            q, kf, vf, causal=causal, window=int(window), impl=attn_impl, remat=remat,
-            softcap=cfg.logit_softcap,
-        )
-    else:
-        out = _masked_attention_traced_window(
-            q, kf, vf, causal=causal, window=window, remat=remat,
-            softcap=cfg.logit_softcap,
-        )
+    out = attention_core(
+        q, kf, vf, causal=causal, window=window, impl=attn_impl, remat=remat,
+        softcap=cfg.logit_softcap,
+    )
 
     out = out * _head_mask(dims, tp_ctx)[None, None, :, None].astype(dt)
     out = out.reshape(B, S, dims.h_local * dims.hd)
@@ -282,42 +349,6 @@ def attn_forward(
     if return_kv:
         return y, (k, v)
     return y
-
-
-@jax.named_scope("attn_core")
-def _masked_attention_traced_window(
-    q, k, v, *, causal: bool, window, remat: bool, softcap: float, q_block: int = 512
-):
-    """Chunked attention that accepts a *traced* window scalar (mask-based)."""
-    B, Sq, H, hd = q.shape
-    Sk = k.shape[1]
-    bq = min(q_block, Sq)
-    if Sq % bq != 0:
-        bq = Sq
-    nb = Sq // bq
-    k_pos = jnp.arange(Sk)
-    w = jnp.asarray(window, jnp.int32)
-
-    def block(args):
-        qb, q_pos = args
-        scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
-        s = jnp.einsum("bqhd,bkhd->bhqk", qb, k).astype(jnp.float32) * scale
-        if softcap > 0.0:
-            s = softcap * jnp.tanh(s / softcap)
-        m = jnp.ones((q_pos.shape[0], Sk), bool)
-        if causal:
-            m &= k_pos[None, :] <= q_pos[:, None]
-        m &= jnp.where(w > 0, q_pos[:, None] - k_pos[None, :] < w, True)
-        s = jnp.where(m[None, None], s, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
-
-    if remat:
-        block = jax.checkpoint(block)
-    qs = q.reshape(B, nb, bq, H, hd).swapaxes(0, 1)
-    pos = jnp.arange(Sq).reshape(nb, bq)
-    out = jax.lax.map(block, (qs, pos))
-    return out.swapaxes(0, 1).reshape(B, Sq, H, hd)
 
 
 # ---------------------------------------------------------------------------

@@ -73,7 +73,6 @@ class RuntimeConfig:
     # decode attention: contract q-head groups against the raw KV cache
     # (no (H/KV)-times K/V materialization); exact for unpadded-head configs
     decode_grouped_gqa: bool = False
-    q_block: int = 512
     ssm_chunk: int = 128
     mlstm_chunk: int = 128
 

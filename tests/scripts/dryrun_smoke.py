@@ -18,7 +18,7 @@ def args(**kw):
     base = dict(
         algorithm="decentlam", topology="exp", gossip_impl="ppermute",
         compression=None, grad_accum=0, remat=True, remat_policy="full",
-        q_block=512, mlstm_chunk=128, ssm_chunk=128, fused_update=False,
+        mlstm_chunk=128, ssm_chunk=128, fused_update=False,
         decode_grouped_gqa=False, gossip_serialize=True,
     )
     base.update(kw)

@@ -26,13 +26,14 @@ import numpy as np
 import pytest
 from jax.sharding import AxisType, Mesh, NamedSharding, SingleDeviceSharding
 
-from repro.configs import get_config
+from repro.configs import SHAPES, get_config
 from repro.core.optimizers import make_optimizer
 from repro.core.planes import LANES, PlaneLayout
 from repro.core.update_spec import MathCtx
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.fused_update import make_plane_stage
 from repro.launch import train
+from repro.models.attention import attention_core, score_block_share
 from repro.train.step import build_train_step
 from repro.train.train_state import abstract_train_state, model_plane_layout
 
@@ -117,6 +118,66 @@ def test_flash_attention_compiles_at_qwen3_heads(one_chip):
                               jnp.bfloat16, sharding=one_chip)
     compiled = flash_attention.lower(q, kv, kv, causal=True).compile()
     assert _custom_calls(compiled) >= 1
+
+
+def _compile_attention(one_chip, B: int, S: int, *, train: bool, **kw):
+    """The jnp attention core at qwen3-0.6b's heads, compiled for one v5e:
+    forward and backward under an outer checkpoint, as the layer scan applies
+    it (``train``), or the serving prefill's forward alone."""
+    x = jax.ShapeDtypeStruct((B, S, QWEN3.n_heads, QWEN3.hd), jnp.bfloat16,
+                             sharding=one_chip)
+    if not train:
+        core = functools.partial(attention_core, causal=True, remat=False, **kw)
+        return jax.jit(core).lower(x, x, x).compile()
+
+    def loss(q, k, v, ct):
+        out = attention_core(q, k, v, causal=True, remat=True, **kw)
+        return jnp.sum(out.astype(jnp.float32) * ct)
+
+    step = jax.jit(jax.grad(jax.checkpoint(loss), argnums=(0, 1, 2)))
+    return step.lower(x, x, x, x).compile()
+
+
+def _flops(compiled) -> float:
+    cost = compiled.cost_analysis()
+    return (cost[0] if isinstance(cost, list) else cost)["flops"]
+
+
+def _hbm_bytes(compiled) -> int:
+    ma = compiled.memory_analysis()
+    return (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+
+
+def test_causal_attention_core_skips_masked_key_blocks(one_chip):
+    """The causal core scores each q block only against its key prefix:
+    0.5625 of the score area at the default 256-row blocks (0.625 at 512),
+    so the compiled step does at most 0.7 of the FLOPs of the same core in
+    one block over the whole score area.  (A non-causal core maps its blocks
+    through a loop, whose body the cost analysis counts once.)"""
+    assert score_block_share(2048, 2048, 512, True) == 0.625
+    assert score_block_share(2048, 2048, 256, True) == 0.5625
+    causal = _flops(_compile_attention(one_chip, 2, 2048, train=True))
+    full = _flops(_compile_attention(one_chip, 2, 2048, train=True, q_block=2048))
+    assert causal <= 0.7 * full, (causal, full)
+
+
+@pytest.mark.parametrize("shape,B,train,limit_gib", [
+    ("prefill_32k", 1, False, 1.5),
+    ("train_4k", 2, True, 1.0),
+])
+def test_attention_core_fits_at_long_shapes(one_chip, shape, B, train, limit_gib):
+    """Past the benchmark's 2048 tokens the core keeps blocks of at most 512
+    rows: one 32k prefill sequence compiles to 1.30 GiB (blocks of an eighth
+    of it, 4096 rows, compile to 4.75 GiB), and a train_4k step, where each
+    block carries its own checkpoint, to 0.82 GiB.  There the step does at
+    most 0.7 of the one-block core's FLOPs."""
+    S = SHAPES[shape].seq_len
+    compiled = _compile_attention(one_chip, B, S, train=train)
+    assert _hbm_bytes(compiled) <= limit_gib * 2**30, _hbm_bytes(compiled) / 2**30
+    if train:
+        full = _compile_attention(one_chip, B, S, train=True, q_block=S)
+        assert _flops(compiled) <= 0.7 * _flops(full), (_flops(compiled), _flops(full))
 
 
 @pytest.fixture(scope="module")
