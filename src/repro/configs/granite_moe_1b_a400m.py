@@ -3,7 +3,9 @@
 
 24L d_model=1024 16H (kv=8) d_ff=512-per-expert vocab=49155, MoE 32e top-8.
 32 % 16 == 0 => true expert parallelism over the model axis.  Full
-attention => long_500k skipped.
+attention => long_500k skipped.  The published expert layer drops no
+token: capacity_factor = n_experts / top_k makes every expert's capacity
+the whole batch.
 """
 import dataclasses
 from .base import ModelConfig
@@ -19,6 +21,7 @@ CONFIG = ModelConfig(
     vocab_size=49155,
     n_experts=32,
     top_k=8,
+    capacity_factor=32 / 8,  # dropless
     rope_theta=10000.0,
     long_context_ok=False,
 )

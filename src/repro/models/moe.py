@@ -1,24 +1,39 @@
-"""Mixture-of-Experts layer (granite-moe family): top-k router + capacity
-dispatch, TPU-native.
+"""Mixture-of-Experts layer (granite-moe family): top-k router, sorted
+dispatch, grouped expert products.
 
-Dispatch is the sort-free "cumsum position + gather/scatter" dropless-with-
-capacity scheme: every token's slot within its expert is its running count
-(in token order); tokens beyond ``capacity`` are dropped (capacity_factor
-1.25 by default, as in GShard/Switch).  Expert compute is a single grouped
-einsum over (E_local, C, d) buffers — static shapes, MXU-friendly, no
-(T, E, C) one-hot monster.
+The router (f32 logits, softmax, top-k, renormalised gates) gives each
+token ``k`` assignments.  A stable sort of the ``T k`` assignments by
+expert puts each expert's rows together, in token order; ``group_sizes``
+counts them.  The expert weights then run as three grouped products over
+those ragged groups (``kernels/moe_gmm``: ``w_in``, ``w_gate``, and
+``w_out`` after the activation), and the rows go back to token order by
+the inverse permutation, to be scaled by their gates and summed over the
+``k`` assignments of each token.  No expert is padded to a capacity.
+
+``capacity_factor`` still bounds an expert: a row whose rank within its
+expert (its position in token order) is ``moe_capacity`` or more gets gate
+0, as GShard/Switch drop it.  When the capacity reaches the token count
+(``capacity_factor >= n_experts / top_k``) no row can pass it, and the
+layer is dropless with no mask at all.
 
 Expert sharding over the model axis picks the first exact fit:
-* ``E % tp == 0``      -> expert parallelism (granite-1b: 32 experts / 16);
+* ``E % tp == 0``      -> expert parallelism: each device runs only the
+                          groups of its ``E / tp`` experts (the kernel's
+                          group offset) and leaves the other rows 0;
 * ``d_ff % tp == 0``   -> tensor parallelism inside every expert
                           (granite-3b: 40 experts, 512 d_ff / 16);
 * otherwise replicated.
-Either way each device scatter-adds its partial token outputs and one psum
-combines them — the same collective as the dense-MLP path.
+Either way one psum combines the devices' partial outputs — the same
+collective as the dense-MLP path.
+
+Spans (``named_scope``): ``moe_route``, ``moe_dispatch``, ``moe_experts``
+and ``moe_combine``.  Counters in the aux dict: ``moe_dropped`` (rows past
+capacity) and ``moe_max_load`` (the busiest expert's rows over the mean).
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -27,6 +42,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ModelConfig
+from ..kernels.moe_gmm.ops import moe_gmm, sum_to
 from .layers import Initializer, TPContext, _ACTS
 
 Tree = Any
@@ -76,6 +92,47 @@ def moe_specs(cfg: ModelConfig, tp: int, model_axis: str = "model") -> Tree:
     return p
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(xt, order, inv, k: int):
+    """Rows of the ``T k`` assignments in sorted ``order``: token
+    ``order // k``.  Its transpose gathers by ``inv`` and sums each token's
+    ``k`` rows, with no scatter."""
+    return jnp.take(xt, order // k, axis=0)
+
+
+def _dispatch_fwd(xt, order, inv, k):
+    return _dispatch(xt, order, inv, k), (xt[:0], order, inv)
+
+
+def _dispatch_bwd(k, res, g):
+    like, order, inv = res  # like: xt's type, no rows
+    dx = jnp.take(g, inv, axis=0).reshape(-1, k, g.shape[-1])
+    dx = jnp.sum(dx, axis=1, dtype=jnp.float32).astype(g.dtype)
+    return sum_to(dx, like), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _permute(x, idx, inv):
+    """``x[idx]`` for a permutation ``idx`` with inverse ``inv``; its
+    transpose is the gather by ``inv``."""
+    return jnp.take(x, idx, axis=0)
+
+
+def _permute_fwd(x, idx, inv):
+    return _permute(x, idx, inv), (idx, inv)
+
+
+def _permute_bwd(res, g):
+    idx, inv = res
+    return jnp.take(g, inv, axis=0), None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
 def moe_forward(
     x: jax.Array,
     params: Tree,
@@ -89,82 +146,60 @@ def moe_forward(
     T = B * S
     xt = x.reshape(T, d)
 
-    # ---- router (fp32) ----
-    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32), params["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_idx = jax.lax.top_k(probs, k)  # (T, k)
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9
-    )
+    with jax.named_scope("moe_route"):
+        logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
+                            params["router"].astype(jnp.float32))
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, expert_idx = jax.lax.top_k(probs, k)  # (T, k)
+        gate_vals = gate_vals / jnp.maximum(
+            jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9
+        )
+        # Switch load-balance + router z-loss
+        counts = jnp.sum(jax.nn.one_hot(expert_idx, E, dtype=jnp.float32), axis=(0, 1))
+        aux_lb = E * jnp.sum(jnp.mean(probs, axis=0) * counts / T)
+        aux_z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
 
-    # ---- aux losses (Switch load-balance + router z-loss) ----
-    me = jnp.mean(probs, axis=0)  # (E,)
-    ce = jnp.mean(
-        jnp.sum(jax.nn.one_hot(expert_idx, E, dtype=jnp.float32), axis=1), axis=0
-    )
-    aux_lb = E * jnp.sum(me * ce)
-    aux_z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
-    aux = {"moe_load_balance": aux_lb, "moe_router_z": aux_z}
+    with jax.named_scope("moe_dispatch"):
+        flat_e = expert_idx.reshape(-1)  # (T*k,) expert of each assignment
+        order = jnp.argsort(flat_e, stable=True)  # by expert, then token
+        inv = jnp.zeros_like(order).at[order].set(jnp.arange(T * k, dtype=order.dtype))
+        group_sizes = counts.astype(jnp.int32)  # exact: counts < 2**24
+        gates = gate_vals.reshape(-1)
+        C = moe_capacity(cfg, T)
+        if C < T:
+            starts = jnp.cumsum(group_sizes) - group_sizes
+            rank = jnp.arange(T * k) - starts[flat_e[order]]  # in sorted order
+            gates = jnp.where(jnp.take(rank, inv) < C, gates, 0.0)
+            dropped = jnp.sum(jnp.maximum(group_sizes - C, 0)).astype(jnp.float32)
+        else:
+            dropped = jnp.float32(0.0)
+        xs = _dispatch(xt, order, inv, k)  # (T*k, d), sorted by expert
 
-    # ---- capacity positions: running count per expert in token order ----
-    C = moe_capacity(cfg, T)
-    flat_e = expert_idx.reshape(-1)  # (T*k,) expert of each assignment
-    onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)  # (T*k, E)
-    pos = jnp.cumsum(onehot, axis=0) - 1  # position among same-expert assigns
-    pos = jnp.sum(pos * onehot, axis=-1)  # (T*k,)
-    keep = pos < C
+    aux = {
+        "moe_load_balance": aux_lb,
+        "moe_router_z": aux_z,
+        "moe_dropped": dropped,
+        "moe_max_load": jnp.max(counts) * E / (T * k),
+        # experts that any kept row reaches (row-sparse gossip tracking):
+        # an expert's first row in token order is always within capacity
+        "moe_expert_hits": (group_sizes > 0).astype(jnp.float32),
+    }
 
-    # dispatch tables: token index + gate per (expert, slot)
-    tok_of = jnp.arange(T).repeat(k)  # (T*k,)
-    slot_e = jnp.where(keep, flat_e, E)  # dropped -> OOB expert row
-    table = jnp.full((E + 1, C), T, jnp.int32)  # T = OOB token -> zero pad
-    table = table.at[slot_e, jnp.where(keep, pos, 0)].set(
-        jnp.where(keep, tok_of, T), mode="drop"
-    )
-    gtable = jnp.zeros((E + 1, C), jnp.float32)
-    gtable = gtable.at[slot_e, jnp.where(keep, pos, 0)].set(
-        jnp.where(keep, gate_vals.reshape(-1), 0.0), mode="drop"
-    )
-    table, gtable = table[:E], gtable[:E]
+    with jax.named_scope("moe_experts"):
+        mode = _expert_sharding(cfg, tp_ctx.size)
+        offset = (tp_ctx.axis_index() * params["w_in"].shape[0]
+                  if mode == "expert" else None)
+        h = moe_gmm(xs, params["w_in"].astype(dt), group_sizes, offset)
+        if "w_gate" in params:
+            g = moe_gmm(xs, params["w_gate"].astype(dt), group_sizes, offset)
+            h = _ACTS[cfg.act](g) * h
+        else:
+            h = _ACTS[cfg.act](h)
+        y = moe_gmm(h, params["w_out"].astype(dt), group_sizes, offset)
 
-    # touched-expert hits for row-sparse gossip tracking: expert e is hit iff
-    # any *kept* assignment routes to it (capacity-dropped tokens produce no
-    # gradient on the expert — slot_e already maps them to the OOB row)
-    aux["moe_expert_hits"] = (
-        jnp.zeros((E,), jnp.float32)
-        .at[slot_e]
-        .max(jnp.ones_like(slot_e, jnp.float32), mode="drop")
-    )
-
-    # ---- local expert slab ----
-    E_local = params["w_in"].shape[0]
-    if E_local < E:  # expert-parallel: slice this device's rows
-        lo = tp_ctx.axis_index() * E_local
-        table_l = jax.lax.dynamic_slice_in_dim(table, lo, E_local, axis=0)
-        gtable_l = jax.lax.dynamic_slice_in_dim(gtable, lo, E_local, axis=0)
-    else:
-        table_l, gtable_l = table, gtable
-
-    xpad = jnp.concatenate([xt, jnp.zeros((1, d), dt)], axis=0)  # OOB row
-    xin = jnp.take(xpad, table_l, axis=0)  # (E_local, C, d)
-
-    h = jnp.einsum("ecd,edf->ecf", xin, params["w_in"].astype(dt))
-    if "w_gate" in params:
-        g = jnp.einsum("ecd,edf->ecf", xin, params["w_gate"].astype(dt))
-        h = _ACTS[cfg.act](g) * h
-    else:
-        h = _ACTS[cfg.act](h)
-    y = jnp.einsum("ecf,efd->ecd", h, params["w_out"].astype(dt))
-    y = y * gtable_l[..., None].astype(dt)
-
-    # ---- combine: scatter-add partial outputs, then one psum ----
-    out = jnp.zeros((T + 1, d), jnp.float32)
-    out = out.at[table_l.reshape(-1)].add(
-        y.reshape(-1, d).astype(jnp.float32), mode="drop"
-    )
-    out = out[:T]
-    if _expert_sharding(cfg, tp_ctx.size) == "replicated":
-        pass  # every device already holds the full output; no reduction
-    else:
-        out = tp_ctx.psum(out)
+    with jax.named_scope("moe_combine"):
+        y = _permute(y, inv, order).reshape(T, k, d)  # back to token order
+        out = jnp.sum(y.astype(jnp.float32) * gates.reshape(T, k, 1), axis=1)
+        if mode != "replicated":
+            out = tp_ctx.psum(out)
     return out.reshape(B, S, d).astype(dt), aux

@@ -355,6 +355,8 @@ def _run_groups(
     eliminates when unused.
     """
     aux_tot = {"moe_load_balance": jnp.float32(0.0), "moe_router_z": jnp.float32(0.0)}
+    if cfg.moe:
+        aux_tot.update(moe_dropped=jnp.float32(0.0), moe_max_load=jnp.float32(0.0))
     entries = {}
     row_info = {}
     for gi, g in enumerate(groups):
@@ -374,7 +376,10 @@ def _run_groups(
         x, (auxs, entry_stack) = jax.lax.scan(body, x, gp)
         for k in aux_tot:
             if auxs and k in auxs:
-                aux_tot[k] = aux_tot[k] + jnp.sum(auxs[k])
+                if k == "moe_max_load":  # the busiest expert of any layer
+                    aux_tot[k] = jnp.maximum(aux_tot[k], jnp.max(auxs[k]))
+                else:
+                    aux_tot[k] = aux_tot[k] + jnp.sum(auxs[k])
         if collect_rows and auxs and "moe_expert_hits" in auxs:
             row_info[f"moe/g{gi}"] = auxs["moe_expert_hits"]  # (Lg, E)
         if serve:

@@ -499,6 +499,8 @@ def build_train_step(
     mspecs = {"loss": P(), "lr": P(), "gossip_gap": P(), "xent": P(),
               "moe_load_balance": P(), "moe_router_z": P(),
               "skipped_nonfinite": P()}
+    if cfg.moe:  # the expert layer's counters (models/moe.py)
+        mspecs.update(moe_dropped=P(), moe_max_load=P())
     if tcfg.track_consensus:
         mspecs["consensus_sq"] = P()
 

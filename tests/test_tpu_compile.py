@@ -233,3 +233,63 @@ def test_benchmark_finds_both_update_stages(one_node_step, monkeypatch):
     assert len(names) == _custom_calls(one_node_step) == 2
     assert sorted(n.rsplit(".", 1)[0] for n in names) == [
         "fused_update_post_decentlam_post", "fused_update_pre_grad_step"]
+
+
+@pytest.fixture(scope="module")
+def granite_step(topo):
+    """The benchmark's granite cell compiled for one v5e: granite-3.0-1b-a400m
+    at published widths, 8 layers, dropless, seq 2048 x batch 4, DecentLaM
+    on flat planes with the Pallas update, built from the cell's own files
+    as ``bench/harness.py`` builds it."""
+    import json
+    import sys
+
+    sys.path.insert(0, os.path.join(ROOT, "bench"))
+    try:
+        import harness
+    finally:
+        sys.path.pop(0)
+    with open(os.path.join(ROOT, "bench", "configs", "granite-3.0-1b-a400m.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(ROOT, "bench", "traffic", "1node.s2048.b4.json")) as f:
+        traffic = json.load(f)
+    mc, tcfg = harness.model_config(cfg), harness.train_config(cfg, traffic)
+    with pytest.MonkeyPatch.context() as mp, _persistent_cache_off():
+        mp.setattr(PlaneLayout, "pack",
+                   functools.partialmethod(PlaneLayout.pack, impl="concat"))
+        mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                    axis_types=(AxisType.Auto,) * 2)
+        step, sspecs, bspecs, channel = build_train_step(mc, tcfg, mesh,
+                                                         node_axes=("data",))
+        state = abstract_train_state(
+            mc, make_optimizer(tcfg.opt_config()), 1, 1, channel=channel,
+            plane_layout=model_plane_layout(mc),
+        )
+        state = jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                              sharding=NamedSharding(mesh, s)),
+            state, sspecs,
+        )
+        shape = (traffic["per_node_batch"], traffic["seq_len"])
+        batch = {
+            k: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                    sharding=NamedSharding(mesh, bspecs[k]))
+            for k in ("tokens", "targets")
+        }
+        return step.lower(state, batch).compile()
+
+
+def test_granite_step_fits_one_chip_with_grouped_expert_kernels(granite_step):
+    """The dropless granite step fits one chip (at most 15 GiB by
+    ``memory_analysis``), runs its expert products in the named grouped
+    kernels (forward, dlhs and drhs), and holds no expert buffer padded to
+    a capacity, ``(32, C, 1024)``, as the capacity tables had (the only
+    such shape left is ``w_out``'s ``(32, 512, 1024)``)."""
+    import re
+
+    gib = _hbm_bytes(granite_step) / 2**30
+    assert gib <= 15.0, f"{gib:.2f} GiB"
+    text = granite_step.as_text()
+    kernels = set(re.findall(r"%(moe_gmm_(?:fwd|dlhs|drhs))[.\d]* = ", text))
+    assert kernels == {"moe_gmm_fwd", "moe_gmm_dlhs", "moe_gmm_drhs"}, kernels
+    assert set(re.findall(r"\[32,(\d+),1024\]", text)) == {"512"}
