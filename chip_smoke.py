@@ -1,6 +1,6 @@
 """Chip smoke test: the DecentLaM train step on a TPU, through the normal path.
 
-    python chip_smoke.py            # one chip, one gossip node: phases a-e
+    python chip_smoke.py            # one chip, one gossip node: phases a-e, g
     python chip_smoke.py --chips 4  # four chips, four gossip nodes: phase f
 
 Every phase runs in this one process (a chip belongs to one process), and
@@ -21,6 +21,12 @@ f. ``--chips 4`` only: the step on a (4, 1) mesh, four nodes on a ring with
    heterogeneous data, each node's params on its own chip, and one
    ``PpermuteChannel`` round on the mesh against ``StackedChannel`` on one
    device.
+g. The finite guard tripped inside the Pallas update kernels: the tiny LM's
+   DecentLaM step on flat planes, its loss scaled by inf on a batch made of
+   one token (non-finite grads) and by 0 on another (the ``g = 0`` update).
+   The poisoned step must count ``skipped_nonfinite == 1``, leave the
+   momentum planes as they were bit for bit, give the params exactly the
+   ``g = 0`` step's, and the next step must train.
 
 Any failed check exits 1 and prints no result line.
 """
@@ -255,10 +261,105 @@ def phase_gossip_round(mesh) -> None:
           "non-finite gossip output")
 
 
+POISON_TOKEN, ZERO_TOKEN = 1, 2  # (g) batches made of one token id
+
+
+def phase_guard(impl: str = "pallas") -> None:
+    """(g) One non-finite step through the guarded update kernels."""
+    import jax.numpy as jnp
+
+    from repro.configs import tiny_lm
+    from repro.core.schedules import ScheduleConfig
+    from repro.launch.mesh import make_mesh
+    from repro.models import transformer as T
+    from repro.train.step import TrainConfig
+
+    cfg = tiny_lm()
+    tcfg = TrainConfig(
+        algorithm="decentlam", topology="ring", momentum=0.9,
+        flat_planes=True, fused_update=True, fused_impl=impl,
+        schedule=ScheduleConfig(kind="constant", peak_lr=1e-2),
+    )
+    forward_loss = T.forward_loss
+
+    def scaled_loss(params, batch, *a, **kw):
+        loss, metrics = forward_loss(params, batch, *a, **kw)
+        tok = batch["tokens"]
+        scale = jnp.where(jnp.all(tok == POISON_TOKEN), jnp.inf,
+                          jnp.where(jnp.all(tok == ZERO_TOKEN), 0.0, 1.0))
+        return loss * scale, metrics
+
+    mesh = make_mesh((1, 1), ("data", "model"))
+    T.forward_loss = scaled_loss  # read when the step is traced
+    try:
+        _guard_steps(cfg, tcfg, mesh, impl)
+    finally:
+        T.forward_loss = forward_loss
+
+
+def _guard_steps(cfg, tcfg, mesh, impl: str) -> None:
+    """(g) body: the steps and the checks."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core.optimizers import make_optimizer
+    from repro.train.step import build_train_step
+    from repro.train.train_state import init_train_state, model_plane_layout
+
+    step, _, _, channel = build_train_step(cfg, tcfg, mesh)
+    state = init_train_state(
+        jax.random.key(SEED), cfg, make_optimizer(tcfg.opt_config()), 1, 1,
+        mesh=mesh, node_axes=("data",), channel=channel,
+        plane_layout=model_plane_layout(cfg, 1),
+    )
+    rng = np.random.default_rng(SEED)
+
+    def batch(tokens):
+        return {"tokens": jnp.asarray(tokens, jnp.int32),
+                "targets": jnp.asarray(np.roll(tokens, -1, 1), jnp.int32)}
+
+    def random_batch():
+        return batch(rng.integers(3, cfg.vocab_size, (PER_NODE_BATCH, 128)))
+
+    def const_batch(tok):
+        return batch(np.full((PER_NODE_BATCH, 128), tok))
+
+    def copy(tree):  # the step donates its state
+        return jax.tree.map(jnp.copy, tree)
+
+    def bits(tree):
+        return [np.asarray(a).tobytes() for a in jax.tree.leaves(tree)]
+
+    n_custom = count_custom_calls(
+        step.lower(state, random_batch()).compile().as_text())
+    state, m0 = step(state, random_batch())  # momentum != 0 from here
+    check(float(m0["skipped_nonfinite"]) == 0.0, "guard tripped on finite grads")
+    m_before = bits(state["opt"]["m"])
+    zero_state, mz = step(copy(state), const_batch(ZERO_TOKEN))
+    bad_state, mb = step(copy(state), const_batch(POISON_TOKEN))
+    skipped = float(mb["skipped_nonfinite"])
+    m_kept = bits(bad_state["opt"]["m"]) == m_before
+    x_g0 = bits(bad_state["params"]) == bits(zero_state["params"])
+    next_state, mn = step(bad_state, random_batch())
+    trains = (float(mn["skipped_nonfinite"]) == 0.0
+              and math.isfinite(float(mn["loss"]))
+              and bits(next_state["opt"]["m"]) != m_before)
+    print(f"guard ({impl}): tpu_custom_calls={n_custom} "
+          f"skipped_nonfinite={skipped} momentum_unchanged={m_kept} "
+          f"params_equal_g0_step={x_g0} next_step_trains={trains} "
+          f"next_loss={float(mn['loss']):.6g}", flush=True)
+    check(impl != "pallas" or n_custom > 0, "no tpu_custom_call in the step")
+    check(skipped == 1.0, f"skipped_nonfinite = {skipped} on non-finite grads")
+    check(m_kept, "the momentum planes changed on a skipped step")
+    check(x_g0, "the params of the skipped step differ from the g = 0 step's")
+    check(trains, "the step after the skipped one did not train")
+
+
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     p.add_argument("--chips", type=int, default=1, choices=(1, 4),
-                   help="1: phases a-e on one chip; 4: phase f only")
+                   help="1: phases a-e and g on one chip; 4: phase f only")
     args = p.parse_args(argv)
 
     # (a) device check, before anything else runs
@@ -288,6 +389,7 @@ def main(argv=None) -> int:
     try:
         if args.chips == 1:
             phase_one_chip(train)
+            phase_guard()
         else:
             phase_four_chips(train)
     except SmokeFailure as e:

@@ -239,7 +239,7 @@ def make_optimizer(cfg: OptimizerConfig) -> Optimizer:
     # ---------------- step ----------------
     def step(
         params, grads, state, *, lr, step_idx, gossip, mean,
-        comp_state=no_comp, node_gaps=None,
+        comp_state=no_comp, node_gaps=None, ok=None,
     ):
         x, new_state, comp_state = run_update(
             spec,
@@ -254,6 +254,7 @@ def make_optimizer(cfg: OptimizerConfig) -> Optimizer:
             comp_state=comp_state,
             stage=reference_stage,
             node_gaps=node_gaps,
+            ok=ok,
         )
         out = jax.tree.map(lambda p, nx: nx.astype(p.dtype), params, x)
         return out, new_state, comp_state

@@ -173,6 +173,10 @@ class MathCtx:
     decoupled_wd: bool = False  # fold  x <- x - lr*wd*x  into this post stage
     clip: bool = False  # multiply g by the global clip scale s["gs"]
     lars: bool = False  # multiply g by the per-leaf trust ratio s["r"]
+    # finite guard folded into the stage: g reads as where(ok, g, 0) and a
+    # state output whose old value is an input keeps that old value when
+    # s["ok"] is 0.0 (the grads went non-finite); see run_update's ``ok``
+    guard: bool = False
 
 
 def math_ctx(cfg, *, nesterov_ok: bool, apply_decoupled_wd: bool) -> MathCtx:
@@ -267,12 +271,30 @@ def post_io(op: str) -> tuple[tuple[str, ...], tuple[str, ...]]:
     return _POST_IO[op]
 
 
+def _ok(s, like):
+    """The guard's predicate as a mask of ``like``'s shape (the f32 scalar
+    is broadcast before the compare, so the kernels select on a vector
+    mask)."""
+    return jnp.broadcast_to(jnp.asarray(s["ok"], jnp.float32), like.shape) != 0.0
+
+
+def _keep(ctx: MathCtx, s, new, old):
+    """A state output under the guard: ``old`` when the grads went
+    non-finite (costs no HBM bytes: ``old`` is already an operand)."""
+    return jnp.where(_ok(s, new), new, old) if ctx.guard else new
+
+
 def _g_eff(ctx: MathCtx, s, x, g):
-    """Clip-scale + coupled weight decay + LARS, folded into the stage.
+    """Guard zeroing + clip-scale + coupled weight decay + LARS, folded into
+    the stage.
 
     Mirrors ``optimizers._preprocess_grads`` order exactly: clip first, then
-    ``wd*x + g``, then the trust ratio on the decayed gradient.
+    ``wd*x + g``, then the trust ratio on the decayed gradient.  The guard
+    zeroes ``g`` before all three, as the unfused path zeroes the grads
+    before the update.
     """
+    if ctx.guard:
+        g = jnp.where(_ok(s, g), g, 0.0)
     if ctx.clip:
         g = s["gs"] * g
     if ctx.coupled_wd:
@@ -334,16 +356,20 @@ def pre_math(op: str, ctx: MathCtx, s, **v):
     if op == "momentum_payload":
         g = _g_eff(ctx, s, v["x"], v["g"])
         m = ctx.beta * v["m"] + g
-        return {"payload": v["x"] - lr * _with_nesterov(ctx, m, g), "m": m}
+        return {
+            "payload": v["x"] - lr * _with_nesterov(ctx, m, g),
+            "m": _keep(ctx, s, m, v["m"]),
+        }
     if op == "momentum_accum":
         g = _g_eff(ctx, s, v.get("x"), v["g"])
         m = ctx.beta * v["m"] + g
-        return {"payload": m, "m": m}
+        return {"payload": m, "m": _keep(ctx, s, m, v["m"])}
     if op == "x_minus_lr_m":
         return {"payload": v["x"] - lr * v["m"]}
     if op == "momentum_keep_x":
         g = _g_eff(ctx, s, v["x"], v["g"])
-        return {"payload": v["x"], "m": ctx.beta * v["m"] + g}
+        m = ctx.beta * v["m"] + g
+        return {"payload": v["x"], "m": _keep(ctx, s, m, v["m"])}
     if op == "qg_payload":
         g = _g_eff(ctx, s, v["x"], v["g"])
         return {"payload": v["x"] - lr * (ctx.beta * v["m"] + g)}
@@ -351,7 +377,7 @@ def pre_math(op: str, ctx: MathCtx, s, **v):
         g = _g_eff(ctx, s, v["x"], v["g"])
         m = ctx.beta * v["m"] + g
         z = 2.0 * v["x"] - v["x_prev"] - lr * (m - v["m_prev"])
-        return {"payload": z, "m": m}
+        return {"payload": z, "m": _keep(ctx, s, m, v["m"])}
     raise ValueError(f"unknown pre op {op!r}")
 
 
@@ -368,15 +394,15 @@ def post_math(op: str, ctx: MathCtx, s, **v):
     if op == "momentum_step":
         m = ctx.beta * v["m"] + v["mix"]
         x = v["x"] - lr * _with_nesterov(ctx, m, v["mix"])
-        return {"x": _decay(ctx, lr, x), "m": m}
+        return {"x": _decay(ctx, lr, x), "m": _keep(ctx, s, m, v["m"])}
     if op == "qg_post":
         m = ctx.beta * v["m"] + (1.0 - ctx.beta) * (v["x"] - v["mix"]) / safe_lr
-        return {"x": _decay(ctx, lr, v["mix"]), "m": m}
+        return {"x": _decay(ctx, lr, v["mix"]), "m": _keep(ctx, s, m, v["m"])}
     if op == "decentlam_post":
         g_tilde = (v["x"] - v["mix"]) / safe_lr
         m = ctx.beta * v["m"] + g_tilde
         x = v["x"] - lr * _with_nesterov(ctx, m, g_tilde)
-        return {"x": _decay(ctx, lr, x), "m": m}
+        return {"x": _decay(ctx, lr, x), "m": _keep(ctx, s, m, v["m"])}
     if op == "decentlam_sa_post":
         # Staleness-aware DecentLaM, a gap-scheduled decentlam -> dsgd
         # interpolation.  Under stale mixing the implicit gradient carries a
@@ -398,7 +424,7 @@ def post_math(op: str, ctx: MathCtx, s, **v):
         else:
             applied = sg * (ctx.beta * v["m"]) + drift
         x = v["x"] - lr * applied
-        return {"x": _decay(ctx, lr, x), "m": m}
+        return {"x": _decay(ctx, lr, x), "m": _keep(ctx, s, m, v["m"])}
     raise ValueError(f"unknown post op {op!r}")
 
 
@@ -457,8 +483,9 @@ def _f32_tree(tree: Tree) -> Tree:
 
 
 def _leaf_scalars(scalars, treedef, ctx: MathCtx):
-    """Per-leaf (lr, gs, r, sg) tuples; r may be a tree of scalars (LARS),
-    sg is the staleness damping (scalar, or (n,) in the stacked layout)."""
+    """Per-leaf (lr, gs, r, sg[, ok]) dicts; r may be a tree of scalars
+    (LARS), sg is the staleness damping (scalar, or (n,) in the stacked
+    layout), ok the finite guard's flag when a guarded stage needs it."""
     n = treedef.num_leaves
     r = scalars.get("r")
     if ctx.lars and r is not None and jax.tree.structure(r) == treedef:
@@ -471,9 +498,11 @@ def _leaf_scalars(scalars, treedef, ctx: MathCtx):
     sg = scalars.get("sg")
     if sg is None:
         sg = jnp.float32(1.0)
-    return [
-        {"lr": scalars["lr"], "gs": gs, "r": rs[i], "sg": sg} for i in range(n)
-    ]
+    out = [{"lr": scalars["lr"], "gs": gs, "r": rs[i], "sg": sg} for i in range(n)]
+    if ctx.guard:
+        for d in out:
+            d["ok"] = scalars["ok"]
+    return out
 
 
 def reference_stage(kind, op, ctx, operands, scalars, like_x):
@@ -500,6 +529,67 @@ def reference_stage(kind, op, ctx, operands, scalars, like_x):
     return {n: jax.tree.unflatten(treedef, col) for n, col in out_cols.items()}
 
 
+def _guard_plan(spec: UpdateSpec, cfg):
+    """How :func:`run_update` applies the finite guard to ``spec``, decided
+    statically: ``(folds, zero_g, unrestored)``.
+
+    ``folds[j]`` says whether the guard folds into stage ``j`` (pre and
+    post of each phase, in order, free ones included).  A stage folds when
+    each state key it writes is restored against the step's original value
+    (the old value is its own input, and no earlier stage wrote the key)
+    and is the step's final value (no later stage reads or writes the key).
+    So no stage ever reads a restored value, and payloads and params use
+    the unrestored state, as the guard done around the update does.  It
+    fails for awc-dmsgd's payload stage, whose momentum the recombine stage
+    reads, for da-dmsgd's, whose momentum ``assign_m`` overwrites, and for
+    the free ``assign_m``, which does not read the old momentum.
+
+    ``zero_g``: ``g`` needs a ``where`` over the whole tree, because a free
+    payload hands it to the communication as it is, or a stage that cannot
+    fold reads it.
+
+    ``unrestored``: the state keys the step writes that no folded stage
+    restores (those of the stages that cannot fold, d2's ``x_prev`` and
+    ``m_prev``, SlowMo's ``u`` and ``anchor``); each takes a ``where`` over
+    its tree.
+    """
+    ios, free = [], []
+    for i, ph in enumerate(spec.phases):
+        ctx = phase_ctx(cfg, spec, i)
+        ios += [pre_io(ph.pre, ctx), post_io(ph.post)]
+        free += [pre_is_free(ph, ctx), post_is_free(ph, ctx)]
+    folds, written, restored = [], set(), set()
+    for j, (ins, outs) in enumerate(ios):
+        state_outs = {k for k in outs if k not in ("x", "payload")}
+        fold = all(
+            k in ins
+            and not any(k in o for _, o in ios[:j])
+            and not any(k in i_ or k in o for i_, o in ios[j + 1:])
+            for k in state_outs
+        )
+        folds.append(fold)
+        written |= state_outs
+        if fold:
+            restored |= state_outs
+    zero_g = any(
+        "g" in ins and (is_free or not fold)
+        for (ins, _), is_free, fold in zip(ios, free, folds)
+    )
+    if spec.d2_state:
+        written |= {"x_prev", "m_prev"}
+    if spec.slowmo_outer:
+        written |= {"u", "anchor"}
+    return folds, zero_g, written - restored
+
+
+def zero_unless(ok, tree: Tree) -> Tree:
+    """``tree`` with every leaf zeroed where ``ok`` is False; ``tree`` itself
+    when there is no guard (``ok`` is None)."""
+    if ok is None:
+        return tree
+    return jax.tree.map(lambda a: jnp.where(ok, a, jnp.zeros_like(a)), tree)
+
+
 def run_update(
     spec: UpdateSpec,
     cfg,
@@ -515,6 +605,7 @@ def run_update(
     stage: StageFn = reference_stage,
     node_gaps=None,
     scalars: dict | None = None,
+    ok=None,
 ):
     """Walk the spec's phases; returns ``(x, new_state, comp_state)``.
 
@@ -536,11 +627,29 @@ def run_update(
     :func:`repro.core.planes.plane_scalars` computes them on the original
     trees (bit-identical to this default) and hands them in with the LARS
     tree already converted to row-indexed columns.
+
+    ``ok`` (a traced bool scalar, or ``None`` for no guard) is the finite
+    guard: when it is False the update runs with ``g = 0`` and the state
+    comes back as it went in, while ``x`` keeps its ``g = 0`` update.  The
+    stages fold it in (``MathCtx.guard``, the flag in the stage scalars'
+    ``ok``), so it costs no pass over the planes where a stage already
+    reads ``g`` and the old state.  Where ``g`` or a state key goes around
+    a stage (a free payload, a free ``assign_m``, awc-dmsgd's momentum, the
+    d2 and SlowMo state), a ``jnp.where`` over that tree keeps the same
+    result.  Caller-supplied ``scalars`` must come from zeroed grads.
     """
     lr = jnp.asarray(lr, jnp.float32)
     safe_lr = jnp.maximum(lr, 1e-12)
-    scalars = dict(grad_scalars(cfg, x, g)) if scalars is None else dict(scalars)
+    guard = ok is not None
+    if scalars is None:
+        scalars = grad_scalars(cfg, x, zero_unless(ok, g))
+    scalars = dict(scalars)
     scalars["lr"] = lr
+    if guard:
+        scalars["ok"] = jnp.asarray(ok).astype(jnp.float32)
+        folds, zero_g, unrestored = _guard_plan(spec, cfg)
+        if zero_g:
+            g = zero_unless(ok, g)
 
     env: dict[str, Tree] = {"x": x, "g": g}
     for k in ("m", "x_prev", "m_prev"):
@@ -548,17 +657,22 @@ def run_update(
             env[k] = state[k]
     x0 = x
 
+    def run_stage(kind, op, ctx, ins, j, mixed=None):
+        """One stage of the walk (stage ``j`` of the guard's ``folds``)."""
+        if guard and folds[j]:
+            ctx = dataclasses.replace(ctx, guard=True)
+        operands = {n: (mixed if n == "mix" else env[n]) for n in ins}
+        return stage(kind, op, ctx, operands, scalars, env["x"])
+
     for i, ph in enumerate(spec.phases):
         ctx = phase_ctx(cfg, spec, i)
 
         # --- PRE: build the payload (and usually the new momentum) ---------
         if pre_is_free(ph, ctx):
-            payload = _f32_tree(env["g"])  # nothing to fuse
+            payload = _f32_tree(env["g"])  # nothing to fuse (zeroed: zero_g)
         else:
             ins, _ = pre_io(ph.pre, ctx)
-            out = stage(
-                "pre", ph.pre, ctx, {n: env[n] for n in ins}, scalars, env["x"]
-            )
+            out = run_stage("pre", ph.pre, ctx, ins, 2 * i)
             payload = out.pop("payload")
             env.update(out)
 
@@ -594,9 +708,7 @@ def run_update(
                 )
         else:
             ins, _ = post_io(ph.post)
-            operands = {n: (mixed if n == "mix" else env[n]) for n in ins}
-            out = stage("post", ph.post, ctx, operands, scalars, env["x"])
-            env.update(out)
+            env.update(run_stage("post", ph.post, ctx, ins, 2 * i + 1, mixed))
 
     x = env["x"]
     new_state = dict(state)
@@ -645,4 +757,9 @@ def run_update(
                 x,
             )
 
+    if guard:
+        for k in [k for k in new_state if k in unrestored]:
+            new_state[k] = jax.tree.map(
+                lambda a, b: jnp.where(ok, a, b), new_state[k], state[k]
+            )
     return x, new_state, comp_state

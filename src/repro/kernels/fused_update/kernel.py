@@ -7,9 +7,10 @@ kernel — one read of the operands, one write of the outputs, per leaf.
 
 Tensors are flattened and tiled (rows, 1024) with (block_rows, 1024) VMEM
 blocks — lane-dim 1024 = 8 x 128 keeps the VPU fully fed.  The traced
-scalars (lr, clip scale, LARS trust ratio, staleness damping) arrive as a
-single (4,) f32 vector in SMEM; all other constants (beta, weight decay,
-nesterov, the op itself) are baked into the kernel.
+scalars (lr, clip scale, LARS trust ratio, staleness damping, and under the
+finite guard its flag) arrive as a single (4,) or (5,) f32 vector in SMEM;
+all other constants (beta, weight decay, nesterov, the guard, the op
+itself) are baked into the kernel.
 
 The kernel body calls the *same* ``pre_math``/``post_math`` the pure-JAX
 reference path uses, so parity with the stacked oracle holds by
@@ -38,6 +39,8 @@ def _stage_body(
     rows, ins = refs[:nrow], refs[nrow: nrow + len(names_in)]
     outs = refs[nrow + len(names_in):]
     s = {"lr": s_ref[0], "gs": s_ref[1], "r": s_ref[2], "sg": s_ref[3]}
+    if ctx.guard:
+        s["ok"] = s_ref[4]
     # row-indexed segment scalars (plane layout): a (block_rows, 1) column
     # overrides the SMEM scalar and broadcasts across the lanes, giving each
     # leaf's rows their own value inside the single whole-plane launch
@@ -55,6 +58,7 @@ def fused_stage_kernel(
     op: str,
     ctx: MathCtx,
     scalars: jax.Array,  # (4,) f32 in SMEM: lr, clip scale, LARS ratio, sg
+    # (+ the guard's ok, 1.0 or 0.0, when ctx.guard)
     inputs: dict[str, jax.Array],  # each (rows, LANES)
     out_dtypes: dict[str, jnp.dtype],
     *,
@@ -101,6 +105,15 @@ def fused_stage_kernel(
         jax.ShapeDtypeStruct(first.shape, dt, vma=vma) for dt in out_dtypes.values()
     ]
 
+    # under the guard a state output is the step's final state, restored
+    # from its own input (update_spec._keep): write it in place, so the
+    # donated state buffer takes it with no copy
+    aliases = {}
+    if ctx.guard:
+        for j, n in enumerate(names_out):
+            if n not in ("x", "payload") and n in names_in:
+                aliases[1 + len(names_row) + names_in.index(n)] = j
+
     kern = functools.partial(
         _stage_body, kind=kind, op=op, ctx=ctx, names_in=names_in,
         names_out=names_out, names_row=names_row,
@@ -113,6 +126,7 @@ def fused_stage_kernel(
         + [bs] * len(names_in),
         out_specs=[bs] * len(names_out),
         out_shape=out_shape,
+        input_output_aliases=aliases,
         interpret=interpret,
         # a stable name for the kernel in traces and in the compiled HLO
         name=f"fused_update_{kind}_{op}",

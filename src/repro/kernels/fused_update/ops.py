@@ -109,9 +109,10 @@ def fused_stage(kind, op, ctx, operands, scalars, like_x, *, interpret=False):
                 "(per-node, as inside shard_map); stacked-layout "
                 "staleness-aware runs use the reference stage"
             )
-        svec = jnp.stack(
-            [jnp.asarray(s["lr"]), jnp.asarray(s["gs"]), jnp.asarray(s["r"]), sg]
-        ).astype(jnp.float32)
+        svec = [jnp.asarray(s["lr"]), jnp.asarray(s["gs"]), jnp.asarray(s["r"]), sg]
+        if ctx.guard:
+            svec.append(jnp.asarray(s["ok"]))
+        svec = jnp.stack(svec).astype(jnp.float32)
         res = _leaf_call(
             kind, op, ctx, leaf_ins, svec, out_dtypes, interpret=interpret
         )
@@ -144,7 +145,7 @@ def fused_plane_stage(kind, op, ctx, operands, scalars, like_x, *, interpret=Fal
     rank*, matching the tp == 1 collapse.  The LARS trust ratio, when
     present, arrives as the layout's row-indexed segment columns
     (``{bucket: (rows, 1)}``) and is fed to the kernel as a narrow VMEM
-    operand; ``gs``/``sg`` stay SMEM scalars.
+    operand; ``gs``/``sg`` (and the guard's ``ok``) stay SMEM scalars.
     """
     names = tuple(operands)
     treedef = jax.tree.structure(operands[names[0]])
@@ -166,9 +167,10 @@ def fused_plane_stage(kind, op, ctx, operands, scalars, like_x, *, interpret=Fal
         r_cols = treedef.flatten_up_to(r)
     r_scalar = jnp.asarray(1.0 if r_cols is not None or r is None else r)
 
-    svec = jnp.stack(
-        [jnp.asarray(scalars["lr"]), gs, r_scalar, sg]
-    ).astype(jnp.float32)
+    svec = [jnp.asarray(scalars["lr"]), gs, r_scalar, sg]
+    if ctx.guard:
+        svec.append(jnp.asarray(scalars["ok"]))
+    svec = jnp.stack(svec).astype(jnp.float32)
 
     out_cols: dict[str, list] = {n: [] for n in names_out}
     for i in range(treedef.num_leaves):

@@ -31,7 +31,7 @@ from ..core.optimizers import OptimizerConfig, make_optimizer
 from ..core.planes import plane_scalars
 from ..core.schedules import ScheduleConfig, build_schedule
 from ..core.topology import build_topology
-from ..core.update_spec import run_update, update_spec
+from ..core.update_spec import run_update, update_spec, zero_unless
 from ..kernels.fused_update import make_plane_stage, make_stage
 from ..models import transformer as T
 from ..models.layers import TPContext
@@ -367,16 +367,17 @@ def build_train_step(
     ):
         """Everything after the gradient: finite guard, planes, update
         kernel and gossip, metric reductions."""
-        # finite guard: when the local grad norm goes non-finite, zero the
-        # grads BEFORE the update path (the gossip payload this round stays
-        # finite, so neighbors keep mixing clean iterates) and restore the
-        # optimizer state after it (momentum/EF frozen — a poisoned step
-        # must not leak into the accumulators).  Params still take the
-        # g=0 update, i.e. the node keeps gossiping.  The decision is
-        # per-node; the psum over the model axis (at any tp, free at
-        # tp == 1) makes every model shard agree, so the replicated params
-        # cannot desync and the guard is provably invariant over the model
-        # axis, as the replicated out_specs require.
+        # finite guard: when the local grad norm goes non-finite, the update
+        # runs with zero grads (the gossip payload this round stays finite,
+        # so neighbors keep mixing clean iterates) and the optimizer state
+        # comes back unchanged (momentum/EF frozen — a poisoned step must
+        # not leak into the accumulators).  Params still take the g=0
+        # update, i.e. the node keeps gossiping.  run_update applies it
+        # (its ``ok``), folded into the update stages where it can.  The
+        # decision is per-node; the psum over the model axis (at any tp,
+        # free at tp == 1) makes every model shard agree, so the replicated
+        # params cannot desync and the guard is provably invariant over the
+        # model axis, as the replicated out_specs require.
         finite = None
         if tcfg.finite_guard:
             gsq = jnp.float32(0.0)
@@ -384,9 +385,6 @@ def build_train_step(
                 gsq = gsq + jnp.sum(jnp.square(gg.astype(jnp.float32)))
             gsq = jax.lax.psum(gsq, model_axis)
             finite = jnp.isfinite(gsq)
-            grads = jax.tree.map(
-                lambda gg: jnp.where(finite, gg, jnp.zeros_like(gg)), grads
-            )
 
         # row-info hit stacks are mask material, not scalar metrics: keep
         # them out of the pmean loop below and feed them to the tracker
@@ -401,9 +399,11 @@ def build_train_step(
             # O(buckets x edge-classes) collectives), unpack the new
             # params for the next forward.  Optimizer + channel state stay
             # in plane form across steps; the clip/LARS scalars come from
-            # the original trees so they match the per-leaf path bit-for-bit.
+            # the original trees (zeroed under the guard, inside the norm
+            # reductions) so they match the per-leaf path bit-for-bit.
             ocfg = tcfg.opt_config()
             g32 = jax.tree.map(lambda gg: gg.astype(jnp.float32), grads)
+            scalars = plane_scalars(ocfg, layout, params, zero_unless(finite, g32))
             new_x_pl, new_opt, comp_state = run_update(
                 update_spec(ocfg),
                 ocfg,
@@ -418,7 +418,8 @@ def build_train_step(
                 stage=make_plane_stage(
                     tcfg.fused_impl if tcfg.fused_update else "ref"
                 ),
-                scalars=plane_scalars(ocfg, layout, params, g32),
+                scalars=scalars,
+                ok=finite,
             )
             new_params = layout.unpack(new_x_pl, like=params)
         elif tcfg.fused_update:
@@ -438,6 +439,7 @@ def build_train_step(
                 mean=mean,
                 comp_state=comp_state,
                 stage=make_stage(tcfg.fused_impl),
+                ok=finite,
             )
         else:
             new_params, new_opt, comp_state = opt.step(
@@ -449,11 +451,7 @@ def build_train_step(
                 gossip=gossip,
                 mean=mean,
                 comp_state=comp_state,
-            )
-
-        if finite is not None:
-            new_opt = jax.tree.map(
-                lambda nw, old: jnp.where(finite, nw, old), new_opt, opt_state
+                ok=finite,
             )
 
         # replicated scalar metrics

@@ -18,6 +18,8 @@ tests/test_distributed.py::test_flat_planes_shard_map_parity_and_collective_coun
   converts optimizer state across the ``flat_planes`` flag.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -270,6 +272,85 @@ def test_plane_parity_pallas_interpret(algo, extras):
         assert _tree_equal(s1[sk], lay.unpack(s2p[sk], dtype=jnp.float32)), sk
 
 
+def _poison(g):
+    """``g`` with one NaN and one inf (in different leaves)."""
+    g = dict(g)
+    g["w1"] = g["w1"].at[3, 2].set(jnp.nan)
+    g["ln"] = g["ln"].at[5].set(jnp.inf)
+    return g
+
+
+@pytest.mark.parametrize("algo", ALGORITHMS)
+@pytest.mark.parametrize("extras", CONFIGS, ids=("plain", "lars-clip-wd"))
+@pytest.mark.parametrize("finite", (True, False), ids=("finite", "nan-inf"))
+@pytest.mark.parametrize(
+    "impl", ("ref", "pallas_interpret", "leaf_pallas_interpret")
+)
+def test_plane_guard_folded_matches_where_guard(algo, extras, finite, impl):
+    """The finite guard folded into the stages (``run_update(ok=...)``)
+    equals, bit for bit, the guard done around an unguarded update: zero
+    ``g`` by ``where``, run the update, restore the state by ``where``.  The
+    params keep their ``g = 0`` update, and every payload handed to gossip
+    is finite.  ``ref`` and ``pallas_interpret`` run the plane stages on
+    packed planes, ``leaf_pallas_interpret`` the per-leaf Pallas stages on
+    the unpacked trees (padded per-leaf tiles)."""
+    tmpl = _tmpl()
+    lay = PlaneLayout.build(tmpl)
+    cfg = OptimizerConfig(algorithm=algo, momentum=0.9, **extras)
+    spec = update_spec(cfg)
+    x = _rand_like(tmpl)
+    g = _rand_like(tmpl, jnp.float32)
+    if not finite:
+        g = _poison(g)
+    ok = jnp.asarray(finite)
+    g0 = jax.tree.map(lambda a: jnp.where(ok, a, jnp.zeros_like(a)), g)
+    leaf = impl.startswith("leaf_")
+
+    def pack(tree, dtype=None):
+        return tree if leaf else lay.pack(tree, dtype=dtype)
+
+    # a state that is not all zeros, so a restore that picked the wrong
+    # value shows
+    state = jax.tree.map(
+        lambda a: a + jnp.float32(0.25), make_optimizer(cfg).init(x)
+    )
+    state_pl = {k: pack(v, dtype=jnp.float32) for k, v in state.items()}
+    payloads = []  # what gossip (or pmsgd's mean) is handed
+
+    def gossip(tree, step, comp):
+        payloads.append(tree)
+        return jax.tree.map(lambda a: 0.7 * a, tree), comp
+
+    def mean(tree):
+        if spec.phases[0].comm == "mean":  # not SlowMo's sync, under a cond
+            payloads.append(tree)
+        return tree
+
+    ng = jnp.int32(2) if spec.staleness_aware else None
+    if leaf:  # run_update derives the scalars from the (zeroed) trees
+        stage, scalars = make_stage(impl.removeprefix("leaf_")), None
+    else:
+        stage, scalars = make_plane_stage(impl), plane_scalars(cfg, lay, x, g0)
+    kw = dict(lr=0.01, step_idx=jnp.int32(3), gossip=gossip, mean=mean,
+              comp_state=(), node_gaps=ng, stage=stage, scalars=scalars)
+    xw, sw, _ = run_update(spec, cfg, x=pack(x), g=pack(g0, dtype=jnp.float32),
+                           state=state_pl, **kw)
+    sw = {k: jax.tree.map(lambda a, b: jnp.where(ok, a, b), v, state_pl[k])
+          for k, v in sw.items()}
+    payloads.clear()
+    xf, sf, _ = run_update(spec, cfg, x=pack(x), g=pack(g, dtype=jnp.float32),
+                           state=state_pl, ok=ok, **kw)
+    assert _tree_equal(xf, xw)
+    assert set(sf) == set(sw)
+    for sk in sf:
+        assert _tree_equal(sf[sk], sw[sk]), sk
+        if not finite:
+            assert _tree_equal(sf[sk], state_pl[sk]), sk
+    assert payloads
+    for p in payloads:
+        assert all(bool(jnp.isfinite(a).all()) for a in jax.tree.leaves(p))
+
+
 @pytest.mark.parametrize("algo", ALGORITHMS)
 def test_plane_launch_count_is_O_stages(algo):
     """jaxpr-counted pallas_calls: per-leaf = leaves x stages, plane =
@@ -308,6 +389,142 @@ def test_plane_launch_count_is_O_stages(algo):
     assert count_primitive(
         jax.make_jaxpr(plane_fn)(tmpl, g, state), "pallas_call"
     ) == n_buckets * stages
+
+
+# ---------------------------------------------------------------------------
+# the finite guard on the real train step (flat planes, Pallas stages)
+# ---------------------------------------------------------------------------
+
+POISON_TOKEN, ZERO_TOKEN = 1, 2  # batches made of one token id throughout
+
+
+@pytest.fixture(scope="module")
+def guard_step():
+    """A tiny model's DecentLaM train step on flat planes, guard on, on one
+    node: ``step`` with the plane reference stage, ``step_pallas`` with the
+    Pallas stages in interpret mode.  Both run the same stage math, the
+    guard included; only ``step`` executes here, because the Pallas
+    interpreter cannot run a kernel body inside the step's varying-axis
+    checked ``shard_map`` (it binds the body's primitives without promoting
+    constants to the varying axes).  The loss is scaled by inf on a batch
+    made only of ``POISON_TOKEN`` (non-finite grads) and by 0 on one made
+    only of ``ZERO_TOKEN`` (the ``g = 0`` update with the guard not
+    engaged); other batches train as usual."""
+    from repro.configs import tiny_lm
+    from repro.core.schedules import ScheduleConfig
+    from repro.launch.mesh import make_mesh
+    from repro.models import transformer as T
+    from repro.train.step import TrainConfig, build_train_step
+    from repro.train.train_state import init_train_state, model_plane_layout
+
+    cfg = tiny_lm(n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, d_ff=128,
+                  vocab_size=256)
+    tcfg = TrainConfig(
+        algorithm="decentlam", topology="ring", momentum=0.9,
+        flat_planes=True, fused_impl="pallas_interpret",
+        schedule=ScheduleConfig(kind="constant", peak_lr=1e-2),
+        runtime=T.RuntimeConfig(dtype="float32", remat=False),
+    )
+    forward_loss = T.forward_loss
+
+    def scaled_loss(params, batch, *a, **kw):
+        loss, metrics = forward_loss(params, batch, *a, **kw)
+        tok = batch["tokens"]
+        scale = jnp.where(jnp.all(tok == POISON_TOKEN), jnp.inf,
+                          jnp.where(jnp.all(tok == ZERO_TOKEN), 0.0, 1.0))
+        return loss * scale, metrics
+
+    mesh = make_mesh((1, 1), ("data", "model"))
+    layout = model_plane_layout(cfg, 1)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(T, "forward_loss", scaled_loss)
+        step, _, _, channel = build_train_step(cfg, tcfg, mesh)
+        step_pallas = build_train_step(
+            cfg, dataclasses.replace(tcfg, fused_update=True), mesh
+        )[0]
+        state = init_train_state(
+            jax.random.key(0), cfg, make_optimizer(tcfg.opt_config()), 1, 1,
+            mesh=mesh, node_axes=("data",), channel=channel,
+            plane_layout=layout,
+        )
+
+        def batch(tokens):
+            return {"tokens": jnp.asarray(tokens, jnp.int32),
+                    "targets": jnp.asarray(np.roll(tokens, -1, 1), jnp.int32)}
+
+        rng = np.random.default_rng(5)
+        yield dict(
+            step=step, step_pallas=step_pallas, state=state, layout=layout,
+            tcfg=tcfg,
+            batch=lambda: batch(rng.integers(3, 256, (2, 16))),
+            poison=batch(np.full((2, 16), POISON_TOKEN)),
+            zero=batch(np.full((2, 16), ZERO_TOKEN)),
+        )
+
+
+def _outside_pallas(jaxpr):
+    """Every eqn of a jaxpr and the jaxprs nested in it, except the bodies
+    of ``pallas_call``s."""
+    from repro.launch.costmodel import _sub_jaxprs
+
+    for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub, _ in _sub_jaxprs(eqn):
+                yield from _outside_pallas(sub)
+
+
+def test_guarded_plane_step_has_no_plane_wide_select(guard_step):
+    """With the guard on, the train step's jaxpr holds no ``select_n`` over
+    a plane bucket outside the kernels (the guard lives in the stages), and
+    it still launches one kernel per bucket per stage."""
+    gs = guard_step
+    jaxpr = jax.make_jaxpr(gs["step_pallas"])(gs["state"], gs["batch"]())
+    plane_shapes = {(rows, LANES) for rows in gs["layout"].rows.values()}
+    selects = [
+        e for e in _outside_pallas(jaxpr) if e.primitive.name == "select_n"
+        and tuple(e.outvars[0].aval.shape[-2:]) in plane_shapes
+    ]
+    assert not selects, [str(e) for e in selects]
+    stages = len(stage_plan(gs["tcfg"].opt_config()))
+    assert count_primitive(jaxpr, "pallas_call") == (
+        len(gs["layout"].rows) * stages
+    )
+
+
+def _bits(tree):
+    return [np.asarray(a).view(np.uint8).tobytes() for a in jax.tree.leaves(tree)]
+
+
+def test_train_step_with_nonfinite_grads_skips_the_update(guard_step):
+    """One step whose grads are non-finite: the guard counts it, leaves
+    the momentum plane as it was bit for bit, and gives the params the
+    ``g = 0`` update; the next finite step trains as usual."""
+    gs = guard_step
+    step = gs["step"]
+
+    def copy(tree):
+        return jax.tree.map(jnp.copy, tree)
+
+    state, metrics = step(copy(gs["state"]), gs["batch"]())  # momentum != 0
+    assert float(metrics["skipped_nonfinite"]) == 0.0
+    m_before = copy(state["opt"]["m"])
+    assert any(bool(jnp.any(a != 0)) for a in jax.tree.leaves(m_before))
+
+    zero_state, zm = step(copy(state), gs["zero"])
+    assert float(zm["skipped_nonfinite"]) == 0.0
+    bad_state, bm = step(copy(state), gs["poison"])
+    assert float(bm["skipped_nonfinite"]) == 1.0
+    assert _bits(bad_state["opt"]["m"]) == _bits(m_before)
+    assert _tree_equal(bad_state["params"], zero_state["params"])
+    assert not _tree_equal(bad_state["params"], state["params"])
+
+    next_state, nm = step(bad_state, gs["batch"]())
+    assert float(nm["skipped_nonfinite"]) == 0.0
+    assert np.isfinite(float(nm["loss"]))
+    assert _bits(next_state["opt"]["m"]) != _bits(m_before)
+    for leaf in jax.tree.leaves((next_state["params"], next_state["opt"])):
+        assert bool(jnp.isfinite(leaf).all())
 
 
 # ---------------------------------------------------------------------------

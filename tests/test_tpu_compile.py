@@ -88,23 +88,27 @@ def _custom_calls(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
+@pytest.mark.parametrize("guard", (False, True), ids=("plain", "guard"))
 @pytest.mark.parametrize(
     "kind,op,names",
     [("pre", "grad_step", ("x", "g")), ("post", "decentlam_post", ("x", "mix", "m"))],
 )
-def test_fused_plane_stage_compiles_at_qwen3_rows(one_chip, kind, op, names):
-    """DecentLaM's two plane stages over qwen3-0.6b's whole f32 bucket."""
+def test_fused_plane_stage_compiles_at_qwen3_rows(one_chip, kind, op, names, guard):
+    """DecentLaM's two plane stages over qwen3-0.6b's whole f32 bucket,
+    with and without the finite guard folded in."""
     layout = model_plane_layout(QWEN3)
     planes = {
         key: jax.ShapeDtypeStruct((rows, LANES), jnp.float32, sharding=one_chip)
         for key, rows in layout.rows.items()
     }
     stage = make_plane_stage("pallas")
-    ctx = MathCtx(beta=0.9)
+    ctx = MathCtx(beta=0.9, guard=guard)
+    scalars = {"lr": jnp.float32(1e-3)}
+    if guard:
+        scalars["ok"] = jnp.float32(1.0)
 
     def run(operands):
-        return stage(kind, op, ctx, operands, {"lr": jnp.float32(1e-3)},
-                     operands["x"])
+        return stage(kind, op, ctx, operands, scalars, operands["x"])
 
     compiled = jax.jit(run).lower({n: planes for n in names}).compile()
     assert _custom_calls(compiled) == len(layout.rows)
@@ -219,6 +223,18 @@ def test_one_node_train_step_fits_one_chip(one_node_step):
              + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     assert total <= HBM_BYTES, f"{total / 2**30:.2f} GiB > 16 GiB"
     assert _custom_calls(one_node_step) > 0
+
+
+def test_one_node_train_step_has_no_plane_wide_select(one_node_step):
+    """The finite guard lives in the update kernels: outside them the
+    compiled step holds no ``select`` over an f32 plane bucket (the guard's
+    restore of the momentum plane was one, a whole plane pass a step)."""
+    import re
+
+    rows = model_plane_layout(QWEN3).rows.values()
+    shapes = "|".join(rf"(?:1,)?{r},{LANES}" for r in rows)
+    pattern = re.compile(rf"= f32\[(?:{shapes})\]\S* select\(")
+    assert not pattern.findall(one_node_step.as_text())
 
 
 def test_benchmark_finds_both_update_stages(one_node_step, monkeypatch):
