@@ -4,18 +4,13 @@ Fig. 6 measures wall-clock with 10/25 Gbps Ethernet between 8-GPU servers;
 here the hardware is a TPU pod, so we report the *analytic* per-node egress
 bytes + latency hops of each algorithm's communication pattern (reported
 from ``GossipChannel.bytes_per_step`` and cross-checked against the legacy
-``core.gossip.gossip_bytes_per_step`` model) and, where a dry-run artifact
-exists, the *measured* collective bytes parsed from the compiled HLO.
+``core.gossip.gossip_bytes_per_step`` model).
 
 Model sizes: ResNet-50 (25.5M, the paper's) + the assigned qwen3-0.6b /
 qwen3-8b.  Emits CSV rows: name, payload_mb, egress_mb, hops, est_ms_at_25gbps.
 """
 
 from __future__ import annotations
-
-import glob
-import json
-import os
 
 from repro.core import PpermuteChannel, build_topology
 
@@ -88,21 +83,6 @@ def run(csv: bool = True):
                 f"{egress/BW*1e3:.1f}"
             )
 
-    # measured collective bytes from dry-run artifacts, if present
-    pat = os.path.join("experiments", "dryrun", "*", "pod1", "*__train_4k.json")
-    arts = sorted(glob.glob(pat))
-    if arts and csv:
-        print("name,measured_collective_egress_mb,dominant")
-        for a in arts[:20]:
-            r = json.load(open(a))
-            if r.get("status") != "ok":
-                continue
-            tag = a.split(os.sep)[-3]
-            print(
-                f"comm-measured/{tag}/{r['arch']},"
-                f"{r['collectives']['egress_bytes']/2**20:.1f},"
-                f"{r['roofline']['dominant']}"
-            )
     return rows
 
 

@@ -52,7 +52,7 @@ from repro.core.update_spec import (
     update_spec,
 )
 from repro.kernels.flash_attention.ref import reference_attention  # noqa: F401 — table reference
-from repro.kernels.fused_update import make_plane_stage, make_stage
+from repro.kernels.fused_update import make_plane_stage
 from repro.kernels.mlstm_chunk.ops import mlstm
 from repro.launch.costmodel import count_primitive
 from repro.models.attention import attention_core
@@ -203,36 +203,21 @@ def bench_optimizer_tails(n=N_TAIL, iters=5):
 # ---------------------------------------------------------------------------
 #
 # The 4M-element blob above measures per-pass bandwidth; real models are
-# *trees* — many leaves, most small — and the per-leaf engine pays one
-# kernel launch per leaf per stage and one collective per leaf per edge
-# class.  This workload is a realistic transformer pytree (mixed bf16
-# matmul weights + f32 norm scales, per-layer q/k norms), measuring:
+# *trees* — many leaves, most small.  The plane update packs them into
+# dtype buckets, so it pays one kernel launch per bucket per stage and one
+# collective per bucket per edge class, whatever the number of leaves.
+# This workload is a realistic transformer pytree (mixed bf16 matmul
+# weights + f32 norm scales, per-layer q/k norms), counting:
 #
-# * launches/step     — pallas_call count in the traced jaxpr, per path
-#                       (per-leaf: leaves x stages; plane: buckets x stages)
-# * collectives/step  — the ppermute-path analytic count per path
-#                       (cross-checked against jaxpr-counted ppermutes in
+# * launches/step     — pallas_call count in the traced jaxpr
+#                       (buckets x stages)
+# * collectives/step  — the ppermute-path analytic count (cross-checked
+#                       against jaxpr-counted ppermutes in
 #                       tests/scripts/distributed_equivalence.py)
-# * end-to-end time   — the per-leaf path executes one *dispatched* stage
-#                       per (leaf, stage), mirroring its launch pattern on
-#                       the accelerator (one ``pallas_call`` per leaf per
-#                       stage; cf. the "unfused = one dispatch per op"
-#                       convention of ``bench_optimizer_tails`` above);
-#                       the plane path is one jitted program including its
-#                       pack/unpack cost.  A whole-tree jit of the
-#                       per-leaf path would let XLA's *CPU* backend fuse
-#                       across leaves — precisely what a per-leaf kernel
-#                       launch cannot do — so that number is recorded for
-#                       context as ``per_leaf_fused_us`` but not compared.
-#                       Communication is excluded from the timing (as in
-#                       the tail bench); the collective savings are
-#                       accounted above and measured on a real mesh in
-#                       the distributed tier.
 
 TREE_N_NODES = 4
 TREE_LAYERS = 48
 TREE_D = 32
-TREE_TIMED_ALGOS = ("decentlam", "dmsgd", "decentlam-sa")
 
 
 def _tree_template(n_layers=TREE_LAYERS, d=TREE_D, vocab=512):
@@ -258,7 +243,7 @@ def _tree_template(n_layers=TREE_LAYERS, d=TREE_D, vocab=512):
 
 
 def _tree_counts(cfg, template) -> dict[str, int]:
-    """Static launch counts of one update tail, per path, from the jaxpr.
+    """Static launch counts of one plane update tail, from the jaxpr.
 
     Uses the per-node layout with an identity-closure transport so the
     trace carries only the engine's own launches; ``pallas_call``
@@ -280,10 +265,6 @@ def _tree_counts(cfg, template) -> dict[str, int]:
     kw = dict(lr=0.01, step_idx=jnp.int32(0), gossip=gossip, mean=mean,
               comp_state=())
 
-    def leaf_fn(x, g, state):
-        return run_update(spec, cfg, x=x, g=g, state=state,
-                          stage=make_stage("pallas_interpret"), **kw)
-
     def plane_fn(x, g, state):
         xp = layout.pack(x)
         gp = layout.pack(g, dtype=jnp.float32)
@@ -293,9 +274,6 @@ def _tree_counts(cfg, template) -> dict[str, int]:
                           scalars=plane_scalars(cfg, layout, x, g), **kw)
 
     return {
-        "launches_per_leaf": count_primitive(
-            jax.make_jaxpr(leaf_fn)(x, g, state), "pallas_call"
-        ),
         "launches_plane": count_primitive(
             jax.make_jaxpr(plane_fn)(x, g, state), "pallas_call"
         ),
@@ -305,124 +283,26 @@ def _tree_counts(cfg, template) -> dict[str, int]:
     }
 
 
-def _identity_gossip(tree, step, comp):
-    """Comm-excluded transport for the timing runs (per-node layout)."""
-    return tree, comp
-
-
-def _time_per_leaf_dispatched(cfg, template, x, g, iters):
-    """The per-leaf path's launch pattern: one dispatched stage execution
-    per (leaf, stage), operands drawn from preallocated slots.
-
-    Each dispatch is a jitted single-leaf ``reference_stage`` call — the
-    CPU analog of the one ``pallas_call`` per leaf per stage the per-leaf
-    engine issues on the accelerator.  Dispatches are pipelined (only the
-    final result is blocked on), so this measures launch overhead the way
-    an accelerator queue would pay it.
-    """
-    leaves_x = jax.tree.leaves(x)
-    leaves_g = jax.tree.leaves(g)
-    env = {
-        "x": leaves_x,
-        "g": leaves_g,
-        "m": [jnp.zeros(a.shape, jnp.float32) for a in leaves_x],
-        "mix": leaves_g,  # stands in for the gossip result (comm excluded)
-        "x_prev": leaves_x,
-        "m_prev": [jnp.zeros(a.shape, jnp.float32) for a in leaves_x],
-    }
-    lr = jnp.float32(LR)
-    plan = stage_plan(cfg)
-    fns = []
-    for kind, op, ctx in plan:
-        ins, _ = pre_io(op, ctx) if kind == "pre" else post_io(op)
-
-        def stage_fn(ops, lr, _kind=kind, _op=op, _ctx=ctx):
-            s = {"lr": lr, "gs": None, "r": None}
-            return reference_stage(_kind, _op, _ctx, ops, s, ops[next(iter(ops))])
-
-        fns.append((jax.jit(stage_fn), ins))
-
-    def run_once():
-        out = None
-        for fn, ins in fns:
-            for i in range(len(leaves_x)):
-                out = fn({n: env[n if n != "payload" else "g"][i] for n in ins}, lr)
-        jax.block_until_ready(out)
-
-    run_once()  # compile every (stage, leaf-shape) pair
-    best = float("inf")
-    for _ in range(3):
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            run_once()
-        best = min(best, (time.perf_counter() - t0) / iters * 1e6)
-    return best
-
-
-def bench_tree_workload(iters=3):
+def bench_tree_workload():
     template = _tree_template()
     topo = build_topology("ring", TREE_N_NODES)
     # collectives accounting uses the distributed wire path's analytic count
     wire = PpermuteChannel(topo, "data")
     layout = PlaneLayout.build(template)
-    rng = np.random.default_rng(4)
-
-    x = template
-    g = jax.tree.map(
-        lambda a: jnp.asarray(rng.standard_normal(a.shape), jnp.float32), x
-    )
     plane_payload = layout.plane_shapes(jnp.float32)
 
     table: dict[str, dict] = {}
     for algo in ALGORITHMS:
         cfg = OptimizerConfig(algorithm=algo, momentum=BETA, weight_decay=WD)
         spec = update_spec(cfg)
-        opt = make_optimizer(cfg)
         entry = dict(_tree_counts(cfg, template))
         entry["gossips_per_step"] = spec.gossips_per_step
-        entry["collectives_per_leaf"] = (
-            wire.collectives_per_round(template) * spec.gossips_per_step
-        )
         entry["collectives_plane"] = (
             wire.collectives_per_round(plane_payload) * spec.gossips_per_step
         )
-
-        if algo in TREE_TIMED_ALGOS:
-            state = opt.init(x)
-            state_pl = {
-                k: layout.pack(v, dtype=jnp.float32) for k, v in state.items()
-            }
-            kw = dict(lr=jnp.float32(LR), step_idx=jnp.int32(0),
-                      gossip=_identity_gossip, mean=lambda t: t, comp_state=())
-
-            @jax.jit
-            def leaf_step(x, g, state, _spec=spec, _cfg=cfg, _kw=kw):
-                return run_update(_spec, _cfg, x=x, g=g, state=state, **_kw)[:2]
-
-            @jax.jit
-            def plane_step(x, g, state_pl, _spec=spec, _cfg=cfg, _kw=kw):
-                xp = layout.pack(x)
-                gp = layout.pack(g, dtype=jnp.float32)
-                x2, s2, _ = run_update(
-                    _spec, _cfg, x=xp, g=gp, state=state_pl,
-                    scalars=plane_scalars(_cfg, layout, x, g), **_kw,
-                )
-                return layout.unpack(x2, like=x), s2
-
-            t_leaf = _time_per_leaf_dispatched(cfg, template, x, g, iters)
-            t_plane = _time(plane_step, x, g, state_pl, iters=iters)
-            t_leaf_fused = _time(leaf_step, x, g, state, iters=iters)
-            entry["per_leaf_us"] = round(t_leaf, 1)
-            entry["plane_us"] = round(t_plane, 1)
-            entry["plane_speedup"] = round(t_leaf / t_plane, 3)
-            entry["per_leaf_fused_us"] = round(t_leaf_fused, 1)
         table[algo] = entry
 
     n_params = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(template))
-    timed = [table[a] for a in TREE_TIMED_ALGOS]
-    agg = round(
-        sum(r["per_leaf_us"] for r in timed) / sum(r["plane_us"] for r in timed), 3
-    )
     return {
         "n_nodes": TREE_N_NODES,
         "topology": "ring",
@@ -430,12 +310,6 @@ def bench_tree_workload(iters=3):
         "n_params": n_params,
         "n_leaves": len(jax.tree.leaves(template)),
         "n_buckets": len(layout.segments),
-        "timed_algorithms": list(TREE_TIMED_ALGOS),
-        # single-number wall-clock summary: per-algorithm CPU timings are
-        # noisy (the true plane win on the accelerator is the launch-count
-        # collapse); the aggregate over the timed tails is what the CI
-        # backstop checks
-        "plane_speedup_aggregate": agg,
         "per_algorithm": table,
     }
 
@@ -577,16 +451,11 @@ def run(csv: bool = True, json_path: str | None = None):
                 f"{row['speedup']:.2f},{row['unfused_array_passes']},"
                 f"{row['fused_array_passes']}"
             )
-        print(
-            "algo,launches_per_leaf,launches_plane,collectives_per_leaf,"
-            "collectives_plane,per_leaf_us,plane_us,plane_speedup"
-        )
+        print("algo,launches_plane,collectives_plane")
         for algo, row in tree["per_algorithm"].items():
             print(
-                f"tree/{algo},{row['launches_per_leaf']},{row['launches_plane']},"
-                f"{row['collectives_per_leaf']:.0f},{row['collectives_plane']:.0f},"
-                f"{row.get('per_leaf_us', '')},{row.get('plane_us', '')},"
-                f"{row.get('plane_speedup', '')}"
+                f"tree/{algo},{row['launches_plane']},"
+                f"{row['collectives_plane']:.0f}"
             )
         tp = tree["tp_sharded"]["tp"]
         print(

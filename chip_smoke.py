@@ -90,7 +90,7 @@ def step_argv(impl: str, *, topology: str = "exp",
     """The ``repro.launch.train`` command line of phases b, c and f."""
     return [
         "--arch", ARCH, "--algorithm", "decentlam", "--topology", topology,
-        "--flat-planes", "--fused-update", "--fused-impl", impl,
+        "--fused-impl", impl,
         "--steps", str(STEPS), "--seq-len", str(SEQ_LEN),
         "--per-node-batch", str(PER_NODE_BATCH),
         "--heterogeneity", str(heterogeneity),
@@ -277,7 +277,7 @@ def phase_guard(impl: str = "pallas") -> None:
     cfg = tiny_lm()
     tcfg = TrainConfig(
         algorithm="decentlam", topology="ring", momentum=0.9,
-        flat_planes=True, fused_update=True, fused_impl=impl,
+        fused_impl=impl,
         schedule=ScheduleConfig(kind="constant", peak_lr=1e-2),
     )
     forward_loss = T.forward_loss

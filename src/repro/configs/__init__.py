@@ -16,7 +16,7 @@ from . import (
     whisper_tiny,
     xlstm_350m,
 )
-from .base import SHAPES, ModelConfig, ShapeSpec, shape_applicable
+from .base import SHAPES, ModelConfig, ShapeSpec
 
 _MODULES = {
     "xlstm-350m": xlstm_350m,
@@ -67,6 +67,5 @@ __all__ = [
     "ModelConfig",
     "ShapeSpec",
     "get_config",
-    "shape_applicable",
     "tiny_lm",
 ]

@@ -115,8 +115,8 @@ class ModelConfig:
         attn = d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd + self.n_heads * hd * d
         if self.xlstm:
             # mLSTM block: up(d->pf d) + gate(d->pf d) + qkv in pf*d space
-            # + down(pf d->d).  Exact N is counted from init_params shapes at
-            # dry-run time; this estimate only seeds reporting defaults.
+            # + down(pf d->d).  Exact N is counted from init_params shapes;
+            # this estimate only seeds reporting defaults.
             pf = self.proj_factor
             per_layer = int(3 * d * pf * d + 3 * (pf * d) * hd * self.n_heads)
         elif self.moe:
@@ -159,10 +159,3 @@ SHAPES: dict[str, ShapeSpec] = {
     "decode_32k": ShapeSpec("decode_32k", "decode", 32768, 128),
     "long_500k": ShapeSpec("long_500k", "decode", 524288, 1),
 }
-
-
-def shape_applicable(cfg: ModelConfig, shape: ShapeSpec) -> tuple[bool, str]:
-    """(runnable?, reason).  long_500k only for sub-quadratic archs."""
-    if shape.name == "long_500k" and not cfg.long_context_ok:
-        return False, "pure full-attention arch: long_500k skipped (DESIGN.md §7)"
-    return True, ""

@@ -40,9 +40,9 @@ closures (``make_*_gossip``, ``init_compression_state``) were removed after
 their one-release grace period — construct a channel instead.
 
 **Flat plane payloads.**  Every channel is payload-structure generic, so
-the flat fast path (``TrainConfig(flat_planes=True)``) needs no separate
-transport: hand ``apply`` a :class:`~repro.core.planes.PlaneLayout` payload
-(one contiguous f32 buffer per dtype bucket) and each gossip round issues
+the train step's flat-plane update needs no separate transport: hand
+``apply`` a :class:`~repro.core.planes.PlaneLayout` payload (one
+contiguous f32 buffer per dtype bucket) and each gossip round issues
 one collective per **bucket** per edge class instead of one per pytree leaf
 — and ``init`` on a plane template moves the delay ring buffers and the
 compression error-feedback residuals into the same contiguous layout (one

@@ -1,9 +1,10 @@
 """Flat parameter planes: dtype-bucketed contiguous views of a pytree.
 
-The per-leaf hot path pays one kernel launch per pytree leaf per update
-stage and one collective per leaf per gossip edge class — for the model-zoo
-configs that is hundreds of dispatches per step, each with its own padding
-to the ``(rows, 1024)`` tile.  A :class:`PlaneLayout` collapses that: the
+An update run leaf by leaf would pay one kernel launch per pytree leaf per
+update stage and one collective per leaf per gossip edge class — for the
+model-zoo configs that is hundreds of dispatches per step, each with its
+own padding to the ``(rows, 1024)`` tile.  A :class:`PlaneLayout` collapses
+that: the
 whole tree is packed **once** into one contiguous ``(rows, LANES)`` buffer
 per dtype bucket, with static per-leaf segment metadata (row offsets,
 shapes, sizes) chosen so that
@@ -12,10 +13,8 @@ shapes, sizes) chosen so that
   leaf straddles a tile row, so a row belongs to exactly one leaf), and
 * every bucket's total row count is a multiple of 64 (the fused-update
   kernel's block height, itself a multiple of the f32/bf16 min-tile
-  sublane counts 8/16 — exact-grid blocks keep the plane kernel's
-  floating-point contraction identical to the per-leaf kernel's, which is
-  what makes plane-vs-per-leaf parity *bit*-exact rather than
-  ulp-close),
+  sublane counts 8/16 — exact-grid blocks, so no block of the plane
+  kernel is partial),
 
 so the fused-update engine runs **one** ``pallas_call`` per stage per
 bucket and the gossip channels ship **one** buffer per bucket per edge
@@ -27,14 +26,14 @@ Per-leaf quantities (the LARS trust ratio) are carried as *row-indexed
 segment scalars*: :meth:`PlaneLayout.row_scalars` scatters a tree of
 per-leaf scalars to a ``(rows, 1)`` column per bucket using the static
 row→segment map, which broadcasts through the same
-``pre_math``/``post_math`` expressions the per-leaf path uses (and rides
+``pre_math``/``post_math`` expressions the per-leaf oracle uses (and rides
 into the Pallas plane kernel as a narrow VMEM operand).
 
 :func:`plane_scalars` computes the gradient-preprocessing scalars on the
 **original trees** with the exact :func:`~repro.core.update_spec.grad_scalars`
 code, then converts only the per-leaf LARS tree to row form — so the
 clip/LARS scalars of the plane path are bit-identical to the per-leaf
-path's by construction (a segment-reduction over planes would change the
+oracle's by construction (a segment-reduction over planes would change the
 summation order).
 
 Layouts are static (built from shapes/dtypes only, ``jax.eval_shape``
@@ -535,7 +534,7 @@ class PlaneLayout:
 
         The static row→segment map scatters each leaf's scalar across its
         rows; broadcasting ``(rows, 1) * (rows, LANES)`` then applies it
-        elementwise exactly like the per-leaf path's scalar multiply.
+        elementwise exactly like the per-leaf oracle's scalar multiply.
         """
         vals = self.treedef.flatten_up_to(scalar_tree)
         out = {}
@@ -552,7 +551,7 @@ def plane_scalars(cfg, layout: PlaneLayout, x: Tree, g: Tree) -> dict:
 
     Runs the exact per-leaf :func:`~repro.core.update_spec.grad_scalars`
     on the *original* trees (so ``gs`` and the LARS ratios are
-    bit-identical to the per-leaf path), then converts the per-leaf LARS
+    bit-identical to the per-leaf oracle), then converts the per-leaf LARS
     tree to row-indexed columns that broadcast over the plane buffers.
     Feed the result to ``run_update(..., scalars=...)`` together with
     plane-packed operands.

@@ -612,7 +612,7 @@ def run_update(
     ``x`` may be any float dtype (the stages compute in f32 and cast the
     parameter output back); ``g`` and the state buckets are f32.  ``stage``
     selects the executor: :func:`reference_stage` or the Pallas engine's
-    (see ``repro.kernels.fused_update.make_stage``).
+    (see ``repro.kernels.fused_update.make_plane_stage``).
 
     ``node_gaps`` overrides the per-node gossip version gaps a
     staleness-aware spec folds into its stages (``(n,)`` stacked / scalar

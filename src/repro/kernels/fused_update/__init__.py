@@ -1,17 +1,3 @@
-from .ops import (
-    LANES,
-    decentlam_update,
-    fused_plane_stage,
-    fused_stage,
-    make_plane_stage,
-    make_stage,
-)
+from .ops import LANES, fused_plane_stage, make_plane_stage
 
-__all__ = [
-    "LANES",
-    "decentlam_update",
-    "fused_plane_stage",
-    "fused_stage",
-    "make_plane_stage",
-    "make_stage",
-]
+__all__ = ["LANES", "fused_plane_stage", "make_plane_stage"]

@@ -3,7 +3,7 @@
 One kernel family covers every elementwise stage of every algorithm's update
 tail (see ``repro.core.update_spec``): the stage op is a compile-time enum,
 so each (kind, op, MathCtx) pair lowers to its own fully-fused elementwise
-kernel — one read of the operands, one write of the outputs, per leaf.
+kernel — one read of the operands, one write of the outputs, per bucket.
 
 Tensors are flattened and tiled (rows, 1024) with (block_rows, 1024) VMEM
 blocks — lane-dim 1024 = 8 x 128 keeps the VPU fully fed.  The traced
@@ -81,8 +81,8 @@ def fused_stage_kernel(
     first = inputs[names_in[0]]
     rows = first.shape[0]
     # blocks need not divide the rows: Pallas masks the boundary block
-    # (plane buffers carry no tail padding; the per-leaf path still
-    # pre-pads each leaf so its grid is exact)
+    # (plane buffers carry no tail padding beyond their bucket's rows, so
+    # such a block reads and writes only real rows)
     grid = (-(-rows // block_rows),)
     bs = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
     bs_row = pl.BlockSpec((block_rows, ROW_COLS), lambda i: (i, 0))

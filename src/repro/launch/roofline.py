@@ -1,31 +1,15 @@
-"""Roofline analysis from compiled dry-run artifacts.
+"""Roofline terms of a step — hardware model is a TPU v5e-like chip:
 
-Three terms per (arch x shape x mesh), per DESIGN.md §8 — hardware model is
-a TPU v5e-like chip:
-
-    compute    = per-device HLO FLOPs / 197 TFLOP/s (bf16)
-    memory     = per-device HLO bytes accessed / 819 GB/s HBM
+    compute    = per-device FLOPs / 197 TFLOP/s (bf16)
+    memory     = per-device bytes accessed / 819 GB/s HBM
     collective = per-device link egress bytes / 50 GB/s ICI
-
-``cost_analysis()`` of the SPMD-partitioned module is already per-device.
-Collective bytes are not in cost_analysis, so we parse the optimized HLO:
-every all-gather / all-reduce / reduce-scatter / all-to-all /
-collective-permute gets a standard per-device egress cost (ring/bidirection
-models); ``-start`` ops are counted, ``-done`` skipped.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import re
-from typing import Iterable
 
-__all__ = [
-    "HW",
-    "CollectiveStats",
-    "parse_collective_bytes",
-    "roofline_terms",
-]
+__all__ = ["HW", "roofline_terms"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -33,76 +17,6 @@ class HW:
     peak_flops: float = 197e12  # bf16 FLOP/s per chip
     hbm_bw: float = 819e9  # bytes/s per chip
     link_bw: float = 50e9  # bytes/s per ICI link
-
-
-_DTYPE_BYTES = {
-    "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1, "f8e4m3": 1,
-    "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
-    "s32": 4, "u32": 4, "f32": 4,
-    "s64": 8, "u64": 8, "f64": 8, "c64": 8,
-    "c128": 16,
-}
-
-_SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
-_OP_RE = re.compile(
-    r"=\s*(\([^)]*\)|\w+\[[\d,]*\][^\s]*)\s+"
-    r"(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
-    r"(-start)?\("
-)
-_GROUPS_RE = re.compile(r"replica_groups=\{?\{([\d,]+)\}")
-_PAIRS_RE = re.compile(r"source_target_pairs=\{\{")
-
-
-def _bytes_of_type(type_str: str) -> int:
-    """Bytes of a (possibly tuple) HLO type string."""
-    total = 0
-    for m in _SHAPE_RE.finditer(type_str):
-        dt, dims = m.group(1), m.group(2)
-        if dt not in _DTYPE_BYTES:
-            continue
-        n = 1
-        if dims:
-            for d in dims.split(","):
-                n *= int(d)
-        total += n * _DTYPE_BYTES[dt]
-    return total
-
-
-@dataclasses.dataclass
-class CollectiveStats:
-    counts: dict
-    egress_bytes: float  # per-device bytes put on links
-
-    def as_dict(self):
-        return {"counts": dict(self.counts), "egress_bytes": self.egress_bytes}
-
-
-def parse_collective_bytes(hlo_text: str) -> CollectiveStats:
-    counts: dict[str, int] = {}
-    egress = 0.0
-    for line in hlo_text.splitlines():
-        m = _OP_RE.search(line)
-        if not m:
-            continue
-        type_str, op = m.group(1), m.group(2)
-        nbytes = _bytes_of_type(type_str)
-        gm = _GROUPS_RE.search(line)
-        gsize = len(gm.group(1).split(",")) if gm else 2
-        counts[op] = counts.get(op, 0) + 1
-        frac = (gsize - 1) / gsize if gsize > 1 else 1.0
-        if op == "all-reduce":
-            egress += 2.0 * frac * nbytes  # ring all-reduce
-        elif op == "all-gather":
-            # result bytes: each device receives all but its own shard,
-            # and forwards as much in a ring
-            egress += frac * nbytes
-        elif op == "reduce-scatter":
-            egress += frac * nbytes  # input-sized ring pass
-        elif op == "all-to-all":
-            egress += frac * nbytes
-        elif op == "collective-permute":
-            egress += nbytes  # each device sends its block once
-    return CollectiveStats(counts=counts, egress_bytes=egress)
 
 
 def roofline_terms(
@@ -129,8 +43,3 @@ def roofline_terms(
         "step_time_lower_bound_s": bound,
         "roofline_fraction": (bound / total) if total > 0 else 0.0,
     }
-
-
-def model_flops(n_active_params: int, tokens: int, *, training: bool) -> float:
-    """MODEL_FLOPS = 6 N D for training, 2 N D for inference forward."""
-    return (6.0 if training else 2.0) * n_active_params * tokens

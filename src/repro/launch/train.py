@@ -74,14 +74,10 @@ def parse_args(argv=None):
     p.add_argument("--per-node-batch", dest="per_node_batch", type=int, default=8)
     p.add_argument("--heterogeneity", type=float, default=0.2)
     p.add_argument("--grad-accum", dest="grad_accum", type=int, default=1)
-    p.add_argument("--fused-update", dest="fused_update", action="store_true")
-    p.add_argument("--flat-planes", dest="flat_planes", action="store_true",
-                   help="pack the update tail + gossip into dtype-bucketed "
-                   "plane buffers (one launch per stage, one collective per "
-                   "bucket per edge class); at --tp > 1 each mesh column "
-                   "packs only its local shard rows")
     p.add_argument("--fused-impl", dest="fused_impl", default="ref",
-                   choices=["ref", "pallas", "pallas_interpret"])
+                   choices=["ref", "pallas", "pallas_interpret"],
+                   help="stage executor of the flat-plane update: the jnp "
+                   "reference or the Pallas fused-update kernel")
     p.add_argument("--measure-json", dest="measure_json", default=None,
                    help="write {'measured_step_s': ...} after the run — the "
                    "calibration input of sim.wallclock.calibrate_from_dryrun")
@@ -283,9 +279,7 @@ def train_config(args):
             total_steps=max(args.steps, 2),
         ),
         runtime=RuntimeConfig(dtype=args.dtype, remat=False),
-        fused_update=args.fused_update,
         fused_impl=args.fused_impl,
-        flat_planes=args.flat_planes,
         track_consensus=args.track_consensus,
         finite_guard=args.finite_guard,
         chaos=_parse_chaos(args.chaos, args.chaos_seed) if args.chaos else None,
@@ -356,33 +350,24 @@ def run(argv=None) -> RunResult:
         return step_fn, opt, channel, bshard
 
     step_fn, opt, channel, bshard = build(mesh, n_nodes)
-    layout = model_plane_layout(cfg, tp) if args.flat_planes else None
+    layout = model_plane_layout(cfg, tp)
 
     if args.resume and args.ckpt_dir:
         host_state, manifest = restore_checkpoint(args.ckpt_dir)
         if jax.tree.leaves(host_state["params"])[0].shape[0] != n_nodes:
             print(f"elastic reshape {manifest.get('n_nodes')} -> {n_nodes}")
             host_state = elastic_reshape(host_state, n_nodes)
-        # checkpoints are interchangeable across --flat-planes AND across
-        # tensor-parallel degrees: a plane-form opt state written at a
-        # different tp (the manifest's "plane_tp") round-trips through the
-        # stored layout's global tree before repacking for this mesh.
-        # Manifests without "plane_tp" predate sharded layouts: any
-        # plane-form opt state they carry was written at tp == 1, so the
-        # stored layout defaults to the tp=1 one.  Tree-form opt states
-        # (the per-leaf production path) never consult it — reconcile only
-        # checks cross-tp layout compatibility when a plane actually needs
-        # converting.
-        cur_layout = layout or model_plane_layout(cfg, tp)
+        # checkpoints resume across tensor-parallel degrees: a plane-form
+        # opt state written at a different tp (the manifest's "plane_tp")
+        # round-trips through the stored layout's global tree before
+        # repacking for this mesh.  Manifests without "plane_tp" were
+        # written at tp == 1; tree-form opt states in them are packed.
         stored_tp = int(manifest.get("plane_tp") or 1)
         stored_layout = (
-            model_plane_layout(cfg, stored_tp) if stored_tp != tp else None
+            model_plane_layout(cfg, stored_tp) if stored_tp != tp else layout
         )
-        check_plane_manifest(manifest, stored_layout or cur_layout)
-        host_state = reconcile_plane_state(
-            host_state, cur_layout, args.flat_planes,
-            stored_layout=stored_layout,
-        )
+        check_plane_manifest(manifest, stored_layout)
+        host_state = reconcile_plane_state(host_state, layout, stored_layout)
         # channel state (delay buffers, error feedback, telemetry) resumes
         # when shapes match; anything missing/invalidated re-inits to zeros
         state = ensure_channel_state(host_state, channel, n_nodes, layout)
@@ -414,8 +399,7 @@ def run(argv=None) -> RunResult:
 
         assert tp == 1, "--serve-while-training requires --tp 1"
         pub = WeightPublisher(
-            layout or model_plane_layout(cfg, tp),
-            gap_threshold=args.publish_gap_threshold,
+            layout, gap_threshold=args.publish_gap_threshold,
         )
         engine = ServeEngine(
             cfg, mesh, slots=4, max_prompt=32, max_new=16,
@@ -435,8 +419,8 @@ def run(argv=None) -> RunResult:
             if step % args.publish_every == 0:
                 gaps = fleet_node_gaps(channel, state["channel"])
                 # node 0 publishes its own iterate (params stay tree-form in
-                # the TrainState even under --flat-planes; only opt/channel
-                # hot state is plane-packed)
+                # the TrainState; only opt/channel hot state is
+                # plane-packed)
                 src = jax.tree.map(lambda x: np.asarray(x)[0], state["params"])
                 shipped = pub.offer(src, version=step + 1, gap=int(gaps[0]))
                 print(f"publish v{step + 1} gap={int(gaps[0])} -> "

@@ -25,8 +25,8 @@ opaque 2-byte void; the fp8 plane-bucket dtypes ``float8_e4m3fn`` /
 sniffing the void width; unknown declared names fail with a clean
 ``ValueError``.  V2 checkpoints (no ``"dtypes"`` entry) still restore
 through the legacy sniff — bf16 was the only 2-byte void V2 ever stored —
-pinned by a migration test in ``tests/test_checkpoint.py``.  Flat-plane
-runs additionally stamp the manifest with the layout's shard metadata
+pinned by a migration test in ``tests/test_checkpoint.py``.  Every
+manifest is stamped with the plane layout's shard metadata
 (``"plane_tp"``, per-bucket local ``"plane_rows"``), the key that lets a
 resume at a different tensor-parallel degree rebuild the written layout
 and reconcile the plane-form optimizer state.
@@ -138,16 +138,16 @@ def _unflatten(flat: dict[str, np.ndarray], dtypes: dict | None = None) -> Tree:
     return tree
 
 
-def save_checkpoint(directory: str, state: Tree, *, metadata: dict | None = None,
-                    plane_layout=None):
+def save_checkpoint(directory: str, state: Tree, *, plane_layout,
+                    metadata: dict | None = None):
     """Write one atomic checkpoint under ``directory``.
 
-    ``plane_layout`` (the training run's :class:`PlaneLayout`, when
-    ``flat_planes`` is on) stamps the V3 manifest with shard metadata —
-    ``plane_tp`` and the per-bucket local row counts — so a resume at a
-    different tensor-parallel degree can rebuild the *written* layout and
-    convert the plane-form optimizer state through
-    ``reconcile_plane_state(..., stored_layout=...)``.
+    ``plane_layout`` (the training run's :class:`PlaneLayout`, the one its
+    plane-form optimizer and channel state are packed in) stamps the V3
+    manifest with shard metadata — ``plane_tp`` and the per-bucket local
+    row counts — so a resume at a different tensor-parallel degree can
+    rebuild the *written* layout and convert the plane-form optimizer state
+    through ``reconcile_plane_state(..., stored_layout=...)``.
     """
     step = int(state["step"])
     os.makedirs(directory, exist_ok=True)
@@ -170,17 +170,9 @@ def save_checkpoint(directory: str, state: Tree, *, metadata: dict | None = None
             "n_nodes": int(state["params"][next(iter(state["params"]))]["table"].shape[0])
             if "embed" in state.get("params", {})
             else None,
-            **(
-                {
-                    "plane_tp": int(plane_layout.tp),
-                    "plane_model_axis": plane_layout.model_axis,
-                    "plane_rows": {
-                        k: int(v) for k, v in plane_layout.rows.items()
-                    },
-                }
-                if plane_layout is not None
-                else {}
-            ),
+            "plane_tp": int(plane_layout.tp),
+            "plane_model_axis": plane_layout.model_axis,
+            "plane_rows": {k: int(v) for k, v in plane_layout.rows.items()},
             **(metadata or {}),
         }
         with open(os.path.join(tmp, "manifest.json"), "w") as f:
@@ -205,8 +197,8 @@ def check_plane_manifest(manifest: dict, stored_layout) -> None:
     disagrees with the one the planes were packed with and the mismatch
     only surfaces as a shape assert deep inside ``unpack``.  This check
     fails fast with an actionable error instead.  Manifests without plane
-    metadata (pre-sharded-layout, or written with ``flat_planes`` off)
-    pass through untouched.
+    metadata (written before every checkpoint carried it) pass through
+    untouched.
     """
     rows = manifest.get("plane_rows")
     if rows is not None:

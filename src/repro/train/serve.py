@@ -117,34 +117,3 @@ def build_decode_step(
         axis_names=set(node_axes) | {model_axis},
     )
     return jax.jit(sm, donate_argnums=(2,)), (pspecs, tok_spec, cspecs)
-
-
-def abstract_cache(
-    cfg: ModelConfig, global_batch: int, target_len: int, mesh,
-    scfg: ServeConfig, *, node_axes=("data",), model_axis="model",
-):
-    """ShapeDtypeStruct cache for dry-run decode cells (global shapes)."""
-    tp = mesh.shape[model_axis]
-
-    def build():
-        return T.init_cache(cfg, global_batch, target_len, tp, scfg.runtime)
-
-    shapes = jax.eval_shape(build)
-
-    # init_cache returns *local* (per-model-shard) slot counts; scale the
-    # sharded axes back to global sizes for the jit-level stand-ins.
-    cspecs = T.cache_specs(
-        cfg, _batch_axes(global_batch, node_axes, mesh), model_axis
-    )
-
-    def to_global(x, spec):
-        shape = list(x.shape)
-        for i, axis in enumerate(spec):
-            if axis == model_axis:
-                shape[i] *= tp
-        return jax.ShapeDtypeStruct(tuple(shape), x.dtype)
-
-    return jax.tree.map(
-        to_global, shapes, cspecs,
-        is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct),
-    )

@@ -10,10 +10,13 @@ Per step, on every node (= one (pod, data) mesh index):
    the node axes and mean = psum (PmSGD / SlowMo sync);
 4. metrics psum-reduced to replicated scalars.
 
-The fused fast path (``fused_update=True``) routes every algorithm's
-elementwise tail — payload build, momentum accumulate, Nesterov, weight
-decay, LARS scaling, recombination — through the Pallas fused-update engine
-(one HBM pass per stage; see ``repro.kernels.fused_update``).
+The update runs on flat planes: params and grads are packed into
+dtype-bucketed ``(rows, LANES)`` buffers, every algorithm's elementwise
+tail — payload build, momentum accumulate, Nesterov, weight decay, LARS
+scaling, recombination — is one stage per bucket, and gossip is one
+collective per bucket per edge class.  ``TrainConfig.fused_impl`` picks the
+stage: the pure-jnp reference or the Pallas fused-update kernel (one HBM
+pass per stage; see ``repro.kernels.fused_update``).
 """
 
 from __future__ import annotations
@@ -32,7 +35,7 @@ from ..core.planes import plane_scalars
 from ..core.schedules import ScheduleConfig, build_schedule
 from ..core.topology import build_topology
 from ..core.update_spec import run_update, update_spec, zero_unless
-from ..kernels.fused_update import make_plane_stage, make_stage
+from ..kernels.fused_update import make_plane_stage
 from ..models import transformer as T
 from ..models.layers import TPContext
 from .train_state import model_plane_layout, stacked_state_specs
@@ -59,23 +62,22 @@ class TrainConfig:
     grad_accum: int = 1
     schedule: ScheduleConfig = ScheduleConfig()
     runtime: T.RuntimeConfig = T.RuntimeConfig()
-    fused_update: bool = False
-    fused_impl: str = "ref"  # ref | pallas | pallas_interpret
-    # flat fast path: pack the whole update tail and the gossip payload into
-    # dtype-bucketed plane buffers (one kernel launch per stage per bucket,
-    # one collective per bucket per edge class); optimizer + channel hot
-    # state stays in plane form across steps.  At tp > 1 the layout is
-    # sharded per mesh column — each TP rank packs only its local shard
-    # rows, so launches and node-axis collectives stay O(buckets) per rank.
-    flat_planes: bool = False
+    # the plane update's stage executor: ref | pallas | pallas_interpret.
+    # Optimizer + channel hot state stays in plane form across steps; at
+    # tp > 1 the layout is sharded per mesh column, so each TP rank packs
+    # only its local shard rows.
+    fused_impl: str = "ref"
+    # Always True: the step has one update path.  The fields remain only
+    # because bench/harness.py passes them (and reads flat_planes); a
+    # benchmark change that drops them there removes them here.
+    fused_update: bool = True
+    flat_planes: bool = True
     gossip_serialize: bool = True  # one recv buffer live at a time (§Perf A-3)
     track_consensus: bool = False
     # row-sparse gossip (repro.sparse): ship only the touched rows of each
-    # plane bucket per round.  Requires flat_planes (the RowTracker
-    # addresses the payload through the plane row->segment map) and
-    # gossip_impl="ppermute".  "exact" is provably equivalent to dense
-    # gossip; "delta" heals rows after delivery (lossy, delay-0 only,
-    # benchmarked in BENCH_gossip.json).
+    # plane bucket per round.  Requires gossip_impl="ppermute".  "exact" is
+    # provably equivalent to dense gossip; "delta" heals rows after delivery
+    # (lossy, delay-0 only, benchmarked in BENCH_gossip.json).
     sparse_gossip: bool = False
     sparse_mode: str = "exact"  # exact | delta
     sparse_crossover: float = 0.9  # dirty fraction at which a bucket goes dense
@@ -89,6 +91,13 @@ class TrainConfig:
     chaos: Any = None  # ChaosSchedule | None (frozen/hashable)
     resilient: bool = False
     resilient_gap: int | None = None  # on-device auto-distrust gap bound
+
+    def __post_init__(self):
+        if not (self.flat_planes and self.fused_update):
+            raise ValueError(
+                "the train step always runs the update on flat planes; "
+                "fused_impl (ref | pallas | pallas_interpret) picks the stage"
+            )
 
     def opt_config(self) -> OptimizerConfig:
         return OptimizerConfig(
@@ -245,24 +254,16 @@ def build_train_step(
     opt = make_optimizer(tcfg.opt_config())
     lr_fn = build_schedule(tcfg.schedule)
 
-    # flat fast path: one static plane layout shared by the step, the state
-    # initializer and the resume path.  At tp > 1 the layout is sharded:
-    # its segments carry local per-mesh-column shapes, so the in-shard_map
-    # pack/unpack below operate on exactly the rank's shard rows and the
-    # stacked plane state splits over the model axis (P(model, None) per
-    # node, see train_state._plane_pspec).
-    layout = (
-        model_plane_layout(cfg, tp, model_axis) if tcfg.flat_planes else None
-    )
+    # one static plane layout shared by the step, the state initializer
+    # and the resume path.  At tp > 1 the layout is sharded: its segments
+    # carry local per-mesh-column shapes, so the in-shard_map pack/unpack
+    # below operate on exactly the rank's shard rows and the stacked plane
+    # state splits over the model axis (P(model, None) per node, see
+    # train_state._plane_pspec).
+    layout = model_plane_layout(cfg, tp, model_axis)
 
     tracker = None
     if tcfg.sparse_gossip:
-        if not tcfg.flat_planes:
-            raise ValueError(
-                "sparse_gossip requires flat_planes=True: the RowTracker "
-                "addresses the gossip payload through the plane "
-                "row->segment map"
-            )
         if tp > 1:
             # the sparse channels' per-round volume telemetry is a
             # replicated scalar, but at tp > 1 each mesh column's dirty-row
@@ -393,66 +394,32 @@ def build_train_step(
             units = {"embed": batch["tokens"], **(row_info or {})}
             comp_state = gossip.mark(comp_state, tracker.step_masks(units))
 
-        if tcfg.flat_planes:
-            # flat fast path: pack once, run the whole tail + gossip on
-            # dtype-bucketed plane buffers (O(buckets x stages) launches,
-            # O(buckets x edge-classes) collectives), unpack the new
-            # params for the next forward.  Optimizer + channel state stay
-            # in plane form across steps; the clip/LARS scalars come from
-            # the original trees (zeroed under the guard, inside the norm
-            # reductions) so they match the per-leaf path bit-for-bit.
-            ocfg = tcfg.opt_config()
-            g32 = jax.tree.map(lambda gg: gg.astype(jnp.float32), grads)
-            scalars = plane_scalars(ocfg, layout, params, zero_unless(finite, g32))
-            new_x_pl, new_opt, comp_state = run_update(
-                update_spec(ocfg),
-                ocfg,
-                x=layout.pack(params),
-                g=layout.pack(g32, dtype=jnp.float32),
-                state=opt_state,
-                lr=lr,
-                step_idx=step_idx,
-                gossip=gossip,
-                mean=mean,
-                comp_state=comp_state,
-                stage=make_plane_stage(
-                    tcfg.fused_impl if tcfg.fused_update else "ref"
-                ),
-                scalars=scalars,
-                ok=finite,
-            )
-            new_params = layout.unpack(new_x_pl, like=params)
-        elif tcfg.fused_update:
-            # fused fast path (any algorithm): the spec's phases run with
-            # the Pallas stage executor — payload build and recombination
-            # are one HBM pass each, with the gossip in between
-            ocfg = tcfg.opt_config()
-            new_params, new_opt, comp_state = run_update(
-                update_spec(ocfg),
-                ocfg,
-                x=params,
-                g=jax.tree.map(lambda gg: gg.astype(jnp.float32), grads),
-                state=opt_state,
-                lr=lr,
-                step_idx=step_idx,
-                gossip=gossip,
-                mean=mean,
-                comp_state=comp_state,
-                stage=make_stage(tcfg.fused_impl),
-                ok=finite,
-            )
-        else:
-            new_params, new_opt, comp_state = opt.step(
-                params,
-                grads,
-                opt_state,
-                lr=lr,
-                step_idx=step_idx,
-                gossip=gossip,
-                mean=mean,
-                comp_state=comp_state,
-                ok=finite,
-            )
+        # pack once, run the whole tail + gossip on dtype-bucketed plane
+        # buffers (O(buckets x stages) launches, O(buckets x edge-classes)
+        # collectives), unpack the new params for the next forward.
+        # Optimizer + channel state stay in plane form across steps; the
+        # clip/LARS scalars come from the original trees (zeroed under the
+        # guard, inside the norm reductions), as per-leaf norms cannot be
+        # read off the packed buffers.
+        ocfg = tcfg.opt_config()
+        g32 = jax.tree.map(lambda gg: gg.astype(jnp.float32), grads)
+        scalars = plane_scalars(ocfg, layout, params, zero_unless(finite, g32))
+        new_x_pl, new_opt, comp_state = run_update(
+            update_spec(ocfg),
+            ocfg,
+            x=layout.pack(params),
+            g=layout.pack(g32, dtype=jnp.float32),
+            state=opt_state,
+            lr=lr,
+            step_idx=step_idx,
+            gossip=gossip,
+            mean=mean,
+            comp_state=comp_state,
+            stage=make_plane_stage(tcfg.fused_impl),
+            scalars=scalars,
+            ok=finite,
+        )
+        new_params = layout.unpack(new_x_pl, like=params)
 
         # replicated scalar metrics
         out_metrics = {

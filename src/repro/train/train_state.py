@@ -5,10 +5,12 @@ replica per decentralized node (DESIGN.md §4).  The ``"channel"`` bucket is
 the gossip transport's state (:class:`repro.core.gossip.GossipChannel`):
 compression error-feedback, delay ring buffers, telemetry — one
 checkpointable node whose structure/specs come from the channel itself.
-``init_train_state`` builds it on-device through jit-with-out-shardings so
-each device only ever materializes its own shard (mandatory at 8B x 32
-replicas); the dry-run uses ``abstract_train_state`` (eval_shape, zero
-allocation).
+The parameters stay in tree form (the forward pass consumes them by name);
+the optimizer and channel buckets hold flat planes of the model's
+:func:`model_plane_layout`.  ``init_train_state`` builds the state on-device
+through jit-with-out-shardings so each device only ever materializes its
+own shard (mandatory at 8B x 32 replicas); ``abstract_train_state`` gives
+its shapes (eval_shape, zero allocation).
 """
 
 from __future__ import annotations
@@ -44,8 +46,8 @@ def model_plane_layout(
 ) -> PlaneLayout:
     """The flat-plane layout of this model's per-node parameter tree.
 
-    ``TrainConfig(flat_planes=True)`` keeps the optimizer and channel hot
-    state packed in this layout across steps; the step, the state
+    The train step keeps the optimizer and channel hot state packed in
+    this layout across steps; the step, the state
     initializer and the resume path must all derive it from the same
     template, which this helper pins (abstract — no allocation).  At
     ``tp > 1`` the layout is sharded: the model's ``param_specs`` decide
@@ -93,16 +95,18 @@ def stacked_state_specs(
 ) -> Tree:
     """Specs for the full TrainState pytree (params + opt + channel state).
 
-    With ``plane_layout`` (the flat fast path), the optimizer and channel
-    buckets hold plane buffers — one ``(rows, LANES)`` leaf per dtype
-    bucket — while the parameters stay in tree form (the forward pass
-    consumes them by name).
+    The optimizer and channel buckets hold plane buffers of
+    ``plane_layout`` (default: the model's own, :func:`model_plane_layout`)
+    — one ``(rows, LANES)`` leaf per dtype bucket — while the parameters
+    stay in tree form (the forward pass consumes them by name).
     """
     from ..core.optimizers import state_keys
 
+    if plane_layout is None:
+        plane_layout = model_plane_layout(cfg, tp, model_axis)
     pspec = T.param_specs(cfg, tp, model_axis)
-    hot_spec = _plane_pspec(plane_layout) if plane_layout is not None else pspec
-    # every optimizer state bucket mirrors the param tree (or its planes)
+    hot_spec = _plane_pspec(plane_layout)
+    # every optimizer state bucket holds the params' planes
     opt_state_spec: Tree = {k: hot_spec for k in state_keys(opt.config)}
     channel_spec = channel.state_specs(hot_spec) if channel is not None else {}
     return {
@@ -123,11 +127,13 @@ def make_train_state_fn(
 ):
     """Pure init function (jit-able with out_shardings).
 
-    With ``plane_layout``, the optimizer state buckets and the channel
-    template are packed into f32 planes here — this is the *only* pack the
-    hot state ever pays outside a checkpoint boundary; the train step keeps
-    it in plane form from then on.
+    The optimizer state buckets and the channel template are packed into
+    f32 planes of ``plane_layout`` (default: the model's own) here — this
+    is the *only* pack the hot state ever pays outside a checkpoint
+    boundary; the train step keeps it in plane form from then on.
     """
+    if plane_layout is None:
+        plane_layout = model_plane_layout(cfg, tp)
 
     def init_fn(key):
         params = T.init_params(key, cfg, tp)
@@ -136,14 +142,11 @@ def make_train_state_fn(
             return jnp.broadcast_to(x[None], (n_nodes,) + x.shape)
 
         sp = jax.tree.map(stack, params)
-        opt_state = opt.init(params)
-        chan_template: Tree = params
-        if plane_layout is not None:
-            opt_state = {
-                k: plane_layout.pack_global(v, dtype=jnp.float32)
-                for k, v in opt_state.items()
-            }
-            chan_template = plane_layout.pack_global(params, dtype=jnp.float32)
+        opt_state = {
+            k: plane_layout.pack_global(v, dtype=jnp.float32)
+            for k, v in opt.init(params).items()
+        }
+        chan_template = plane_layout.pack_global(params, dtype=jnp.float32)
         opt_state = jax.tree.map(stack, opt_state)
         chan = (
             jax.tree.map(stack, channel.init(chan_template))
@@ -173,6 +176,8 @@ def init_train_state(
     channel: GossipChannel | None = None,
     plane_layout: PlaneLayout | None = None,
 ):
+    if plane_layout is None:
+        plane_layout = model_plane_layout(cfg, tp, model_axis)
     init_fn = make_train_state_fn(cfg, opt, n_nodes, tp, channel, plane_layout)
     if mesh is None:
         return init_fn(key)
@@ -213,10 +218,11 @@ def ensure_channel_state(
     state: Tree,
     channel: GossipChannel | None,
     n_nodes: int,
-    plane_layout: PlaneLayout | None = None,
+    plane_layout: PlaneLayout,
 ) -> Tree:
     """Reconcile a restored TrainState's ``"channel"`` bucket with the
-    current channel's structure.
+    current channel's structure (its payload template: the params packed
+    into ``plane_layout``'s f32 planes).
 
     Matching sub-nodes survive (compression error feedback and delay
     buffers resume bit-exactly on a same-shape restart); anything missing —
@@ -233,19 +239,12 @@ def ensure_channel_state(
     """
     if channel is None:
         return {**state, "channel": {}}
-    if plane_layout is not None:
-        # flat fast path: the channel state lives in plane layout, so the
-        # expected structure comes from the packed f32 payload template
-        template = jax.eval_shape(
-            lambda p: plane_layout.pack_global(
-                jax.tree.map(lambda x: x[0], p), dtype=jnp.float32
-            ),
-            state["params"],
-        )
-    else:
-        template = jax.eval_shape(
-            lambda p: jax.tree.map(lambda x: x[0], p), state["params"]
-        )
+    template = jax.eval_shape(
+        lambda p: plane_layout.pack_global(
+            jax.tree.map(lambda x: x[0], p), dtype=jnp.float32
+        ),
+        state["params"],
+    )
     abstract = jax.eval_shape(
         lambda t: jax.tree.map(
             lambda x: jnp.broadcast_to(x[None], (n_nodes,) + x.shape),
@@ -295,33 +294,33 @@ def _check_same_global_template(a: PlaneLayout, b: PlaneLayout) -> None:
 
 
 def reconcile_plane_state(
-    state: Tree, plane_layout: PlaneLayout, flat_planes: bool,
+    state: Tree, plane_layout: PlaneLayout,
     stored_layout: PlaneLayout | None = None,
 ) -> Tree:
-    """Convert a restored TrainState's optimizer bucket between tree and
-    plane form, so checkpoints are interchangeable across the
-    ``flat_planes`` flag.
+    """Bring a restored TrainState's optimizer bucket into the plane form
+    of ``plane_layout``.
+
+    Two conversions, per state key:
+
+    * a tree-form bucket (written by a per-leaf update path that no longer
+      exists) is packed, so such checkpoints still resume;
+    * a plane-form bucket written at another tensor-parallel degree —
+      ``stored_layout``, rebuilt from the V3 manifest's ``plane_tp`` —
+      round-trips through the global tree (``stored.unpack_global`` ->
+      ``current.pack_global``), so checkpoints written at ``tp=k`` restore
+      at ``tp=1`` and vice versa, provided both tp values pad the model
+      identically.  That global-template compatibility is asserted only
+      when a plane actually needs converting: a tree-form bucket resumes
+      across tp values regardless of padding differences, since no plane
+      is ever interpreted through the wrong layout.
 
     A plane-form bucket is recognized by its top-level keys being the
     layout's dtype-bucket names (parameter trees never use dtype names as
     top-level keys).  Channel state is *not* converted — its structure is
-    transport-internal (ring buffers sized by the payload), so a
-    cross-format resume re-initializes it through
-    :func:`ensure_channel_state`, exactly like any other structural
-    change.  All optimizer buckets are f32 by construction, packed and
-    unpacked with the stacked node axis preserved.
-
-    ``stored_layout`` is the layout the checkpoint was *written* with
-    (from the V3 manifest's ``plane_tp``); when it differs from
-    ``plane_layout`` a plane-form bucket first round-trips through the
-    global tree (``stored.unpack_global`` -> ``current.pack_global``), so
-    checkpoints written at ``tp=k`` restore at ``tp=1`` and vice versa —
-    provided both tp values pad the model identically.  That global-
-    template compatibility is asserted only when a plane-form bucket
-    actually needs converting: a tree-form opt state (the per-leaf
-    production path) resumes across tp values regardless of padding
-    differences, since no plane is ever interpreted through the wrong
-    layout.
+    transport-internal (ring buffers sized by the payload), so a resume
+    that changed it re-initializes it through :func:`ensure_channel_state`,
+    exactly like any other structural change.  All optimizer buckets are
+    f32 by construction, packed with the stacked node axis preserved.
     """
     if "opt" not in state:
         return state
@@ -332,20 +331,15 @@ def reconcile_plane_state(
     new_opt: Tree = {}
     for k, v in state["opt"].items():
         is_plane = isinstance(v, dict) and set(v) == buckets
-        if is_plane and cross_tp:
+        if is_plane and not cross_tp:
+            new_opt[k] = v
+            continue
+        if is_plane:
             if not templates_checked:
                 _check_same_global_template(stored, plane_layout)
                 templates_checked = True
             v = stored.unpack_global(v, dtype=jnp.float32, leading=1)
-            is_plane = False
-        if flat_planes and not is_plane:
-            new_opt[k] = plane_layout.pack_global(v, dtype=jnp.float32,
-                                                  leading=1)
-        elif not flat_planes and is_plane:
-            new_opt[k] = plane_layout.unpack_global(v, dtype=jnp.float32,
-                                                    leading=1)
-        else:
-            new_opt[k] = v
+        new_opt[k] = plane_layout.pack_global(v, dtype=jnp.float32, leading=1)
     return {**state, "opt": new_opt}
 
 
@@ -354,6 +348,7 @@ def abstract_train_state(
     channel: GossipChannel | None = None,
     plane_layout: PlaneLayout | None = None,
 ):
-    """ShapeDtypeStruct pytree of the TrainState (dry-run input stand-in)."""
+    """ShapeDtypeStruct pytree of the TrainState (compile-only input
+    stand-in)."""
     init_fn = make_train_state_fn(cfg, opt, n_nodes, tp, channel, plane_layout)
     return jax.eval_shape(init_fn, jax.random.key(0))

@@ -2,9 +2,11 @@
 
 Run with XLA_FLAGS=--xla_force_host_platform_device_count=8 (set by the
 pytest wrapper).  Covers ppermute gossip, allgather-baseline gossip,
-compression, and a fault-excluded topology.
+compression, a fault-excluded topology, the Pallas stage's trace, the
+flat-plane collective counts and row-sparse gossip.
 """
 
+import dataclasses
 import sys
 
 import jax
@@ -14,6 +16,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import tiny_lm
 from repro.core import (
+    DelayedStackedChannel,
     StackedChannel,
     build_topology,
     make_optimizer,
@@ -27,20 +30,99 @@ from repro.models.layers import TPContext
 from repro.train.step import TrainConfig, build_train_step
 from repro.train.train_state import init_train_state
 
+
+def put(batch, bshard):
+    return jax.tree.map(lambda x, sh: jax.device_put(jnp.asarray(x), sh),
+                        batch, bshard)
+
+
+def run_step(cfg, tcfg, mesh, N, TP, data, steps=3):
+    """``steps`` steps of the shard_map train step; returns the final state
+    and the step's ppermute count (from its jaxpr)."""
+    from repro.launch.costmodel import count_primitive
+
+    opt = make_optimizer(tcfg.opt_config())
+    step_fn, _, bspecs, channel = build_train_step(
+        cfg, tcfg, mesh, node_axes=("data",)
+    )
+    state = init_train_state(jax.random.key(0), cfg, opt, N, TP, mesh=mesh,
+                             node_axes=("data",), channel=channel)
+    bshard = jax.tree.map(lambda s: NamedSharding(mesh, s), bspecs,
+                          is_leaf=lambda x: isinstance(x, P))
+    n_ppermute = count_primitive(
+        jax.make_jaxpr(step_fn)(state, put(data.batch(0), bshard)), "ppermute"
+    )
+    for k in range(steps):
+        state, metrics = step_fn(state, put(data.batch(k), bshard))
+    assert np.isfinite(float(metrics["loss"]))
+    return state, n_ppermute
+
+
+def reference_params(cfg, tcfg, N, TP, S, data, steps=3):
+    """The stacked oracle: per-node grads (vmapped, unsharded) and the
+    per-leaf float32 ``opt.step`` over a plain dense-W channel (delayed
+    when the step's channel is)."""
+    rt = tcfg.runtime
+    tp1 = TPContext(size=1)
+    opt = make_optimizer(tcfg.opt_config())
+    topo = build_topology(tcfg.topology, N)
+    if tcfg.gossip_delay:
+        g_ref = DelayedStackedChannel(topo, tcfg.gossip_delay,
+                                      calls_per_step=opt.gossips_per_step)
+    else:
+        g_ref = StackedChannel(topo)
+    m_ref = make_stacked_mean(N)
+    params0 = T.init_params(jax.random.key(0), cfg, tp=TP)
+    ref_p = jax.tree.map(lambda x: jnp.broadcast_to(x[None], (N,) + x.shape),
+                         params0)
+    ref_o = jax.tree.map(lambda x: jnp.broadcast_to(x[None], (N,) + x.shape),
+                         opt.init(params0))
+    ref_c = g_ref.init(ref_p)
+    lr = tcfg.schedule.peak_lr
+
+    def per_node_grads(sp, batch):
+        def one(p, bt, bg):
+            def lf(pp):
+                return T.forward_loss(pp, {"tokens": bt, "targets": bg}, cfg,
+                                      tp1, rt)
+            (l, mm), g = jax.value_and_grad(lf, has_aux=True)(p)
+            return g, l
+
+        bt = batch["tokens"].reshape(N, -1, S)
+        bg = batch["targets"].reshape(N, -1, S)
+        return jax.vmap(one)(sp, bt, bg)
+
+    @jax.jit
+    def ref_step(sp, so, sc, batch, k):
+        g, l = per_node_grads(sp, batch)
+        return opt.step(sp, g, so, lr=jnp.float32(lr), step_idx=k,
+                        gossip=g_ref, mean=m_ref, comp_state=sc)
+
+    for k in range(steps):
+        b = {kk: jnp.asarray(v) for kk, v in data.batch(k).items()}
+        ref_p, ref_o, ref_c = ref_step(ref_p, ref_o, ref_c, b, jnp.int32(k))
+    return ref_p
+
+
+def max_err(a, b) -> float:
+    return max(jax.tree.leaves(jax.tree.map(
+        lambda x, y: float(np.max(np.abs(
+            np.asarray(x, np.float32) - np.asarray(y, np.float32)))),
+        a, b)))
+
+
 MODE = sys.argv[1] if len(sys.argv) > 1 else "baseline"
+TOL = 2e-5
 
 if MODE.startswith("planes"):
-    # Flat-plane fast path vs the per-leaf path on the same 8-device mesh:
-    # identical trajectories (leaf-exact) AND the collapsed collective
-    # count — the plane step must ppermute one buffer per dtype bucket per
-    # edge class where the per-leaf step ppermutes every pytree leaf.
-    # "planes" runs plain decentlam on 8 nodes x tp=1; "planes-delayed"
-    # runs decentlam-sa over a delay-2 DelayedPpermuteChannel (ring buffers
-    # in plane layout); "planes-tp" reruns BOTH cases on a 4-node x 2-way-TP
-    # mesh with the sharded layout — per-rank local buckets, same collapsed
-    # ppermute count as tp=1 (the model axis adds no gossip collectives).
-    from repro.launch.costmodel import count_primitive
-    from repro.train.train_state import init_train_state as _init_state
+    # The flat-plane step vs the stacked per-leaf oracle on the same mesh,
+    # AND the collapsed collective count — the plane step ppermutes one
+    # buffer per dtype bucket per edge class.  "planes" runs plain
+    # decentlam on 8 nodes x tp=1; "planes-delayed" runs decentlam-sa over
+    # a delay-2 DelayedPpermuteChannel (ring buffers in plane layout);
+    # "planes-tp" reruns BOTH cases on a 4-node x 2-way-TP mesh with the
+    # sharded layout — per-rank local buckets, same collapsed ppermute
+    # count as tp=1 (the model axis adds no gossip collectives).
     from repro.train.train_state import model_plane_layout
 
     S = 32
@@ -59,67 +141,27 @@ if MODE.startswith("planes"):
         vocab_size=256, seq_len=S, per_node_batch=2, n_nodes=N,
         heterogeneity=0.5,
     ))
-    params_tmpl = jax.eval_shape(
-        lambda k: T.init_params(k, cfg, tp=TP), jax.random.key(0)
-    )
-    n_leaves = len(jax.tree.leaves(params_tmpl))
-    layout = model_plane_layout(cfg, TP)
-    n_buckets = len(layout.segments)
+    n_buckets = len(model_plane_layout(cfg, TP).segments)
     classes = len(build_topology("ring", N).edge_classes(0))
 
     for algo, delay in cases:
-        common = dict(
+        tcfg = TrainConfig(
             algorithm=algo, topology="ring", momentum=0.9, gossip_delay=delay,
             schedule=ScheduleConfig(kind="constant", peak_lr=1e-2),
             runtime=T.RuntimeConfig(dtype="float32", remat=False),
         )
-        finals, counts = {}, {}
-        for flat in (False, True):
-            tcfg = TrainConfig(flat_planes=flat, **common)
-            opt = make_optimizer(tcfg.opt_config())
-            step_fn, _, bspecs, channel = build_train_step(
-                cfg, tcfg, mesh, node_axes=("data",)
-            )
-            state = _init_state(
-                jax.random.key(0), cfg, opt, N, TP, mesh=mesh,
-                node_axes=("data",),
-                channel=channel, plane_layout=layout if flat else None,
-            )
-            bshard = jax.tree.map(lambda s: NamedSharding(mesh, s), bspecs,
-                                  is_leaf=lambda x: isinstance(x, P))
-            b0 = jax.tree.map(lambda x, sh: jax.device_put(jnp.asarray(x), sh),
-                              data.batch(0), bshard)
-            counts[flat] = count_primitive(
-                jax.make_jaxpr(step_fn)(state, b0), "ppermute"
-            )
-            for k in range(3):
-                b = jax.tree.map(
-                    lambda x, sh: jax.device_put(jnp.asarray(x), sh),
-                    data.batch(k), bshard,
-                )
-                state, metrics = step_fn(state, b)
-            assert np.isfinite(float(metrics["loss"]))
-            finals[flat] = jax.device_get(state["params"])
-
-        maxerr = max(
-            float(np.max(np.abs(
-                np.asarray(a, np.float32) - np.asarray(b, np.float32)
-            )))
-            for a, b in zip(jax.tree.leaves(finals[False]),
-                            jax.tree.leaves(finals[True]))
-        )
-        assert maxerr == 0.0, (
-            f"{MODE}/{algo}: plane vs per-leaf trajectories differ: {maxerr}"
-        )
-        # the per-device program carries one ppermute per leaf (per-leaf
-        # path) / per bucket (plane path) per edge class, REGARDLESS of tp:
-        # the tp > 1 counts must equal the tp == 1 collapse exactly
-        assert counts[False] == classes * n_leaves, (counts, classes, n_leaves)
-        assert counts[True] == classes * n_buckets, (counts, classes, n_buckets)
-        print(f"{MODE}/{algo}: OK bit-exact; ppermutes/step {counts[False]} "
-              f"-> {counts[True]} ({n_leaves} leaves -> {n_buckets} "
-              f"bucket(s) x {classes} edge classes, tp={TP})")
-    print(f"{MODE}: OK bit-exact")
+        state, n_ppermute = run_step(cfg, tcfg, mesh, N, TP, data)
+        err = max_err(state["params"],
+                      reference_params(cfg, tcfg, N, TP, S, data))
+        assert err < TOL, f"{MODE}/{algo}: plane step vs oracle: {err}"
+        # the per-device program carries one ppermute per bucket per edge
+        # class, REGARDLESS of tp: the tp > 1 count equals the tp == 1
+        # collapse exactly
+        assert n_ppermute == classes * n_buckets, (n_ppermute, classes, n_buckets)
+        print(f"{MODE}/{algo}: OK maxerr={err:.2e}; ppermutes/step "
+              f"{n_ppermute} ({n_buckets} bucket(s) x {classes} edge "
+              f"classes, tp={TP})")
+    print(f"{MODE}: OK")
     sys.exit(0)
 
 if MODE == "sparse":
@@ -141,7 +183,7 @@ if MODE == "sparse":
         heterogeneity=0.5,
     ))
     common = dict(
-        algorithm="decentlam", topology="ring", momentum=0.9, flat_planes=True,
+        algorithm="decentlam", topology="ring", momentum=0.9,
         schedule=ScheduleConfig(kind="constant", peak_lr=1e-2),
         runtime=T.RuntimeConfig(dtype="float32", remat=False),
     )
@@ -198,7 +240,7 @@ kwargs = dict(
     schedule=ScheduleConfig(kind="constant", peak_lr=1e-2),
     runtime=T.RuntimeConfig(dtype="float32", remat=False),
 )
-tol = 2e-5
+tol = TOL
 if MODE == "allgather":
     kwargs["gossip_impl"] = "allgather"
 elif MODE == "compressed":
@@ -213,74 +255,54 @@ elif MODE == "topk":
     kwargs["compression"] = "topk:0.05"
     tol = float("inf")
 elif MODE == "fused":
-    # exercises the fused-update code path in step.py (payload -> gossip ->
-    # fused tail).  impl="ref" is bit-identical math to the Pallas kernel
-    # (validated elementwise in tests/test_kernels.py); interpret-mode Pallas
-    # can't trace inside a check_vma shard_map on CPU (its Python block
-    # slicing mixes variances) — on TPU the real kernel lowers natively.
-    kwargs["fused_update"] = True
+    # the Pallas stage executor: its step traces on the sharded 4x2 layout
+    # with one pallas_call per bucket per stage.  Interpret-mode Pallas
+    # can't run inside a check_vma shard_map on CPU (its Python block
+    # slicing mixes variances), so the trajectory below runs the "ref"
+    # stage, whose math the kernel shares (pinned in tests/test_planes.py
+    # and tests/test_kernels.py); on TPU the real kernel lowers natively.
+    from repro.core.update_spec import stage_plan
+    from repro.launch.costmodel import count_primitive
+    from repro.train.train_state import model_plane_layout
+
+    tcfg = TrainConfig(fused_impl="pallas_interpret", **kwargs)
+    opt = make_optimizer(tcfg.opt_config())
+    step_fn, _, bspecs, channel = build_train_step(
+        cfg, tcfg, mesh, node_axes=("data",)
+    )
+    state = init_train_state(jax.random.key(0), cfg, opt, N, TP, mesh=mesh,
+                             node_axes=("data",), channel=channel)
+    bshard = jax.tree.map(lambda s: NamedSharding(mesh, s), bspecs,
+                          is_leaf=lambda x: isinstance(x, P))
+    data = SyntheticLM(SyntheticLMConfig(vocab_size=256, seq_len=S,
+                                         per_node_batch=2, n_nodes=N,
+                                         heterogeneity=0.5))
+    n_calls = count_primitive(
+        jax.make_jaxpr(step_fn)(state, put(data.batch(0), bshard)),
+        "pallas_call",
+    )
+    want = len(model_plane_layout(cfg, TP).segments) * len(
+        stage_plan(tcfg.opt_config()))
+    assert n_calls == want, (n_calls, want)
     kwargs["fused_impl"] = "ref"
 
 tcfg = TrainConfig(**kwargs)
-opt = make_optimizer(tcfg.opt_config())
-step_fn, _, bspecs, channel = build_train_step(cfg, tcfg, mesh, node_axes=("data",))
-state = init_train_state(jax.random.key(0), cfg, opt, N, TP, mesh=mesh,
-                         node_axes=("data",), channel=channel)
 data = SyntheticLM(SyntheticLMConfig(vocab_size=256, seq_len=S, per_node_batch=2,
                                      n_nodes=N, heterogeneity=0.5))
-bshard = jax.tree.map(lambda s: NamedSharding(mesh, s), bspecs,
-                      is_leaf=lambda x: isinstance(x, P))
-for k in range(3):
-    b = jax.tree.map(lambda x, sh: jax.device_put(jnp.asarray(x), sh),
-                     data.batch(k), bshard)
-    state, metrics = step_fn(state, b)
-assert np.isfinite(float(metrics["loss"]))
-
-# stacked reference with plain (uncompressed, dense-W) channel
-rt = tcfg.runtime
-tp1 = TPContext(size=1)
-topo = build_topology(kwargs["topology"], N)
-g_ref, m_ref = StackedChannel(topo), make_stacked_mean(N)
-params0 = T.init_params(jax.random.key(0), cfg, tp=TP)
-ref_p = jax.tree.map(lambda x: jnp.broadcast_to(x[None], (N,) + x.shape), params0)
-ref_o = jax.tree.map(lambda x: jnp.broadcast_to(x[None], (N,) + x.shape),
-                     opt.init(params0))
-
-
-def per_node_grads(sp, batch):
-    def one(p, bt, bg):
-        def lf(pp):
-            return T.forward_loss(pp, {"tokens": bt, "targets": bg}, cfg, tp1, rt)
-        (l, mm), g = jax.value_and_grad(lf, has_aux=True)(p)
-        return g, l
-
-    bt = batch["tokens"].reshape(N, -1, S)
-    bg = batch["targets"].reshape(N, -1, S)
-    return jax.vmap(one)(sp, bt, bg)
-
-
-@jax.jit
-def ref_step(sp, so, batch, k):
-    g, l = per_node_grads(sp, batch)
-    p2, o2, _ = opt.step(sp, g, so, lr=jnp.float32(1e-2), step_idx=k,
-                         gossip=g_ref, mean=m_ref)
-    return p2, o2
-
-
-for k in range(3):
-    b = {kk: jnp.asarray(v) for kk, v in data.batch(k).items()}
-    ref_p, ref_o = ref_step(ref_p, ref_o, b, jnp.int32(k))
-
-errs = jax.tree.leaves(jax.tree.map(
-    lambda a, b_: float(np.max(np.abs(np.asarray(a) - np.asarray(b_)))),
-    state["params"], ref_p))
-maxerr = max(errs)
+state, _ = run_step(cfg, tcfg, mesh, N, TP, data)
+# the oracle gossips over a plain uncompressed dense-W channel
+ref_p = reference_params(
+    cfg, dataclasses.replace(tcfg, compression=None, gossip_impl="ppermute"),
+    N, TP, S, data,
+)
+maxerr = max_err(state["params"], ref_p)
 assert maxerr < tol, f"{MODE}: {maxerr}"
 if MODE == "topk":
     ef = [np.abs(np.asarray(x)).sum()
           for x in jax.tree.leaves(state["channel"]["comp"])]
     assert sum(ef) > 0.0, "error-feedback residuals never populated"
 tele = state["channel"]["t"]
+opt = make_optimizer(tcfg.opt_config())
 assert int(tele["rounds"][0]) == 3 * opt.gossips_per_step, tele
 assert float(tele["bytes"][0]) > 0.0
 print(f"{MODE}: OK maxerr={maxerr:.2e}")

@@ -13,6 +13,7 @@ from repro.core import (
     make_optimizer,
     make_stacked_mean,
 )
+from repro.core.planes import LANES, PlaneLayout
 from repro.train.checkpoint import (
     elastic_reshape,
     latest_step,
@@ -38,14 +39,24 @@ def _state(n_nodes=4, step=7):
     return st
 
 
+def _layout_of(st):
+    """The plane layout of a stacked state's per-node params."""
+    return PlaneLayout.build(jax.tree.map(lambda a: a[0], st["params"]))
+
+
+def _save(d, st, **kw):
+    return save_checkpoint(d, st, plane_layout=_layout_of(st), **kw)
+
+
 def test_save_restore_bit_exact(tmp_path):
     st = _state()
     d = str(tmp_path / "ckpt")
-    save_checkpoint(d, st, metadata={"topology": "exp"})
+    _save(d, st, metadata={"topology": "exp"})
     assert latest_step(d) == 7
     restored, manifest = restore_checkpoint(d)
     assert manifest["step"] == 7
     assert manifest["topology"] == "exp"
+    assert manifest["plane_tp"] == 1  # every manifest carries its layout
     for a, b in zip(jax.tree.leaves(st), jax.tree.leaves(restored)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
@@ -62,7 +73,7 @@ def test_v3_dtype_manifest_roundtrip(tmp_path):
         np.arange(12).reshape(4, 3) % 2 == 0
     )}}
     d = str(tmp_path / "ckpt")
-    save_checkpoint(d, st)
+    _save(d, st)
     restored, manifest = restore_checkpoint(d)
     assert manifest["format"] == 3
     assert manifest["dtypes"]["params/bf16_plane"] == "bfloat16"
@@ -94,7 +105,7 @@ def test_v3_fp8_plane_bucket_roundtrip(tmp_path):
         }
     }
     d = str(tmp_path / "ckpt")
-    save_checkpoint(d, st)
+    _save(d, st)
     restored, manifest = restore_checkpoint(d)
     assert manifest["dtypes"]["channel/comp/float8_e4m3fn"] == "float8_e4m3fn"
     assert manifest["dtypes"]["channel/comp/float8_e5m2"] == "float8_e5m2"
@@ -118,7 +129,7 @@ def test_unknown_manifest_dtype_rejected(tmp_path):
 
     st = _state(step=2)
     d = str(tmp_path / "ckpt")
-    save_checkpoint(d, st)
+    _save(d, st)
     mpath = os.path.join(d, "step_00000002", "manifest.json")
     with open(mpath) as f:
         manifest = json.load(f)
@@ -144,7 +155,7 @@ def test_v2_checkpoint_migration(tmp_path):
         jnp.arange(24, dtype=jnp.bfloat16).reshape(4, 6) / 3
     )
     d = str(tmp_path / "ckpt")
-    save_checkpoint(d, st)
+    _save(d, st)
     # strip the checkpoint back to the V2 manifest shape on disk
     mpath = os.path.join(d, "step_00000005", "manifest.json")
     with open(mpath) as f:
@@ -164,9 +175,9 @@ def test_v2_checkpoint_migration(tmp_path):
 def test_atomic_overwrite(tmp_path):
     st = _state(step=3)
     d = str(tmp_path / "ckpt")
-    save_checkpoint(d, st)
+    _save(d, st)
     st["step"] = jnp.int32(9)
-    save_checkpoint(d, st)
+    _save(d, st)
     assert latest_step(d) == 9
     restored, _ = restore_checkpoint(d, step=3)
     assert int(restored["step"]) == 3
@@ -191,10 +202,10 @@ def test_elastic_shrink_and_grow():
 def test_elastic_then_restart_roundtrip(tmp_path):
     st = _state(n_nodes=4)
     d = str(tmp_path / "c")
-    save_checkpoint(d, st)
+    _save(d, st)
     restored, _ = restore_checkpoint(d)
     resized = elastic_reshape(restored, 8)
-    save_checkpoint(str(tmp_path / "c2"), resized)
+    _save(str(tmp_path / "c2"), resized)
     again, _ = restore_checkpoint(str(tmp_path / "c2"))
     assert jax.tree.leaves(again["params"])[0].shape[0] == 8
 
@@ -248,7 +259,7 @@ def test_channel_state_roundtrip_bit_exact(tmp_path):
     st = _delayed_run(3)
     assert set(st["channel"]) == {"t", "comp", "delay"}
     d = str(tmp_path / "ck")
-    save_checkpoint(d, st)
+    _save(d, st)
     restored, _ = restore_checkpoint(d)
     assert jax.tree.structure(restored["channel"]) == jax.tree.structure(
         st["channel"]
@@ -262,7 +273,7 @@ def test_channel_state_resume_equality(tmp_path):
     — delay ring buffers and error-feedback residuals survive the restart."""
     st3 = _delayed_run(3)
     d = str(tmp_path / "ck")
-    save_checkpoint(d, st3)
+    _save(d, st3)
     restored, _ = restore_checkpoint(d)
     resumed = _delayed_run(3, state=restored)
     straight = _delayed_run(6)
@@ -279,12 +290,16 @@ def test_ensure_channel_state_reconciles_legacy_and_fresh():
     n, d = 4, 5
     topo = build_topology("ring", n)
     params = {"w": jnp.zeros((n, d), jnp.float32)}
+    lay = PlaneLayout.build({"w": jnp.zeros((d,), jnp.float32)})
+    plane = (lay.rows["float32"], LANES)  # the channel's payload: planes
     channel = PpermuteChannel(
         topo, ("data",), compression="topk:0.5", telemetry=True
     )
-    fixed = ensure_channel_state({"params": params, "channel": {}}, channel, n)
+    fixed = ensure_channel_state(
+        {"params": params, "channel": {}}, channel, n, lay
+    )
     assert set(fixed["channel"]) == {"t", "comp"}
-    assert fixed["channel"]["comp"]["w"].shape == (n, d)
+    assert fixed["channel"]["comp"]["float32"].shape == (n, *plane)
     assert fixed["channel"]["t"]["rounds"].shape == (n,)
 
     # a populated matching bucket survives reconciliation untouched
@@ -293,10 +308,10 @@ def test_ensure_channel_state_reconciles_legacy_and_fresh():
             "rounds": jnp.arange(n, dtype=jnp.int32),
             "bytes": jnp.ones((n,), jnp.float32),
         },
-        "comp": {"w": jnp.ones((n, d), jnp.float32)},
+        "comp": {"float32": jnp.ones((n, *plane), jnp.float32)},
     }
     kept = ensure_channel_state(
-        {"params": params, "channel": populated}, channel, n
+        {"params": params, "channel": populated}, channel, n, lay
     )
     for a, b in zip(jax.tree.leaves(populated), jax.tree.leaves(kept["channel"])):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -305,10 +320,11 @@ def test_ensure_channel_state_reconciles_legacy_and_fresh():
     # stale: the delayed channel has fresh zeroed history + counts
     delayed = DelayedPpermuteChannel(topo, ("data",), 2, telemetry=True)
     fixed2 = ensure_channel_state(
-        {"params": params, "channel": populated}, delayed, n
+        {"params": params, "channel": populated}, delayed, n, lay
     )
     assert set(fixed2["channel"]) == {"t", "delay"}
-    assert fixed2["channel"]["delay"]["s0"]["hist"]["w"].shape == (n, 3, d)
+    assert fixed2["channel"]["delay"]["s0"]["hist"]["float32"].shape == (
+        n, 3, *plane)
     assert int(np.max(np.asarray(fixed2["channel"]["delay"]["s0"]["count"]))) == 0
 
     # delay slots resume ATOMICALLY: a checkpoint from --gossip-delay 2
@@ -317,23 +333,23 @@ def test_ensure_channel_state_reconciles_legacy_and_fresh():
     old_slot = {
         "delay": {
             "s0": {
-                "hist": {"w": jnp.ones((n, 3, d), jnp.float32)},
+                "hist": {"float32": jnp.ones((n, 3, *plane), jnp.float32)},
                 "count": jnp.full((n,), 7, jnp.int32),
             }
         }
     }
     delayed3 = DelayedPpermuteChannel(topo, ("data",), 3, telemetry=True)
     fixed3 = ensure_channel_state(
-        {"params": params, "channel": old_slot}, delayed3, n
+        {"params": params, "channel": old_slot}, delayed3, n, lay
     )
     slot = fixed3["channel"]["delay"]["s0"]
-    assert slot["hist"]["w"].shape == (n, 4, d)
+    assert slot["hist"]["float32"].shape == (n, 4, *plane)
     assert int(np.max(np.asarray(slot["count"]))) == 0  # count reset with hist
     # same-shape slots survive untouched (count AND hist together)
     fixed2b = ensure_channel_state(
         {"params": params, "channel": old_slot},
-        DelayedPpermuteChannel(topo, ("data",), 2, telemetry=True), n,
+        DelayedPpermuteChannel(topo, ("data",), 2, telemetry=True), n, lay,
     )
     slot2 = fixed2b["channel"]["delay"]["s0"]
     assert int(np.max(np.asarray(slot2["count"]))) == 7
-    np.testing.assert_array_equal(np.asarray(slot2["hist"]["w"]), 1.0)
+    np.testing.assert_array_equal(np.asarray(slot2["hist"]["float32"]), 1.0)

@@ -9,8 +9,9 @@ import pytest
 
 from repro.core import StackedChannel, build_topology, make_stacked_mean
 from repro.core.optimizers import ALGORITHMS, OptimizerConfig, make_optimizer
-from repro.core.update_spec import run_update, update_spec
-from repro.kernels.fused_update import decentlam_update, make_stage
+from repro.core.planes import PlaneLayout, plane_scalars
+from repro.core.update_spec import MathCtx, reference_stage, run_update, update_spec
+from repro.kernels.fused_update import make_plane_stage
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import reference_attention
 from repro.kernels.mlstm_chunk.ops import mlstm
@@ -123,6 +124,37 @@ def test_mlstm_decode_extends_sequence():
     )
 
 
+def _plane_stage_on_tree(kind, op, ctx, ops, scalars, like_x):
+    """The Pallas plane stage (interpret mode) on trees: each operand is
+    packed into the plane layout of ``like_x``, the stage runs once per
+    bucket, and its outputs are unpacked to trees again."""
+    lay = PlaneLayout.build(like_x)
+    packed = {
+        n: lay.pack(t, dtype=None if n == "x" else jnp.float32)
+        for n, t in ops.items()
+    }
+    out = make_plane_stage("pallas_interpret")(
+        kind, op, ctx, packed, scalars, lay.pack(like_x)
+    )
+    return {
+        n: lay.unpack(v, like=like_x) if n == "x"
+        else lay.unpack(v, dtype=jnp.float32)
+        for n, v in out.items()
+    }
+
+
+def _decentlam_post(x, mix, m, lr, beta, stage):
+    """The DecentLaM tail (eq. 17 + momentum + step) as one post stage:
+    ``g~ = (x - mix) / lr``, ``m_new = beta m + g~``, ``x_new = x - lr
+    m_new``.  Returns ``(x_new, m_new)``."""
+    scalars = {"lr": jnp.float32(lr), "gs": jnp.float32(1.0),
+               "r": jnp.float32(1.0)}
+    out = stage("post", "decentlam_post", MathCtx(beta=beta),
+                {"x": {"w": x}, "mix": {"w": mix}, "m": {"w": m}}, scalars,
+                {"w": x})
+    return out["x"]["w"], out["m"]["w"]
+
+
 @pytest.mark.parametrize(
     "shape,dt",
     [((1000,), jnp.float32), ((33, 77), jnp.float32), ((8, 128), jnp.bfloat16),
@@ -132,18 +164,14 @@ def test_decentlam_update_kernel(shape, dt):
     x = _rand(shape, dt)
     mix = x - 0.01 * jnp.sign(x)
     m = _rand(shape, jnp.float32)
-    lr = jnp.float32(0.02)
-    p_ref, m_ref = decentlam_update({"w": x}, {"w": mix}, {"w": m}, lr, beta=0.9, impl="ref")
-    p_pl, m_pl = decentlam_update(
-        {"w": x}, {"w": mix}, {"w": m}, lr, beta=0.9, impl="pallas_interpret"
-    )
+    lr = 0.02
+    p_ref, m_ref = _decentlam_post(x, mix, m, lr, 0.9, reference_stage)
+    p_pl, m_pl = _decentlam_post(x, mix, m, lr, 0.9, _plane_stage_on_tree)
     np.testing.assert_allclose(
-        np.asarray(p_ref["w"], np.float32), np.asarray(p_pl["w"], np.float32),
+        np.asarray(p_ref, np.float32), np.asarray(p_pl, np.float32),
         atol=1e-2 if dt == jnp.bfloat16 else 1e-6,
     )
-    np.testing.assert_allclose(
-        np.asarray(m_ref["w"]), np.asarray(m_pl["w"]), atol=1e-2
-    )
+    np.testing.assert_allclose(np.asarray(m_ref), np.asarray(m_pl), atol=1e-2)
 
 
 @pytest.mark.parametrize("sg", [1.0, 0.37, 0.0])
@@ -152,15 +180,10 @@ def test_decentlam_sa_post_fused_matches_reference(sg):
     factors (the fused engine receives sg as the 4th SMEM scalar — the
     per-node value inside shard_map).  At sg=1 both must also equal the
     plain decentlam_post (the bit-exactness hinge)."""
-    from repro.core.update_spec import MathCtx, reference_stage
-    from repro.kernels.fused_update import make_stage
-
     rng = np.random.default_rng(17)
     ops = {
-        "x": jnp.asarray(rng.standard_normal((9, 33)), jnp.float32),
-        "mix": jnp.asarray(rng.standard_normal((9, 33)), jnp.float32),
-        "m": jnp.asarray(rng.standard_normal((9, 33)), jnp.float32),
-        "g": jnp.asarray(rng.standard_normal((9, 33)), jnp.float32),
+        name: {"w": jnp.asarray(rng.standard_normal((9, 33)), jnp.float32)}
+        for name in ("x", "mix", "m", "g")
     }
     scalars = {
         "lr": jnp.float32(0.02),
@@ -172,21 +195,23 @@ def test_decentlam_sa_post_fused_matches_reference(sg):
     ref = reference_stage(
         "post", "decentlam_sa_post", ctx, ops, scalars, ops["x"]
     )
-    fus = make_stage("pallas_interpret")(
+    fus = _plane_stage_on_tree(
         "post", "decentlam_sa_post", ctx, ops, scalars, ops["x"]
     )
     for k in ("x", "m"):
         np.testing.assert_allclose(
-            np.asarray(ref[k]), np.asarray(fus[k]), rtol=1e-5, atol=1e-5,
-            err_msg=k,
+            np.asarray(ref[k]["w"]), np.asarray(fus[k]["w"]), rtol=1e-5,
+            atol=1e-5, err_msg=k,
         )
     if sg == 1.0:
         plain = reference_stage(
             "post", "decentlam_post", ctx,
             {k: ops[k] for k in ("x", "mix", "m")}, scalars, ops["x"],
         )
-        np.testing.assert_array_equal(np.asarray(ref["x"]), np.asarray(plain["x"]))
-        np.testing.assert_array_equal(np.asarray(ref["m"]), np.asarray(plain["m"]))
+        for k in ("x", "m"):
+            np.testing.assert_array_equal(
+                np.asarray(ref[k]["w"]), np.asarray(plain[k]["w"])
+            )
 
 
 def test_decentlam_update_semantics():
@@ -194,19 +219,32 @@ def test_decentlam_update_semantics():
     x = _rand((256,), jnp.float32)
     mix = _rand((256,), jnp.float32)
     m = _rand((256,), jnp.float32)
-    lr = jnp.float32(0.1)
-    p, m2 = decentlam_update({"w": x}, {"w": mix}, {"w": m}, lr, beta=0.9, impl="ref")
+    p, _ = _decentlam_post(x, mix, m, 0.1, 0.9, reference_stage)
     np.testing.assert_allclose(
-        np.asarray(p["w"]), np.asarray(mix - 0.1 * 0.9 * m), atol=1e-5
+        np.asarray(p), np.asarray(mix - 0.1 * 0.9 * m), atol=1e-5
     )
 
 
 # ---------------------------------------------------------------------------
 # Fused multi-algorithm engine: every algorithm's full update tail through
-# the Pallas stage kernels (interpret mode) vs the stacked reference step.
+# the Pallas plane stage kernels (interpret mode, per node over the stacked
+# planes) vs the stacked reference step.
 # ---------------------------------------------------------------------------
 
 N_NODES = 8
+
+
+def _per_node(stage):
+    """``stage`` run on each node's planes of stacked ``(n, rows, LANES)``
+    buffers; the stage scalars are shared by every node."""
+
+    def run(kind, op, ctx, operands, scalars, like_x):
+        def one(ops, like):
+            return stage(kind, op, ctx, ops, scalars, like)
+
+        return jax.vmap(one)(operands, like_x)
+
+    return run
 
 
 def _fused_vs_reference(cfg: OptimizerConfig, dt, *, steps=1, lr=0.01):
@@ -220,10 +258,13 @@ def _fused_vs_reference(cfg: OptimizerConfig, dt, *, steps=1, lr=0.01):
     }
     opt = make_optimizer(cfg)
     spec = update_spec(cfg)
-    stage = make_stage("pallas_interpret")
+    stage = _per_node(make_plane_stage("pallas_interpret"))
+    lay = PlaneLayout.build(jax.tree.map(lambda a: a[0], params))
 
-    p_ref, p_fus = params, params
-    s_ref, s_fus = opt.init(params), opt.init(params)
+    p_ref, p_fus = params, lay.pack(params, leading=1)
+    s_ref = opt.init(params)
+    s_fus = {k: lay.pack(v, dtype=jnp.float32, leading=1)
+             for k, v in s_ref.items()}
     for k in range(steps):
         grads = {
             kk: jnp.asarray(rng.standard_normal(v.shape), jnp.float32)
@@ -233,12 +274,19 @@ def _fused_vs_reference(cfg: OptimizerConfig, dt, *, steps=1, lr=0.01):
             p_ref, grads, s_ref, lr=lr, step_idx=jnp.int32(k),
             gossip=gossip, mean=mean,
         )
+        # the scalars come from the stacked trees, as the reference's do
+        scalars = plane_scalars(
+            cfg, lay, lay.unpack(p_fus, like=params, leading=1), grads
+        )
         x, s_fus, _ = run_update(
-            spec, cfg, x=p_fus, g=grads, state=s_fus, lr=lr,
-            step_idx=jnp.int32(k), gossip=gossip, mean=mean,
-            comp_state=(), stage=stage,
+            spec, cfg, x=p_fus, g=lay.pack(grads, dtype=jnp.float32, leading=1),
+            state=s_fus, lr=lr, step_idx=jnp.int32(k), gossip=gossip,
+            mean=mean, comp_state=(), stage=stage, scalars=scalars,
         )
         p_fus = jax.tree.map(lambda p, v: v.astype(p.dtype), p_fus, x)
+    p_fus = lay.unpack(p_fus, like=params, leading=1)
+    s_fus = {k: lay.unpack(v, dtype=jnp.float32, leading=1)
+             for k, v in s_fus.items()}
 
     # the momentum recovery (x - mix)/lr amplifies roundoff by 1/lr per
     # step, so state comparisons need a relative component

@@ -1,21 +1,22 @@
 """Flat parameter planes: layout, parity, launch counts, state round-trips.
 
-The tentpole claims pinned here (fast tier; the shard_map side lives in
+The train step runs every update on flat planes.  The claims pinned here
+(fast tier; the shard_map side lives in
 tests/test_distributed.py::test_flat_planes_shard_map_parity_and_collective_count):
 
 * pack/unpack is a lossless round trip for mixed-dtype trees, per-node and
   stacked, and both pack lowerings produce identical buffers;
-* the plane path is **bit-exact** with the per-leaf path for all 11
-  algorithms on the Pallas stage executor (interpret mode), including LARS
-  row scalars, grad clip, weight decay and staleness damping; on the
-  stacked reference executor (real gossip channel) it agrees up to the
-  rounding of the dense ``W @ x`` mix;
+* for all 11 algorithms, including LARS row scalars, grad clip, weight
+  decay and staleness damping, the plane update against the per-leaf
+  ``reference_stage`` oracle is **bit-exact** on the plane reference stage
+  and within ``MIX_ULPS`` ulps of each leaf's largest value on the plane
+  Pallas stage (interpret mode); on the stacked reference executor (real
+  gossip channel) it agrees up to the rounding of the dense ``W @ x`` mix;
 * the plane Pallas path issues exactly O(dtype-buckets x stages)
-  ``pallas_call``s where the per-leaf path issues O(leaves x stages) —
-  counted from the traced jaxpr;
+  ``pallas_call``s — counted from the traced jaxpr;
 * plane-layout channel state (delay ring buffers, error feedback)
-  checkpoints and resumes bit-exactly, and ``reconcile_plane_state``
-  converts optimizer state across the ``flat_planes`` flag.
+  checkpoints and resumes bit-exactly, and ``reconcile_plane_state`` packs
+  tree-form optimizer state and converts planes across tp.
 """
 
 import dataclasses
@@ -30,7 +31,7 @@ from repro.core.gossip import DelayedStackedChannel
 from repro.core.optimizers import ALGORITHMS, OptimizerConfig, make_optimizer
 from repro.core.planes import LANES, PlaneLayout, plane_scalars
 from repro.core.update_spec import run_update, stage_plan, update_spec
-from repro.kernels.fused_update import make_plane_stage, make_stage
+from repro.kernels.fused_update import make_plane_stage
 from repro.launch.costmodel import count_primitive
 
 RNG = np.random.default_rng(11)
@@ -59,6 +60,22 @@ def _tree_equal(a, b) -> bool:
     return all(
         jax.tree.leaves(jax.tree.map(lambda p, q: bool(jnp.array_equal(p, q)), a, b))
     )
+
+
+def _assert_ulps(got, want, what, ulps=None):
+    """Each leaf of ``got`` within ``ulps`` (default ``MIX_ULPS``) ulps of
+    its dtype at the leaf's largest value; ``ulps=0`` asks for bit
+    equality."""
+    ulps = MIX_ULPS if ulps is None else ulps
+    leaves = jax.tree_util.tree_leaves_with_path(want)
+    for (path, w), g in zip(leaves, jax.tree.leaves(got), strict=True):
+        if ulps == 0:
+            assert bool(jnp.array_equal(g, w)), f"{what}{jax.tree_util.keystr(path)}"
+            continue
+        eps = float(jnp.finfo(w.dtype).eps)
+        scale = max(_absmax(g), _absmax(w))
+        _assert_close(g, w, ulps * eps * scale,
+                      f"{what}{jax.tree_util.keystr(path)}")
 
 
 # ---------------------------------------------------------------------------
@@ -121,7 +138,8 @@ def test_row_scalars_scatter():
 
 def test_pallas_interpret_zero_pad_rows_inert():
     """A plane whose rows are not a multiple of the 64-row kernel block
-    still computes the real rows exactly (boundary block masked)."""
+    still computes the real rows (boundary block masked): the Pallas plane
+    stage matches the per-leaf reference on the unpacked tree."""
     tmpl = {"w": jnp.asarray(RNG.standard_normal((70,)), jnp.float32)}
     lay = PlaneLayout.build(tmpl)
     cfg = OptimizerConfig(algorithm="decentlam", momentum=0.9)
@@ -134,16 +152,16 @@ def test_pallas_interpret_zero_pad_rows_inert():
 
     kw = dict(lr=0.01, step_idx=jnp.int32(0), gossip=gossip, mean=lambda t: t,
               comp_state=())
-    x1, s1, _ = run_update(spec, cfg, x=tmpl, g=g, state=state,
-                           stage=make_stage("pallas_interpret"), **kw)
+    x1, s1, _ = run_update(spec, cfg, x=tmpl, g=g, state=state, **kw)
     xp = lay.pack(tmpl)
-    x2p, _, _ = run_update(
+    x2p, s2p, _ = run_update(
         spec, cfg, x=xp, g=lay.pack(g, dtype=jnp.float32),
         state={k: lay.pack(v, dtype=jnp.float32) for k, v in state.items()},
         stage=make_plane_stage("pallas_interpret"),
         scalars=plane_scalars(cfg, lay, tmpl, g), **kw,
     )
-    assert _tree_equal(x1, lay.unpack(x2p, like=tmpl))
+    _assert_ulps(lay.unpack(x2p, like=tmpl), x1, "x")
+    _assert_ulps(lay.unpack(s2p["m"], dtype=jnp.float32), s1["m"], "m")
 
 
 # ---------------------------------------------------------------------------
@@ -239,12 +257,15 @@ def test_plane_parity_reference_stacked(algo, extras):
                               f"step {k} {sk}")
 
 
+@pytest.mark.parametrize("impl", ("ref", "pallas_interpret"))
 @pytest.mark.parametrize("algo", ALGORITHMS)
 @pytest.mark.parametrize("extras", CONFIGS, ids=("plain", "lars-clip-wd"))
-def test_plane_parity_pallas_interpret(algo, extras):
-    """Per-node Pallas path: whole-plane stage kernels equal the per-leaf
-    stage kernels bit-for-bit (incl. the LARS row-scalar operand and the
-    staleness damping scalar)."""
+def test_plane_parity_pallas_interpret(algo, extras, impl):
+    """Per-node plane update vs the per-leaf ``reference_stage`` oracle on
+    the unpacked trees (incl. the LARS row-scalar operand and the staleness
+    damping scalar): the plane reference stage is bit-exact, the plane
+    Pallas stage (interpret mode) within ``MIX_ULPS`` ulps of each leaf's
+    largest value."""
     tmpl = _tmpl()
     lay = PlaneLayout.build(tmpl)
     cfg = OptimizerConfig(algorithm=algo, momentum=0.9, **extras)
@@ -259,24 +280,24 @@ def test_plane_parity_pallas_interpret(algo, extras):
     ng = jnp.int32(2) if spec.staleness_aware else None
     kw = dict(lr=0.01, step_idx=jnp.int32(3), gossip=gossip, mean=lambda t: t,
               comp_state=(), node_gaps=ng)
-    x1, s1, _ = run_update(spec, cfg, x=x, g=g, state=state,
-                           stage=make_stage("pallas_interpret"), **kw)
+    x1, s1, _ = run_update(spec, cfg, x=x, g=g, state=state, **kw)
     x2p, s2p, _ = run_update(
         spec, cfg, x=lay.pack(x), g=lay.pack(g, dtype=jnp.float32),
         state={k: lay.pack(v, dtype=jnp.float32) for k, v in state.items()},
-        stage=make_plane_stage("pallas_interpret"),
+        stage=make_plane_stage(impl),
         scalars=plane_scalars(cfg, lay, x, g), **kw,
     )
-    assert _tree_equal(x1, lay.unpack(x2p, like=x))
+    ulps = 0 if impl == "ref" else MIX_ULPS
+    _assert_ulps(lay.unpack(x2p, like=x), x1, "x", ulps)
     for sk in s1:
-        assert _tree_equal(s1[sk], lay.unpack(s2p[sk], dtype=jnp.float32)), sk
+        _assert_ulps(lay.unpack(s2p[sk], dtype=jnp.float32), s1[sk], sk, ulps)
 
 
 def _poison(g):
     """``g`` with one NaN and one inf (in different leaves)."""
     g = dict(g)
-    g["w1"] = g["w1"].at[3, 2].set(jnp.nan)
-    g["ln"] = g["ln"].at[5].set(jnp.inf)
+    g["ln"] = g["ln"].at[5].set(jnp.nan)
+    g["b"] = jnp.full_like(g["b"], jnp.inf)
     return g
 
 
@@ -284,7 +305,7 @@ def _poison(g):
 @pytest.mark.parametrize("extras", CONFIGS, ids=("plain", "lars-clip-wd"))
 @pytest.mark.parametrize("finite", (True, False), ids=("finite", "nan-inf"))
 @pytest.mark.parametrize(
-    "impl", ("ref", "pallas_interpret", "leaf_pallas_interpret")
+    "impl", ("ref", "pallas_interpret", "sharded_pallas_interpret")
 )
 def test_plane_guard_folded_matches_where_guard(algo, extras, finite, impl):
     """The finite guard folded into the stages (``run_update(ok=...)``)
@@ -292,10 +313,16 @@ def test_plane_guard_folded_matches_where_guard(algo, extras, finite, impl):
     ``g`` by ``where``, run the update, restore the state by ``where``.  The
     params keep their ``g = 0`` update, and every payload handed to gossip
     is finite.  ``ref`` and ``pallas_interpret`` run the plane stages on
-    packed planes, ``leaf_pallas_interpret`` the per-leaf Pallas stages on
-    the unpacked trees (padded per-leaf tiles)."""
-    tmpl = _tmpl()
-    lay = PlaneLayout.build(tmpl)
+    packed planes, ``sharded_pallas_interpret`` the Pallas stages on one
+    mesh column's local buckets of a tp=2 sharded layout."""
+    if impl.startswith("sharded_"):
+        tmpl, specs = _sharded_tmpl_specs()
+        lay = PlaneLayout.build(tmpl, tp=2, shardings=specs)
+        tmpl = jax.tree.map(jnp.zeros_like, lay.local_template())
+        impl = impl.removeprefix("sharded_")
+    else:
+        tmpl = _tmpl()
+        lay = PlaneLayout.build(tmpl)
     cfg = OptimizerConfig(algorithm=algo, momentum=0.9, **extras)
     spec = update_spec(cfg)
     x = _rand_like(tmpl)
@@ -304,17 +331,13 @@ def test_plane_guard_folded_matches_where_guard(algo, extras, finite, impl):
         g = _poison(g)
     ok = jnp.asarray(finite)
     g0 = jax.tree.map(lambda a: jnp.where(ok, a, jnp.zeros_like(a)), g)
-    leaf = impl.startswith("leaf_")
-
-    def pack(tree, dtype=None):
-        return tree if leaf else lay.pack(tree, dtype=dtype)
 
     # a state that is not all zeros, so a restore that picked the wrong
     # value shows
     state = jax.tree.map(
         lambda a: a + jnp.float32(0.25), make_optimizer(cfg).init(x)
     )
-    state_pl = {k: pack(v, dtype=jnp.float32) for k, v in state.items()}
+    state_pl = {k: lay.pack(v, dtype=jnp.float32) for k, v in state.items()}
     payloads = []  # what gossip (or pmsgd's mean) is handed
 
     def gossip(tree, step, comp):
@@ -327,19 +350,18 @@ def test_plane_guard_folded_matches_where_guard(algo, extras, finite, impl):
         return tree
 
     ng = jnp.int32(2) if spec.staleness_aware else None
-    if leaf:  # run_update derives the scalars from the (zeroed) trees
-        stage, scalars = make_stage(impl.removeprefix("leaf_")), None
-    else:
-        stage, scalars = make_plane_stage(impl), plane_scalars(cfg, lay, x, g0)
     kw = dict(lr=0.01, step_idx=jnp.int32(3), gossip=gossip, mean=mean,
-              comp_state=(), node_gaps=ng, stage=stage, scalars=scalars)
-    xw, sw, _ = run_update(spec, cfg, x=pack(x), g=pack(g0, dtype=jnp.float32),
-                           state=state_pl, **kw)
+              comp_state=(), node_gaps=ng, stage=make_plane_stage(impl),
+              scalars=plane_scalars(cfg, lay, x, g0))
+    xw, sw, _ = run_update(spec, cfg, x=lay.pack(x),
+                           g=lay.pack(g0, dtype=jnp.float32), state=state_pl,
+                           **kw)
     sw = {k: jax.tree.map(lambda a, b: jnp.where(ok, a, b), v, state_pl[k])
           for k, v in sw.items()}
     payloads.clear()
-    xf, sf, _ = run_update(spec, cfg, x=pack(x), g=pack(g, dtype=jnp.float32),
-                           state=state_pl, ok=ok, **kw)
+    xf, sf, _ = run_update(spec, cfg, x=lay.pack(x),
+                           g=lay.pack(g, dtype=jnp.float32), state=state_pl,
+                           ok=ok, **kw)
     assert _tree_equal(xf, xw)
     assert set(sf) == set(sw)
     for sk in sf:
@@ -353,8 +375,8 @@ def test_plane_guard_folded_matches_where_guard(algo, extras, finite, impl):
 
 @pytest.mark.parametrize("algo", ALGORITHMS)
 def test_plane_launch_count_is_O_stages(algo):
-    """jaxpr-counted pallas_calls: per-leaf = leaves x stages, plane =
-    buckets x stages — the tentpole's launch-collapse claim."""
+    """jaxpr-counted pallas_calls of the plane update: buckets x stages,
+    whatever the number of leaves."""
     tmpl = _tmpl()
     lay = PlaneLayout.build(tmpl)
     cfg = OptimizerConfig(algorithm=algo, momentum=0.9, weight_decay=0.01)
@@ -368,10 +390,6 @@ def test_plane_launch_count_is_O_stages(algo):
     kw = dict(lr=0.01, step_idx=jnp.int32(0), gossip=gossip, mean=lambda t: t,
               comp_state=())
 
-    def leaf_fn(x, g, state):
-        return run_update(spec, cfg, x=x, g=g, state=state,
-                          stage=make_stage("pallas_interpret"), **kw)
-
     def plane_fn(x, g, state):
         return run_update(
             spec, cfg, x=lay.pack(x), g=lay.pack(g, dtype=jnp.float32),
@@ -381,11 +399,8 @@ def test_plane_launch_count_is_O_stages(algo):
         )
 
     stages = len(stage_plan(cfg))
-    n_leaves = len(jax.tree.leaves(tmpl))
     n_buckets = len(lay.segments)
-    assert count_primitive(
-        jax.make_jaxpr(leaf_fn)(tmpl, g, state), "pallas_call"
-    ) == n_leaves * stages
+    assert n_buckets < len(jax.tree.leaves(tmpl))
     assert count_primitive(
         jax.make_jaxpr(plane_fn)(tmpl, g, state), "pallas_call"
     ) == n_buckets * stages
@@ -401,8 +416,9 @@ POISON_TOKEN, ZERO_TOKEN = 1, 2  # batches made of one token id throughout
 @pytest.fixture(scope="module")
 def guard_step():
     """A tiny model's DecentLaM train step on flat planes, guard on, on one
-    node: ``step`` with the plane reference stage, ``step_pallas`` with the
-    Pallas stages in interpret mode.  Both run the same stage math, the
+    node: ``step`` with the plane reference stage (``fused_impl="ref"``),
+    ``step_pallas`` with the Pallas stages in interpret mode
+    (``fused_impl="pallas_interpret"``).  Both run the same stage math, the
     guard included; only ``step`` executes here, because the Pallas
     interpreter cannot run a kernel body inside the step's varying-axis
     checked ``shard_map`` (it binds the body's primitives without promoting
@@ -421,7 +437,7 @@ def guard_step():
                   vocab_size=256)
     tcfg = TrainConfig(
         algorithm="decentlam", topology="ring", momentum=0.9,
-        flat_planes=True, fused_impl="pallas_interpret",
+        fused_impl="ref",
         schedule=ScheduleConfig(kind="constant", peak_lr=1e-2),
         runtime=T.RuntimeConfig(dtype="float32", remat=False),
     )
@@ -440,7 +456,7 @@ def guard_step():
         mp.setattr(T, "forward_loss", scaled_loss)
         step, _, _, channel = build_train_step(cfg, tcfg, mesh)
         step_pallas = build_train_step(
-            cfg, dataclasses.replace(tcfg, fused_update=True), mesh
+            cfg, dataclasses.replace(tcfg, fused_impl="pallas_interpret"), mesh
         )[0]
         state = init_train_state(
             jax.random.key(0), cfg, make_optimizer(tcfg.opt_config()), 1, 1,
@@ -527,6 +543,38 @@ def test_train_step_with_nonfinite_grads_skips_the_update(guard_step):
         assert bool(jnp.isfinite(leaf).all())
 
 
+@pytest.mark.parametrize("field", ("flat_planes", "fused_update"))
+def test_train_config_has_one_update_path(field):
+    """The step always updates on flat planes: turning either legacy field
+    off is refused, and ``fused_impl`` alone picks the stage."""
+    from repro.train.step import TrainConfig
+
+    with pytest.raises(ValueError, match="flat planes"):
+        TrainConfig(**{field: False})
+
+
+def test_slowmo_clip_weight_decay_step_is_finite():
+    """SlowMo with ``grad_clip`` and ``weight_decay`` builds with the
+    default TrainConfig and takes a finite step on two nodes (the per-leaf
+    tree step failed to trace it: its sync ``cond`` branches differed in
+    type)."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=2",
+               PYTHONPATH=os.path.join(root, "src"), JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(root, "tests", "scripts",
+                                      "slowmo_clip_wd.py")],
+        capture_output=True, text=True, timeout=600, env=env,
+    )
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    assert "slowmo clip+wd: OK" in proc.stdout, proc.stdout
+
+
 # ---------------------------------------------------------------------------
 # plane-layout channel state: checkpoint round trip + resume equality
 # ---------------------------------------------------------------------------
@@ -595,7 +643,7 @@ def test_plane_channel_state_checkpoint_roundtrip(tmp_path):
         "opt": b_s,
         "channel": b_c,
     }
-    save_checkpoint(str(tmp_path), jax.device_get(ckpt))
+    save_checkpoint(str(tmp_path), jax.device_get(ckpt), plane_layout=lay)
     restored, _ = restore_checkpoint(str(tmp_path))
     assert _tree_equal(restored["channel"], b_c)  # delay rings + EF exact
     b_x, b_s, b_c = restored["params"], restored["opt"], restored["channel"]
@@ -608,8 +656,8 @@ def test_plane_channel_state_checkpoint_roundtrip(tmp_path):
 
 
 def test_reconcile_plane_state_roundtrip():
-    """Optimizer state converts tree <-> plane across the flat_planes flag
-    without loss (the cross-format resume path)."""
+    """A tree-form optimizer state (written by the per-leaf update path of
+    older checkpoints) packs into the plane layout without loss."""
     from repro.train.train_state import reconcile_plane_state
 
     n = 3
@@ -620,13 +668,14 @@ def test_reconcile_plane_state_roundtrip():
         tmpl,
     )
     tree_state = {"step": jnp.int32(7), "params": {}, "opt": {"m": m}}
-    packed = reconcile_plane_state(tree_state, lay, True)
+    packed = reconcile_plane_state(tree_state, lay)
     assert set(packed["opt"]["m"]) == set(lay.segments)
+    assert _tree_equal(
+        lay.unpack_global(packed["opt"]["m"], dtype=jnp.float32, leading=1), m
+    )
     # already-plane state passes through unchanged
-    again = reconcile_plane_state(packed, lay, True)
+    again = reconcile_plane_state(packed, lay)
     assert _tree_equal(again["opt"]["m"], packed["opt"]["m"])
-    back = reconcile_plane_state(packed, lay, False)
-    assert _tree_equal(back["opt"]["m"], m)
 
 
 def _tp_cfg():
@@ -850,10 +899,11 @@ def _sharded_tmpl_specs():
 @pytest.mark.parametrize("algo", ALGORITHMS)
 def test_plane_parity_sharded_local(algo, tp):
     """One mesh column's view of a sharded layout: the whole-plane Pallas
-    stage on the LOCAL buckets is bit-exact with the per-leaf stage on the
-    local tree, for all 11 algorithms with LARS row scalars + clip + decay
-    and staleness damping — the acceptance anchor's fast-tier half (the
-    8-device shard_map half lives in tests/test_distributed.py)."""
+    stage on the LOCAL buckets matches the per-leaf ``reference_stage`` on
+    the local tree within ``MIX_ULPS`` ulps, for all 11 algorithms with
+    LARS row scalars + clip + decay and staleness damping — the fast-tier
+    half (the 8-device shard_map half lives in
+    tests/test_distributed.py)."""
     tmpl, specs = _sharded_tmpl_specs()
     lay = PlaneLayout.build(tmpl, tp=tp, shardings=specs)
     local = _rand_like(jax.tree.map(jnp.zeros_like, lay.local_template()))
@@ -871,17 +921,16 @@ def test_plane_parity_sharded_local(algo, tp):
     ng = jnp.int32(2) if spec.staleness_aware else None
     kw = dict(lr=0.01, step_idx=jnp.int32(3), gossip=gossip, mean=lambda t: t,
               comp_state=(), node_gaps=ng)
-    x1, s1, _ = run_update(spec, cfg, x=local, g=g, state=state,
-                           stage=make_stage("pallas_interpret"), **kw)
+    x1, s1, _ = run_update(spec, cfg, x=local, g=g, state=state, **kw)
     x2p, s2p, _ = run_update(
         spec, cfg, x=lay.pack(local), g=lay.pack(g, dtype=jnp.float32),
         state={k: lay.pack(v, dtype=jnp.float32) for k, v in state.items()},
         stage=make_plane_stage("pallas_interpret"),
         scalars=plane_scalars(cfg, lay, local, g), **kw,
     )
-    assert _tree_equal(x1, lay.unpack(x2p, like=local))
+    _assert_ulps(lay.unpack(x2p, like=local), x1, "x")
     for sk in s1:
-        assert _tree_equal(s1[sk], lay.unpack(s2p[sk], dtype=jnp.float32)), sk
+        _assert_ulps(lay.unpack(s2p[sk], dtype=jnp.float32), s1[sk], sk)
 
 
 def test_sharded_launch_count_matches_tp1_collapse():
@@ -951,16 +1000,13 @@ def test_reconcile_plane_state_cross_tp(tmp_path):
 
     # tp=2 checkpoint -> tp=1 run
     state = {"step": jnp.int32(5), "params": {}, "opt": {"m": packed2}}
-    out = reconcile_plane_state(state, lay1, True, stored_layout=lay2)
+    out = reconcile_plane_state(state, lay1, stored_layout=lay2)
     assert _tree_equal(out["opt"]["m"], packed1)
     # tp=1 checkpoint -> tp=2 run
     back = reconcile_plane_state(
-        {**state, "opt": {"m": packed1}}, lay2, True, stored_layout=lay1
+        {**state, "opt": {"m": packed1}}, lay2, stored_layout=lay1
     )
     assert _tree_equal(back["opt"]["m"], packed2)
-    # cross-tp restore straight to tree form (flat_planes turned off)
-    tree = reconcile_plane_state(state, lay1, False, stored_layout=lay2)
-    assert _tree_equal(tree["opt"]["m"], m)
 
     # the manifest carries the layout the checkpoint was written with
     save_checkpoint(str(tmp_path), jax.device_get(state), plane_layout=lay2)
@@ -968,7 +1014,7 @@ def test_reconcile_plane_state_cross_tp(tmp_path):
     assert manifest["plane_tp"] == 2
     assert manifest["plane_rows"] == {k: int(v) for k, v in lay2.rows.items()}
     stored = model_plane_layout(cfg, int(manifest["plane_tp"]))
-    out2 = reconcile_plane_state(restored, lay1, True, stored_layout=stored)
+    out2 = reconcile_plane_state(restored, lay1, stored_layout=stored)
     assert _tree_equal(out2["opt"]["m"], packed1)
 
     # incompatible global templates (different vocab padding) refuse loudly
@@ -978,13 +1024,13 @@ def test_reconcile_plane_state_cross_tp(tmp_path):
         dataclasses.replace(cfg, vocab_size=cfg.vocab_size + 2), 1
     )
     with pytest.raises(ValueError, match="mismatch|structure"):
-        reconcile_plane_state(state, other, True, stored_layout=lay2)
+        reconcile_plane_state(state, other, stored_layout=lay2)
 
 
 def test_reconcile_tree_form_ignores_cross_tp_padding():
-    """A tree-form opt state resumes across tp even when tp-dependent
-    padding (vocab_padded) differs between the stored and current layouts
-    — the per-leaf production path.  Regression: the global-template
+    """A tree-form opt state (an older checkpoint's per-leaf form) resumes
+    across tp even when tp-dependent padding (vocab_padded) differs between
+    the stored and current layouts.  Regression: the global-template
     compatibility check must run lazily, only when a plane-form bucket
     actually needs cross-tp conversion, not eagerly whenever
     ``stored.tp != plane_layout.tp``."""
@@ -1013,12 +1059,9 @@ def test_reconcile_tree_form_ignores_cross_tp_padding():
         lay2.global_template(),
     )
     state = {"step": jnp.int32(5), "params": {}, "opt": {"m": m}}
-    # tp=1-written manifest resumed at tp=2 per-leaf: passes through intact
-    out = reconcile_plane_state(state, lay2, False, stored_layout=lay1)
-    assert _tree_equal(out["opt"]["m"], m)
-    # and the flat-planes resume of a tree-form state packs with the
-    # *current* layout without ever touching the stored one
-    packed = reconcile_plane_state(state, lay2, True, stored_layout=lay1)
+    # a tree-form state packs with the *current* layout without ever
+    # touching the stored one
+    packed = reconcile_plane_state(state, lay2, stored_layout=lay1)
     assert _tree_equal(packed["opt"]["m"],
                        lay2.pack_global(m, dtype=jnp.float32, leading=1))
 

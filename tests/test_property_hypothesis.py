@@ -20,7 +20,9 @@ from repro.core import (  # noqa: E402
     build_topology,
     consensus_distance,
 )
-from repro.kernels.fused_update import decentlam_update  # noqa: E402
+from repro.core.planes import PlaneLayout  # noqa: E402
+from repro.core.update_spec import MathCtx  # noqa: E402
+from repro.kernels.fused_update import make_plane_stage  # noqa: E402
 from repro.kernels.flash_attention.ops import flash_attention  # noqa: E402
 from repro.kernels.flash_attention.ref import reference_attention  # noqa: E402
 
@@ -205,15 +207,20 @@ def test_decentlam_sa_gap0_bitexact_and_damping_monotone(name, delay, base):
     st.floats(1e-6, 0.5),  # lr
 )
 def test_decentlam_update_identity(n, beta, lr):
-    """Fused kernel == x - lr*(beta*m + (x - mix)/lr) for any shape/params."""
+    """Fused decentlam_post plane stage == x - lr*(beta*m + (x - mix)/lr)
+    for any shape/params."""
     rng = np.random.default_rng(n)
     x = jnp.asarray(rng.standard_normal(n), jnp.float32)
     mix = jnp.asarray(rng.standard_normal(n), jnp.float32)
     m = jnp.asarray(rng.standard_normal(n), jnp.float32)
-    p, m2 = decentlam_update(
-        {"w": x}, {"w": mix}, {"w": m}, jnp.float32(lr), beta=beta,
-        impl="pallas_interpret",
+    lay = PlaneLayout.build({"w": x})
+    out = make_plane_stage("pallas_interpret")(
+        "post", "decentlam_post", MathCtx(beta=beta),
+        {k: lay.pack({"w": v}) for k, v in (("x", x), ("mix", mix), ("m", m))},
+        {"lr": jnp.float32(lr), "gs": jnp.float32(1.0), "r": jnp.float32(1.0)},
+        lay.pack({"w": x}),
     )
+    p, m2 = (lay.unpack(out[k], dtype=jnp.float32) for k in ("x", "m"))
     g_tilde = (x - mix) / max(lr, 1e-12)
     m_expect = beta * m + g_tilde
     x_expect = x - lr * m_expect
